@@ -38,14 +38,6 @@ from ozone_tpu.codec.fused import FusedSpec, _POLY
 from ozone_tpu.utils.checksum import ChecksumType
 
 
-def _compiler_params_cls():
-    """Pallas-TPU compiler-params class across jax versions: renamed
-    TPUCompilerParams -> CompilerParams upstream; the constructor
-    signature (dimension_semantics, vmem_limit_bytes) is unchanged."""
-    cls = getattr(pltpu, "CompilerParams", None)
-    return cls if cls is not None else pltpu.TPUCompilerParams
-
-
 def _unpack_bits_i32(x_u8: jax.Array) -> jax.Array:
     """uint8 [..., T] -> int32 {0,1} [..., 8, T] (LSB-first planes)."""
     x = x_u8.astype(jnp.int32)
@@ -157,7 +149,7 @@ def _pallas_fused_cached(
                 jax.ShapeDtypeStruct((b, k, s * 128), jnp.int32),
                 jax.ShapeDtypeStruct((b, p, s * 128), jnp.int32),
             ],
-            compiler_params=_compiler_params_cls()(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel"),
                 vmem_limit_bytes=100 * 1024 * 1024,
             ),

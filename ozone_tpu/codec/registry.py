@@ -5,7 +5,9 @@ CodecRegistry.java:55-97: ServiceLoader-discovered factories, native-first
 ordering) and CodecUtil.createRawEncoderWithFallback (rawcoder/util/
 CodecUtil.java:55-82): backends are tried in priority order and the first
 one that instantiates wins, so the TPU coder is "just another factory" next
-to the numpy reference coder, selectable/overridable by name.
+to the numpy reference coder, selectable/overridable by name. The fallback
+stops at the device: off the CPU platform a failing jax factory raises
+instead of handing back a host coder.
 """
 
 from __future__ import annotations
@@ -103,18 +105,17 @@ class CodecRegistry:
         # C++ backend (ISA-L-class nibble-shuffle kernels): preferred over
         # numpy, below the TPU backend — mirrors the reference's
         # native-first ordering (CodecRegistry.java:92-97)
-        try:
-            from ozone_tpu import native as _native
+        # (no toolchain: load() is None and the backend is left out; a
+        # toolchain that fails to build raises NativeBuildError)
+        from ozone_tpu import native as _native
 
-            if _native.load() is not None:
-                from ozone_tpu.codec import cpp_coder
+        if _native.load() is not None:
+            from ozone_tpu.codec import cpp_coder
 
-                self.register(
-                    "rs", "cpp", 50, cpp_coder.CppRSEncoder,
-                    cpp_coder.CppRSDecoder,
-                )
-        except Exception as e:  # pragma: no cover - toolchain present in CI
-            log.warning("cpp codec backend unavailable: %s", e)
+            self.register(
+                "rs", "cpp", 50, cpp_coder.CppRSEncoder,
+                cpp_coder.CppRSDecoder,
+            )
         # TPU backend registers lazily: importing jax is deliberately deferred
         # so host-only tools never pay for it.
         try:
@@ -145,6 +146,15 @@ class CodecRegistry:
                 maker = f.make_encoder if what == "encoder" else f.make_decoder
                 return maker(options)
             except Exception as e:  # fall through to next backend
+                if f.name == "jax":
+                    import jax
+
+                    # only a platform NAMED cpu may hand the device
+                    # coder's work to a host coder; anywhere else a
+                    # failing device coder is the error (and a backend
+                    # that failed to initialise raises again right here)
+                    if jax.default_backend() != "cpu":
+                        raise
                 errors.append(f"{f.name}: {e}")
                 log.warning(
                     "codec backend %s failed for %s, falling back: %s",

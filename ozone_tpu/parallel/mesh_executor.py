@@ -124,13 +124,7 @@ class _MeshProgram:
     def compile_count(self) -> int:
         """Compiled-executable census across this program's jitted
         callables; steady-state dispatches must not move it."""
-        total = 0
-        for f in self.jitted:
-            try:
-                total += int(f._cache_size())
-            except Exception:  # ozlint: allow[error-swallowing] -- _cache_size is a private jax probe; absent on some versions, the census just under-counts
-                continue
-        return total
+        return sum(int(f._cache_size()) for f in self.jitted)
 
 
 class _Sub:
@@ -270,8 +264,7 @@ class MeshExecutor:
         kind = key[0]
         if kind == "encode":
             spec = key[1]
-            if fused._prefer_host_coder(spec.options,
-                                        checksum=spec.checksum):
+            if fused._prefer_host_coder():
                 single = fused._native_fused_encoder(
                     spec.options, spec.checksum, spec.bytes_per_checksum)
                 if single is not None:
@@ -281,9 +274,7 @@ class MeshExecutor:
             return _MeshProgram(jfn, (jfn,), False)
         if kind == "decode":
             spec, valid, erased = key[1], list(key[2]), list(key[3])
-            out_ratio = len(erased) / max(len(valid), 1)
-            if fused._prefer_host_coder(spec.options, out_ratio=out_ratio,
-                                        checksum=spec.checksum):
+            if fused._prefer_host_coder():
                 single = fused._native_fused_decoder(
                     spec.options, spec.checksum, spec.bytes_per_checksum,
                     tuple(valid), tuple(erased))
@@ -563,6 +554,10 @@ class MeshExecutor:
         if ops > 1:
             METRICS.counter("multi_op_dispatches").inc()
         METRICS.gauge("batch_fill_pct").set(100.0 * rows / lane.width)
+        # devices holding a shard of the last dispatch's output: n on a
+        # real SPMD dispatch, 0 for the host twin's numpy arrays
+        METRICS.gauge("output_shards").set(
+            len(getattr(outs[0], "addressable_shards", ())))
         with self._lock:
             METRICS.gauge("queue_depth").set(self._queue_depth_locked())
         self._inflight.append(
@@ -746,21 +741,15 @@ def maybe_executor() -> Optional[MeshExecutor]:
     """The executor when it can exist here: enabled AND more than one
     device attached — the ONE check routed datapaths (lifecycle mesh
     lane, reconstruction storms, codec-service spill) make before
-    falling back to their single-chip pipelines."""
+    falling back to their single-chip pipelines. A backend that fails
+    to initialise raises (see fused._prefer_host_coder)."""
     if not enabled():
         return None
-    try:
-        import jax
+    import jax
 
-        if jax.device_count() < 2:
-            return None
-    except Exception:  # noqa: BLE001 - no backend: single-device path
+    if jax.device_count() < 2:
         return None
-    try:
-        return get_executor()
-    except Exception:  # noqa: BLE001 - mesh construction failed: fall back
-        log.exception("mesh executor unavailable")
-        return None
+    return get_executor()
 
 
 def reset_for_tests() -> None:

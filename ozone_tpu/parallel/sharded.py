@@ -44,18 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 top-level export
-    from jax import shard_map
-except ImportError:  # older jax: experimental namespace, same semantics
-    from jax.experimental.shard_map import shard_map
-
-import inspect
-
-#: the replication-checker toggle was renamed check_rep -> check_vma
-#: across jax versions; resolve the name this jax actually accepts
-_CHECK_KW = ("check_vma"
-             if "check_vma" in inspect.signature(shard_map).parameters
-             else "check_rep")
+from jax import shard_map
 
 from ozone_tpu.codec import crc_device
 from ozone_tpu.codec.api import CoderOptions
@@ -92,12 +81,10 @@ def default_codec_mesh(axis: str = "dn") -> Optional[Mesh]:
     attached, None (single-chip fused path) otherwise. Datanode daemons
     and the minicluster hand this to the reconstruction coordinator and
     scrubber so multi-chip hosts repair/scrub across every chip without
-    configuration."""
-    try:
-        n = jax.device_count()
-    except Exception:  # noqa: BLE001 - no backend: single-device path
-        return None
-    return make_mesh(axis=axis) if n > 1 else None
+    configuration. A backend that fails to initialise raises: a daemon
+    that cannot reach its chip refuses to start instead of serving on
+    the host."""
+    return make_mesh(axis=axis) if jax.device_count() > 1 else None
 
 
 def pad_batch(batch: np.ndarray, n: int) -> tuple[np.ndarray, int]:
@@ -302,8 +289,7 @@ def _ring_apply_cached(mesh: Mesh, axis: str, with_crc: bool,
         mesh=mesh,
         in_specs=(P(None, axis, None), P(axis, None)),
         out_specs=P(None, None, None),
-        # replication checker off (check_vma / legacy check_rep — the
-        # name this jax accepts, resolved at import): the output IS
+        # replication checker off: the output IS
         # replicated, but only by a dynamic argument — after n-1
         # ppermute hops every chip has XOR-accumulated all n partials
         # (each hop k adds the partial that originated k chips
@@ -312,7 +298,7 @@ def _ring_apply_cached(mesh: Mesh, axis: str, with_crc: bool,
         # depend on the permutation completing a cycle; the dryrun
         # asserts cross-device equality of this output at runtime
         # (__graft_entry__.dryrun_multichip).
-        **{_CHECK_KW: False},
+        check_vma=False,
     )
     def ring_decode(units_local, a_local):
         # units_local [B, upc, C] uint8; a_local [upc*8, e*8] int8

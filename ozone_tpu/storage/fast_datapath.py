@@ -59,16 +59,19 @@ _lib_tried = False
 
 
 def load_lib() -> Optional[ctypes.CDLL]:
-    """Build-on-demand + load (native/__init__ pattern); None when no
-    toolchain — the daemon then simply serves gRPC only."""
+    """Build-on-demand + load (native/__init__ pattern); None when the
+    host has no toolchain — the daemon then serves gRPC only. A
+    toolchain that fails to build raises NativeBuildError: the daemon
+    refuses to start rather than quietly serve the slow transport (and
+    so does every later call in the process)."""
     global _lib, _lib_tried
     with _lib_lock:
         if _lib is not None or _lib_tried:
             return _lib
-        _lib_tried = True
         so = build_shared(_SRC, _SO,
                           extra=("-O3", "-march=native", "-std=c++17",
                                  "-pthread"))
+        _lib_tried = True
         if so is None:
             return None
         try:

@@ -744,7 +744,19 @@ def cmd_admin(args) -> int:
 
 # -------------------------------------------------------------------- freon
 def cmd_freon(args) -> int:
+    """Run one generator, print its summary, and exit 1 when the
+    summary counts any failed op — a load run that lost operations is
+    not a success, whatever rate it printed."""
     from ozone_tpu.tools import freon
+    from ozone_tpu.utils.compile_cache import count_compiles
+
+    count_compiles()  # the summaries' device block reports them
+    failed = False
+
+    def emit(summary: dict) -> None:
+        nonlocal failed
+        _emit(summary)
+        failed = failed or summary.get("failures", 0) > 0
 
     if args.generator == "ockg":
         oz = _client(args)
@@ -753,34 +765,34 @@ def cmd_freon(args) -> int:
             replication=args.replication or None, validate=args.validate,
             warmup=args.warmup,
         )
-        _emit(rep.summary())
+        emit(rep.summary())
     elif args.generator == "ockr":
         oz = _client(args)
-        _emit(freon.ockr(oz, args.num, threads=args.threads).summary())
+        emit(freon.ockr(oz, args.num, threads=args.threads).summary())
     elif args.generator == "ockrr":
         oz = _client(args)
-        _emit(freon.ockrr(oz, args.num, size=args.size,
-                          threads=args.threads,
-                          n_keys=args.keys).summary())
+        emit(freon.ockrr(oz, args.num, size=args.size,
+                         threads=args.threads,
+                         n_keys=args.keys).summary())
     elif args.generator == "ockv":
         oz = _client(args)
-        _emit(freon.ockv(oz, n_keys=args.num, size=args.size,
-                         threads=args.threads).summary())
+        emit(freon.ockv(oz, n_keys=args.num, size=args.size,
+                        threads=args.threads).summary())
     elif args.generator == "fskg":
         oz = _client(args)
-        _emit(freon.fskg(
+        emit(freon.fskg(
             oz, n_files=args.num, size=args.size, threads=args.threads,
             replication=args.replication or None,
         ).summary())
     elif args.generator == "mpug":
         oz = _client(args)
-        _emit(freon.mpug(
+        emit(freon.mpug(
             oz, n_uploads=args.num, part_size=args.size,
             threads=args.threads,
             replication=args.replication or None,
         ).summary())
     elif args.generator == "fsg":
-        _emit(freon.fsg(
+        emit(freon.fsg(
             _client(args), n_files=args.num, size=args.size,
             threads=args.threads,
             replication=args.replication or None).summary())
@@ -788,17 +800,17 @@ def cmd_freon(args) -> int:
         from ozone_tpu.net.scm_service import GrpcScmClient
 
         scm = GrpcScmClient(args.om, tls=_client_tls())
-        _emit(freon.ecrd(
+        emit(freon.ecrd(
             _client(args), scm, size=args.size, rounds=args.num,
             replication=args.replication or "rs-6-3-1048576",
         ))
     elif args.generator == "sdg":
         # -t is deliberately not honored: the snapshot chain is ordered
-        _emit(freon.sdg(
+        emit(freon.sdg(
             _client(args), n_rounds=args.num, size=args.size,
             replication=args.replication or None).summary())
     elif args.generator == "s3kg":
-        _emit(freon.s3kg(
+        emit(freon.s3kg(
             args.endpoint, n_keys=args.num, size=args.size,
             threads=args.threads, validate=args.validate,
         ).summary())
@@ -808,13 +820,13 @@ def cmd_freon(args) -> int:
         # OM-provisioned credentials — the bench wires those)
         tenants = [{"name": f"tenant-{i}", "rate": 0.0}
                    for i in range(max(1, args.threads))]
-        _emit(freon.swarm(
+        emit(freon.swarm(
             args.endpoint, tenants, duration_s=args.duration,
             n_keys=args.num, tiny=args.tiny,
         ).summary())
     elif args.generator == "tinyg":
         oz = _client(args)
-        _emit(freon.tinyg(
+        emit(freon.tinyg(
             oz, n_keys=args.num, size=args.size, threads=args.threads,
             replication=args.replication or "rs-3-2-4096",
             packer=not args.no_packer, mix=args.tiny,
@@ -822,7 +834,7 @@ def cmd_freon(args) -> int:
         ).summary())
     elif args.generator == "lcg":
         oz = _client(args)
-        _emit(freon.lcg(
+        emit(freon.lcg(
             oz, n_keys=args.num, size=args.size, threads=args.threads,
             replication=args.replication or "RATIS/THREE",
             target=args.target,
@@ -833,7 +845,7 @@ def cmd_freon(args) -> int:
                   "destination cluster endpoint)", file=sys.stderr)
             return 1
         oz = _client(args)
-        _emit(freon.geo(
+        emit(freon.geo(
             oz, args.dest, n_keys=args.num, size=args.size,
             threads=args.threads,
             replication=args.replication or "RATIS/THREE",
@@ -841,24 +853,24 @@ def cmd_freon(args) -> int:
         ).summary())
     elif args.generator == "hsg":
         oz = _client(args)
-        _emit(freon.hsg(
+        emit(freon.hsg(
             oz, n_keys=args.num, size=args.size, threads=args.threads,
             replication=args.replication or "RATIS/THREE",
         ).summary())
     elif args.generator == "rawcoder":
-        _emit(
+        emit(
             freon.rawcoder_bench(
-                schema=args.schema, cell=args.cell, batch=args.batch
+               schema=args.schema, cell=args.cell, batch=args.batch
             )
         )
     elif args.generator == "omkg":
-        _emit(freon.omkg(_client(args), n_keys=args.num,
-                         threads=args.threads).summary())
+        emit(freon.omkg(_client(args), n_keys=args.num,
+                        threads=args.threads).summary())
     elif args.generator == "ommg":
-        _emit(freon.ommg(_client(args), n_ops=args.num,
-                         threads=args.threads, mix=args.mix).summary())
+        emit(freon.ommg(_client(args), n_ops=args.num,
+                        threads=args.threads, mix=args.mix).summary())
     elif args.generator == "scmtb":
-        _emit(freon.scmtb(
+        emit(freon.scmtb(
             _client(args), n_blocks=args.num, threads=args.threads,
             replication=args.replication or "rs-3-2-4096",
         ).summary())
@@ -866,23 +878,23 @@ def cmd_freon(args) -> int:
         from ozone_tpu.net.scm_service import GrpcScmClient
 
         scm = GrpcScmClient(args.om, tls=_client_tls())
-        _emit(freon.dnsim(
+        emit(freon.dnsim(
             scm, n_datanodes=args.num, n_containers=args.containers,
             duration_s=args.duration, interval_s=args.interval,
             threads=args.threads,
         ).summary())
     elif args.generator == "cmdw":
-        _emit(freon.cmdw(args.root or "/tmp/ozone-cmdw", n_chunks=args.num,
-                         size=args.size, threads=args.threads).summary())
+        emit(freon.cmdw(args.root or "/tmp/ozone-cmdw", n_chunks=args.num,
+                        size=args.size, threads=args.threads).summary())
     elif args.generator == "dbgen":
-        _emit(freon.dbgen(args.root or "/tmp/ozone-dbgen.db",
-                          n_keys=args.num).summary())
+        emit(freon.dbgen(args.root or "/tmp/ozone-dbgen.db",
+                         n_keys=args.num).summary())
     elif args.generator == "ralg":
         import tempfile
 
         root = args.root or tempfile.mkdtemp(prefix="ozone-ralg-")
-        _emit(freon.ralg(root, n_entries=args.num, size=args.size,
-                         threads=args.threads).summary())
+        emit(freon.ralg(root, n_entries=args.num, size=args.size,
+                        threads=args.threads).summary())
     elif args.generator in ("dcg", "dcb", "dcv", "dsg", "dnbp"):
         oz = _client(args)
         dn_ids = list(oz.clients.known_ids())
@@ -891,14 +903,14 @@ def cmd_freon(args) -> int:
                   "reachable?)", file=sys.stderr)
             return 1
         if args.generator == "dnbp":
-            _emit(freon.dnbp(oz.clients, dn_ids, args.num,
-                             threads=args.threads).summary())
-            return 0
+            emit(freon.dnbp(oz.clients, dn_ids, args.num,
+                            threads=args.threads).summary())
+            return int(failed)
         gen = {"dcg": freon.dcg, "dcb": freon.dcb, "dcv": freon.dcv,
                "dsg": freon.dsg}[args.generator]
-        _emit(gen(oz.clients, dn_ids, args.num, size=args.size,
-                  threads=args.threads).summary())
-    return 0
+        emit(gen(oz.clients, dn_ids, args.num, size=args.size,
+                 threads=args.threads).summary())
+    return int(failed)
 
 
 # ------------------------------------------------------------------ daemons
@@ -937,8 +949,12 @@ def cmd_cluster(args) -> int:
 
     root = Path(args.root or tempfile.mkdtemp(prefix="ozone-cluster-"))
     root.mkdir(parents=True, exist_ok=True)
-    env = dict(os.environ, PYTHONPATH=str(Path(__file__).resolve()
-                                          .parents[2]))
+    # N datanodes on one host cannot share its chip: the daemons this
+    # launcher spawns are pinned to the CPU and the chip is left to the
+    # client or gateway. A datanode started by hand on its own host is
+    # not pinned and uses its chip.
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(Path(__file__).resolve().parents[2]))
     procs: list = []
 
     def spawn(argv, log_name):
@@ -2322,7 +2338,10 @@ def _ship_spans(args) -> None:
 
 
 def main(argv=None) -> int:
+    from ozone_tpu.utils.compile_cache import ensure_compile_cache
+
     args = build_parser().parse_args(argv)
+    ensure_compile_cache()
     try:
         return args.fn(args)
     except StorageError as e:

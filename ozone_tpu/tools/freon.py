@@ -94,6 +94,7 @@ class BaseFreonGenerator:
         self.threads = threads
         self._lat: list[float] = []
         self._failures = 0
+        self._first_error = ""
         self._bytes = 0
         self._lock = threading.Lock()
 
@@ -109,9 +110,11 @@ class BaseFreonGenerator:
                 with self._lock:
                     self._lat.append(dt)
                     self._bytes += nbytes
-            except Exception:
+            except Exception as e:
                 with self._lock:
                     self._failures += 1
+                    if not self._first_error:
+                        self._first_error = f"op {i}: {e!r}"
 
         with ThreadPoolExecutor(max_workers=self.threads) as pool:
             list(pool.map(task, range(self.n_ops)))
@@ -122,6 +125,9 @@ class BaseFreonGenerator:
             elapsed_s=time.time() - t0,
             latencies_s=self._lat,
             bytes_processed=self._bytes,
+            # a count alone cannot say what failed
+            extras=({"first_error": self._first_error}
+                    if self._failures else {}),
         )
 
 
@@ -142,6 +148,31 @@ def _client_hist_extras() -> dict:
                 p: round(1e3 * v, 3)
                 for p, v in h.percentiles().items()}
     return out
+
+
+def _device_extras() -> dict:
+    """What the run's codec work ran on, so no summary is read without
+    its device: platform / device_kind / device count as JAX reports
+    them, the fused path the factories chose (`jax` or `native`), this
+    process's compile counters (where its entry point asked for them:
+    `cmd_freon` does), and how many fused dispatches the shared codec
+    service and the mesh executor launched."""
+    from ozone_tpu.codec import fused
+    from ozone_tpu.codec import service as codec_service
+    from ozone_tpu.parallel import mesh_executor
+    from ozone_tpu.utils.compile_cache import compile_counts
+
+    svc = codec_service.METRICS.snapshot()
+    out = {**fused.backend_report(), **compile_counts()}
+    for name in ("dispatches", "stripes_dispatched", "slots_dispatched"):
+        out[name] = int(svc.get(name, 0))
+    mex = mesh_executor._executor
+    if mex is not None:
+        st = mex.stats()
+        out["mesh"] = {k: int(st.get(k, 0)) for k in (
+            "devices", "dispatches", "stripes_dispatched", "programs",
+            "programs_host_twin", "output_shards", "compile_counts")}
+    return {"device": out}
 
 
 def _det_payload(size: int, seed: int = 0) -> np.ndarray:
@@ -191,6 +222,7 @@ def ockg(
         b.write_key(f"{prefix}-warmup-{w}", payload, replication)
     rep = BaseFreonGenerator("ockg", n_keys, threads).run(op)
     rep.extras.update(_client_hist_extras())
+    rep.extras.update(_device_extras())
     return rep
 
 
@@ -476,6 +508,7 @@ def ockr(client, n_keys: int, threads: int = 4, volume: str = "freon-vol",
 
     rep = BaseFreonGenerator("ockr", n_keys, threads).run(op)
     rep.extras.update(_client_hist_extras())
+    rep.extras.update(_device_extras())
     return rep
 
 
@@ -1012,7 +1045,9 @@ def ockv(client, n_keys: int = 100, size: int = 10 * 1024,
         assert np.array_equal(got, expect), f"corrupt key {prefix}-{i}"
         return int(got.size)
 
-    return BaseFreonGenerator("ockv", n_keys, threads).run(op)
+    rep = BaseFreonGenerator("ockv", n_keys, threads).run(op)
+    rep.extras.update(_device_extras())
+    return rep
 
 
 def fskg(client, n_files: int = 100, size: int = 10 * 1024,
@@ -1174,6 +1209,60 @@ def sdg(client, n_rounds: int = 10, keys_per_round: int = 5,
     return BaseFreonGenerator("sdg", n_rounds, threads=1).run(op)
 
 
+def _dispatch_counts() -> tuple[int, int]:
+    """(dispatches, stripes) the shared codec service and the mesh
+    executor have launched in this process so far."""
+    from ozone_tpu.codec import service as codec_service
+    from ozone_tpu.parallel import mesh_executor
+
+    snaps = (codec_service.METRICS.snapshot(),
+             mesh_executor.METRICS.snapshot())
+    return (sum(int(s.get("dispatches", 0)) for s in snaps),
+            sum(int(s.get("stripes_dispatched", 0)) for s in snaps))
+
+
+class ReplicaMismatch(Exception):
+    """A rebuilt replica is not what was written."""
+
+
+def _verify_rebuilt_unit(dn, group, opts, unit: int,
+                         payload: np.ndarray) -> int:
+    """Read DATA unit `unit` of `group` straight off datanode client
+    `dn` — chunk records, stored CRCs and bytes, no reader and so no
+    decode — and hold it to `payload`, the bytes the group was written
+    from. Returns the bytes compared; raises ReplicaMismatch (or the
+    datanode's StorageError when the replica is not there at all)."""
+    from ozone_tpu.client.ec_reader import unit_true_lengths
+    from ozone_tpu.utils.checksum import Checksum, ChecksumType
+
+    k, cell = opts.data_units, opts.cell_size
+    blk = dn.get_block(group.block_id)
+    if blk.block_group_length != group.length:
+        raise ReplicaMismatch(
+            f"block group length {blk.block_group_length}, "
+            f"wrote {group.length}")
+    want_len = unit_true_lengths(group, opts)[unit]
+    got_len = sum(info.length for info in blk.chunks)
+    if got_len != want_len or len(
+            {info.offset for info in blk.chunks}) != len(blk.chunks):
+        raise ReplicaMismatch(
+            f"unit {unit} holds {got_len} bytes in {len(blk.chunks)} "
+            f"chunks, wrote {want_len}")
+    for info in blk.chunks:
+        at = (info.offset // cell * k + unit) * cell
+        want = payload[at:at + info.length]
+        got = dn.read_chunk(group.block_id, info, verify=True)
+        if not np.array_equal(np.asarray(got).reshape(-1), want):
+            raise ReplicaMismatch(
+                f"unit {unit} chunk at {info.offset}: bytes differ")
+        sums = info.checksum
+        if sums.type is not ChecksumType.CRC32C or sums != Checksum(
+                sums.type, sums.bytes_per_checksum).compute(want):
+            raise ReplicaMismatch(
+                f"unit {unit} chunk at {info.offset}: stored CRCs differ")
+    return got_len
+
+
 def ecrd(
     client,
     scm,
@@ -1189,10 +1278,20 @@ def ecrd(
     repairing it onto a spare datanode — survivor reads + device decode
     + target writes, all over the real wire
     (ECReconstructionCoordinator.java:146 reconstructECContainerGroup).
+    On a multi-device host the decode batches ride the process mesh
+    executor, as in client/reconstruction.py. Outside the timed region
+    every round reads the REBUILT REPLICA ITSELF off the target datanode
+    (_verify_rebuilt_unit; never through a reader, which would decode
+    around a replica that is missing or wrong) and compares its bytes
+    and stored CRCs with what was written; a round that fails this
+    counts in `failures`. `repair_dispatches` are the device dispatches
+    launched inside the timed region, i.e. by the coordinator alone.
     """
     import time as _time
 
     from ozone_tpu.codec.api import CoderOptions
+    from ozone_tpu.parallel import mesh_executor
+    from ozone_tpu.storage.ids import StorageError
     from ozone_tpu.storage.reconstruction import (
         ECReconstructionCoordinator,
         ReconstructionCommand,
@@ -1210,7 +1309,13 @@ def ecrd(
     b = client.get_volume(volume).get_bucket(bucket)
     payload = _det_payload(size, seed=9)
     all_nodes = [n["dn_id"] for n in scm.status()["nodes"]]
+    coord = ECReconstructionCoordinator(
+        client.clients, executor=mesh_executor.maybe_executor())
     results = []
+    failures = 0
+    first_error = ""
+    verified = 0
+    repair = [0, 0]  # dispatches, stripes launched by the coordinator
     for r in range(rounds):
         key = f"drill-{r}"
         b.write_key(key, payload, replication)
@@ -1240,23 +1345,41 @@ def ecrd(
                      for u in range(opts.all_units) if u != lost},
             targets={lost + 1: spare},
         )
-        coord = ECReconstructionCoordinator(client.clients)
+        before = _dispatch_counts()
         t0 = _time.perf_counter()
         coord.reconstruct_container_group(cmd)
         dt = _time.perf_counter() - t0
+        after = _dispatch_counts()
+        repair = [n + a - b for n, a, b in zip(repair, after, before)]
         unit_bytes = -(-g.length // opts.data_units)
         results.append((unit_bytes, dt))
+        try:
+            verified += _verify_rebuilt_unit(
+                client.clients.get(spare), g, opts, lost, payload)
+        except (StorageError, ReplicaMismatch) as e:
+            failures += 1
+            first_error = first_error or f"round {r}: {e!r}"
         b.delete_key(key)
     per_dn = [ub / 2**20 / dt for ub, dt in results]
     per_dn.sort()
     out = {
-        "name": "ecrd",
+        "generator": "ecrd",
         "rounds": rounds,
+        "failures": failures,
+        **({"first_error": first_error} if failures else {}),
         "unit_mib": round(results[0][0] / 2**20, 2),
+        # everything the coordinator wrote (the container's other
+        # blocks included) / the drill keys' units compared above
+        "bytes_reconstructed": int(coord.metrics.counter(
+            "bytes_reconstructed").value),
+        "bytes_verified": verified,
+        "repair_dispatches": repair[0],
+        "repair_stripes": repair[1],
         "reconstruct_mib_s_per_datanode": round(
             per_dn[len(per_dn) // 2], 2),
         "best_mib_s_per_datanode": round(per_dn[-1], 2),
         "times_s": [round(dt, 3) for _, dt in results],
+        **_device_extras(),
     }
     return out
 

@@ -2,9 +2,7 @@
 
 Exit status 0 = zero unsuppressed findings, 1 = findings, 2 = usage or
 analysis error. Keep this import-light (no jax): the tier-1 gate runs
-it as a subprocess with a <5 s budget (set ``OZONE_TPU_SKIP_JAX_PIN=1``
-or an empty ``JAX_PLATFORMS`` so the package __init__ skips its eager
-platform pin).
+it as a subprocess with a <5 s budget.
 """
 
 from __future__ import annotations

@@ -103,12 +103,11 @@ _NATIVE_LIB = False  # tri-state: False = unprobed, None = unavailable
 def _native_lib():
     global _NATIVE_LIB
     if _NATIVE_LIB is False:
-        try:
-            from ozone_tpu import native
+        from ozone_tpu import native
 
-            _NATIVE_LIB = native.load()
-        except Exception:  # noqa: BLE001 - pure-python fallback
-            _NATIVE_LIB = None
+        # None without a toolchain (pure-python fallback below); a
+        # toolchain that fails to build raises
+        _NATIVE_LIB = native.load()
     return _NATIVE_LIB
 
 

@@ -445,8 +445,11 @@ def test_smoke_round3_verbs(live_cluster):
 def test_cluster_launcher_supervises_and_tears_down(tmp_path):
     """`ozone-tpu cluster`: the one-command compose-cluster analog
     spawns scm-om + datanodes, serves traffic, and SIGTERM reaps every
-    child."""
-    env = dict(os.environ, PYTHONPATH=str(REPO), JAX_PLATFORMS="cpu")
+    child. Whatever platform the launcher's own environment names, the
+    daemons it spawns are pinned to the CPU: N datanodes on one host
+    cannot share its chip, which is left to the client."""
+    env = dict(os.environ, PYTHONPATH=str(REPO))
+    env.pop("JAX_PLATFORMS", None)
     port = _free_port()
     sup = subprocess.Popen(
         [sys.executable, "-m", "ozone_tpu.tools", "cluster",
@@ -473,6 +476,12 @@ def test_cluster_launcher_supervises_and_tears_down(tmp_path):
                 pass
             time.sleep(0.5)
         assert ready, "cluster launcher never became healthy"
+        kids = Path(f"/proc/{sup.pid}/task/{sup.pid}/children") \
+            .read_text().split()
+        assert len(kids) == 3  # scm-om + 2 datanodes
+        for kid in kids:
+            kid_env = Path(f"/proc/{kid}/environ").read_bytes().split(b"\0")
+            assert b"JAX_PLATFORMS=cpu" in kid_env
         _cli(["sh", "volume", "create", "/clv", "--om", om])
     finally:
         sup.send_signal(signal.SIGTERM)

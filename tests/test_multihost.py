@@ -66,14 +66,12 @@ assert checked == 4, checked
 
 # a collective that MUST cross the process boundary: psum over the
 # hybrid (dcn, dn) mesh's both axes
-from jax.experimental.shard_map import shard_map
-
 h = multihost.hybrid_codec_mesh()
 assert h.devices.shape == (2, 4)
 hs = NamedSharding(h, P(("dcn", "dn")))
 ones = jax.make_array_from_process_local_data(
     hs, np.full(4, pid + 1, np.float32), global_shape=(8,))
-summed = shard_map(
+summed = jax.shard_map(
     lambda x: jax.lax.psum(x, ("dcn", "dn")),
     mesh=h, in_specs=P(("dcn", "dn")), out_specs=P())(ones)
 # proc0 contributes 4x1, proc1 4x2 -> 12; replicated everywhere

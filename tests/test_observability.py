@@ -160,7 +160,7 @@ def test_prometheus_text_golden_every_registry_renders():
         MESH.counter(name).inc(0)
     for name in ("devices", "depth", "queue_depth", "batch_fill_pct",
                  "inflight_depth", "inflight_per_device",
-                 "max_inflight_depth"):
+                 "max_inflight_depth", "output_shards"):
         MESH.gauge(name).set(0)
     MESH.histogram("queue_wait_seconds").observe(0.0)
     MESH.histogram("dispatch_seconds").observe(0.0)
@@ -267,7 +267,8 @@ def test_prometheus_text_golden_every_registry_renders():
                  "mesh_staging_reuses", "mesh_devices", "mesh_depth",
                  "mesh_queue_depth", "mesh_batch_fill_pct",
                  "mesh_inflight_depth", "mesh_inflight_per_device",
-                 "mesh_max_inflight_depth", "mesh_queue_wait_seconds",
+                 "mesh_max_inflight_depth", "mesh_output_shards",
+                 "mesh_queue_wait_seconds",
                  "mesh_dispatch_seconds",
                  "replication_keys_shipped", "replication_bytes_shipped",
                  "replication_deletes_shipped", "replication_conflicts",

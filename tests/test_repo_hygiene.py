@@ -2,7 +2,10 @@
 
 A `__pycache__` directory committed alongside source (PR 15 removed a
 batch of them) poisons review diffs and ships stale bytecode that
-shadows edited modules on some import paths; this pins the cleanup."""
+shadows edited modules on some import paths; this pins the cleanup.
+Native libraries and the compile cache are made at run time on the
+machine that uses them: a tracked `-march=native` .so can SIGILL on
+another CPU, and a tracked cache is keyed to a path that moved."""
 
 import subprocess
 from pathlib import Path
@@ -27,3 +30,13 @@ def test_no_bytecode_tracked():
 def test_gitignore_covers_bytecode():
     text = (REPO / ".gitignore").read_text()
     assert "__pycache__" in text and "*.pyc" in text
+
+
+def test_no_native_or_compile_cache_artifacts_tracked():
+    bad = [f for f in _tracked()
+           if f.endswith((".so", ".so.stamp")) or ".jax_cache" in f
+           or f.startswith("chiprun_out/")]
+    assert not bad, f"run-time artifacts tracked in git: {bad[:10]}"
+    text = (REPO / ".gitignore").read_text().split()
+    for pattern in ("*.so", "*.so.stamp", ".jax_cache/", "chiprun_out/"):
+        assert pattern in text, f".gitignore lacks {pattern}"

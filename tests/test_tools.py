@@ -1,6 +1,8 @@
 """Freon generators + CLI tests against a loopback gRPC cluster."""
 
 import json
+import time
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -54,6 +56,107 @@ def test_freon_ockg_and_read(cluster):
     rep3 = freon.ockrr(oz, 20, threads=3, size=1500, n_keys=12)
     s3 = rep3.summary()
     assert s3["ops"] == 20 and s3["failures"] == 0
+    # every summary names what its codec work ran on
+    dev = s["device"]
+    assert (dev["platform"], dev["device_count"]) == ("cpu", 8)
+    # (the choice record is per process: earlier tests may have forced
+    # the other path too)
+    assert set(dev["fused_backend"].split("+")) <= {"native", "jax"}
+    assert dev["dispatches"] > 0 and dev["stripes_dispatched"] >= 12
+
+
+def test_cli_freon_exit_code_follows_failures(cluster, capsys):
+    """`freon` exits 1 when its summary counts a failed op: ockv with
+    the size the keys were written at validates clean, with another
+    size every comparison fails — and the summary says what failed."""
+    meta, dns = cluster
+    om = meta.address
+    assert cli_main(["freon", "ockg", "-n", "3", "-s", "5000", "-t", "2",
+                     "--om", om, "--replication", "rs-3-2-4096"]) == 0
+    capsys.readouterr()
+    assert cli_main(["freon", "ockv", "-n", "3", "-s", "5000",
+                     "--om", om]) == 0
+    ok = json.loads(capsys.readouterr().out)
+    assert ok["failures"] == 0
+    # the entry point that reports the compile counters starts them
+    assert {"compiles", "cache_hits", "cache_writes"} <= set(ok["device"])
+    assert cli_main(["freon", "ockv", "-n", "3", "-s", "4999",
+                     "--om", om]) == 1
+    bad = json.loads(capsys.readouterr().out)
+    assert bad["failures"] == 3 and "corrupt key" in bad["first_error"]
+
+
+def test_freon_ecrd_verifies_rebuilt_bytes(tmp_path, monkeypatch):
+    """ecrd reads every rebuilt replica straight off its target
+    datanode and compares bytes and stored CRCs with what was written
+    (a read through ECBlockGroupReader would decode around a bad
+    replica and pass anything): clean rounds report failures == 0 with
+    the bytes rebuilt and compared; a coordinator that rebuilds nothing,
+    or the wrong bytes under self-consistent CRCs, fails every round."""
+    from ozone_tpu.client.dn_client import DatanodeClientFactory
+    from ozone_tpu.client.ozone_client import OzoneClient
+    from ozone_tpu.net.om_service import GrpcOmClient
+    from ozone_tpu.net.scm_service import GrpcScmClient
+    from ozone_tpu.storage import reconstruction
+
+    meta = ScmOmDaemon(tmp_path / "om.db", block_size=8 * 4096,
+                       container_size=4 * 1024 * 1024,
+                       stale_after_s=1000.0, dead_after_s=2000.0)
+    meta.start()
+    dns = [DatanodeDaemon(tmp_path / f"dn{i}", f"dn{i}", meta.address,
+                          heartbeat_interval_s=0.5) for i in range(5)]
+    for d in dns:
+        d.start()
+    try:
+        clients = DatanodeClientFactory()
+        oz = OzoneClient(GrpcOmClient(meta.address, clients=clients),
+                         clients)
+        scm = GrpcScmClient(meta.address)
+
+        def drill():
+            # ecrd closes its containers on the datanodes directly; the
+            # SCM stops allocating into them once heartbeats report it
+            time.sleep(1.5)
+            return freon.ecrd(oz, scm, size=60_000, rounds=2,
+                              replication="rs-3-2-4096")
+
+        out = drill()
+        assert out["failures"] == 0 and out["rounds"] == 2
+        # 60_000 B over k=3 cells of 4096: unit 1 holds 5 cells = 20480 B
+        assert out["bytes_verified"] == 2 * 20_480
+        assert out["bytes_reconstructed"] >= out["bytes_verified"]
+        assert out["repair_stripes"] >= 2 * 5
+        assert out["device"]["platform"] == "cpu"
+        assert out["generator"] == "ecrd"  # as every freon summary
+
+        # rebuilds the wrong bytes, checksummed as such: the target
+        # takes them, and only a comparison with what was written tells
+        real_pairs = reconstruction.build_chunk_pairs
+
+        def wrong_pairs(block_id, sb, cells, crcs, *rest):
+            return real_pairs(block_id, sb, np.asarray(cells) ^ 1,
+                              crcs[..., :0], *rest)
+
+        with monkeypatch.context() as m:
+            m.setattr(reconstruction, "build_chunk_pairs", wrong_pairs)
+            bad = drill()
+        assert bad["failures"] == 2 and bad["bytes_verified"] == 0
+        assert "bytes differ" in bad["first_error"]
+        assert bad["bytes_reconstructed"] >= 2 * 20_480
+
+        # rebuilds nothing at all
+        with monkeypatch.context() as m:
+            m.setattr(reconstruction.ECReconstructionCoordinator,
+                      "reconstruct_container_group",
+                      lambda self, cmd: None)
+            noop = drill()
+        assert noop["failures"] == 2 and noop["bytes_verified"] == 0
+        assert noop["bytes_reconstructed"] == 0
+        assert noop["repair_dispatches"] == 0
+    finally:
+        for d in dns:
+            d.stop()
+        meta.stop()
 
 
 def test_freon_rawcoder_matrix():
@@ -541,41 +644,101 @@ def test_resilience_lint_no_hardcoded_timeouts_or_retry_sleeps():
     assert not findings, format_findings(findings)
 
 
-def test_libdatapath_rebuild_staleness():
-    """The native datapath .so must never be served stale: after
-    load_lib() the cached libdatapath.so is at least as new as
-    datapath.cpp, and build_shared's mtime probe recompiles an aged
-    artifact instead of loading it."""
+def test_native_build_stamp_and_concurrency(tmp_path):
+    """The native build must survive N processes and a moved checkout
+    (native.build_shared): freshness is a stamp of source + flags + CPU,
+    not mtimes; the build lands atomically under a cross-process flock,
+    so concurrent first users compile once; a failing compile raises."""
     import os
     import shutil
+    import subprocess
+    import sys
 
-    from ozone_tpu.native import build_shared
-    from ozone_tpu.storage.fast_datapath import _SO, _SRC, load_lib
+    from ozone_tpu.native import NativeBuildError, build_shared
+    from ozone_tpu.storage.fast_datapath import _SO, load_lib
 
     if shutil.which("g++") is None:
-        pytest.skip("no C++ toolchain: native datapath runs as gRPC "
-                    "fallback; staleness check needs a compiler")
+        pytest.skip("no C++ toolchain")
     assert load_lib() is not None
-    assert _SO.stat().st_mtime >= _SRC.stat().st_mtime, \
-        "libdatapath.so is older than datapath.cpp — load_lib served " \
-        "a stale build"
+    assert _SO.with_name(_SO.name + ".stamp").exists()
 
-    # rebuild mechanics on a tiny source (sub-second compile)
-    import tempfile
-    from pathlib import Path
+    # a counting compiler wrapper: how many real compiles happened
+    count = tmp_path / "compiles"
+    cxx = tmp_path / "cxx"
+    cxx.write_text(f'#!/bin/sh\necho x >> {count}\nexec g++ "$@"\n')
+    cxx.chmod(0o755)
+    src = tmp_path / "probe.cpp"
+    src.write_text('extern "C" int probe() { return 1; }\n')
+    so = tmp_path / "libprobe.so"
+    stamp = tmp_path / "libprobe.so.stamp"
 
-    with tempfile.TemporaryDirectory() as td:
-        src = Path(td) / "probe.cpp"
-        src.write_text('extern "C" int probe() { return 1; }\n')
-        so = Path(td) / "libprobe.so"
-        assert build_shared(src, so) is not None
-        built = so.stat().st_mtime_ns
-        # age the artifact behind its source: must recompile, not reuse
-        os.utime(so, ns=(built - 10**10, built - 10**10))
-        src.write_text('extern "C" int probe() { return 2; }\n')
-        assert build_shared(src, so) is not None
-        assert so.stat().st_mtime_ns > built - 10**10, \
-            "build_shared reused a stale .so"
+    def compiles() -> int:
+        return len(count.read_text().split()) if count.exists() else 0
+
+    # N processes racing on a fresh checkout: one compile, all succeed
+    racers = [subprocess.Popen(
+        [sys.executable, "-c",
+         "import sys; from pathlib import Path\n"
+         "from ozone_tpu.native import build_shared\n"
+         "assert build_shared(Path(sys.argv[1]), Path(sys.argv[2]), "
+         "compiler=sys.argv[3]) is not None"
+         , str(src), str(so), str(cxx)],
+        cwd=str(Path(__file__).resolve().parent.parent))
+        for _ in range(4)]
+    assert [p.wait(timeout=120) for p in racers] == [0] * 4
+    assert compiles() == 1
+    assert not list(tmp_path.glob(".*.tmp"))
+
+    # fresh: reused, however old the artifact looks next to its source
+    built = so.stat().st_mtime_ns
+    os.utime(so, ns=(built - 10**10, built - 10**10))
+    assert build_shared(src, so, compiler=str(cxx)) == so
+    assert compiles() == 1
+    # a .so that arrived from another machine (stamp names another CPU)
+    # is NEWER than its source and must still be rebuilt
+    stamp.write_text("0" * 64)
+    os.utime(so)
+    assert build_shared(src, so, compiler=str(cxx)) == so
+    assert compiles() == 2
+    # changed source: rebuilt
+    src.write_text('extern "C" int probe() { return 2; }\n')
+    assert build_shared(src, so, compiler=str(cxx)) == so
+    assert compiles() == 3
+    # a toolchain that fails is an error, and leaves the old .so alone
+    src.write_text("this is not C++\n")
+    with pytest.raises(NativeBuildError):
+        build_shared(src, so, compiler=str(cxx))
+    assert so.exists() and not list(tmp_path.glob(".*.tmp"))
+    # no toolchain at all: None (the caller goes without the backend)
+    assert build_shared(src, so, compiler="no-such-compiler") is None
+
+
+@pytest.mark.parametrize("loader", ["coder", "datapath"])
+def test_native_loaders_raise_on_a_failed_build(loader, monkeypatch):
+    """A compile that fails is the loader's error too — never the
+    'unavailable' that no toolchain means — and stays one: the next
+    call in the same process raises again instead of returning None."""
+    from ozone_tpu import native
+    from ozone_tpu.native import NativeBuildError
+    from ozone_tpu.storage import fast_datapath
+
+    mod, fn, flags = {
+        "coder": (native, native.load, ("_lib", "_tried")),
+        "datapath": (fast_datapath, fast_datapath.load_lib,
+                     ("_lib", "_lib_tried")),
+    }[loader]
+    monkeypatch.setattr(mod, flags[0], None)
+    monkeypatch.setattr(mod, flags[1], False)
+
+    def failing(*_a, **_kw):
+        raise NativeBuildError("compile failed")
+
+    monkeypatch.setattr(mod, "build_shared", failing)
+    for _ in range(2):
+        with pytest.raises(NativeBuildError):
+            fn()
+    monkeypatch.setattr(mod, "build_shared", lambda *_a, **_kw: None)
+    assert fn() is None  # no toolchain: goes without
 
 
 def test_cli_version_and_getconf(capsys):
