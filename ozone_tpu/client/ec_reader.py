@@ -497,6 +497,16 @@ class ECBlockGroupReader:
         batched decode replans around it — hedging into the decode
         pipeline instead of waiting the straggler out. Without a spare
         the read must wait (the straggler is the k-th survivor)."""
+        # the fan-in as a stage of its own: what the unit reads
+        # (net:read_chunks, on the pool) do not cover — each cell's
+        # copy into the decode batch, the pool's hand-offs — is the
+        # fan-in's own time, not the caller's
+        with Tracer.instance().span("ec:fanout", units=len(valid),
+                                    stripes=depth):
+            self._fanout_traced(pool, fill_unit, valid, depth)
+
+    def _fanout_traced(self, pool, fill_unit, valid: list[int],
+                       depth: int) -> None:
         from concurrent.futures import wait as fwait
 
         nodes = self.group.pipeline.nodes

@@ -255,8 +255,9 @@ class OzoneBucket:
         # and the commit — each hop times out on the remaining budget.
         # The root span is the flight recorder's SLO unit for a PUT.
         t0 = time.perf_counter()
-        with Tracer.instance().span("client:put", volume=self.volume,
-                                    bucket=self.name, key=key) as sp:
+        with Tracer.instance().operation(
+                "client:put", volume=self.volume, bucket=self.name,
+                key=key) as sp:
             with resilience.start("key_write"):
                 # tiny-object routing: only for scheme-default writes on
                 # an opted-in bucket (an explicit per-key replication
@@ -282,7 +283,12 @@ class OzoneBucket:
                         return
                 with self.open_key(key, replication,
                                    metadata=metadata) as h:
-                    h.write(data)
+                    # the payload's way into the writer (for an EC key,
+                    # slicing it into cells) as a stage of its own; what
+                    # is then left to the root is the close path's own
+                    # work (the batch's np.stack, waking on results)
+                    with Tracer.instance().span("client:write"):
+                        h.write(data)
         METRICS.histogram("put_seconds").observe(
             time.perf_counter() - t0, sp.trace_id)
 
@@ -330,10 +336,9 @@ class OzoneBucket:
             raise ValueError(f"range [{offset},{offset + length}) out of "
                              f"bounds for size {size}")
         t0 = time.perf_counter()
-        with Tracer.instance().span("client:get", volume=self.volume,
-                                    bucket=self.name,
-                                    key=info.get("key", ""),
-                                    bytes=length) as sp:
+        with Tracer.instance().operation(
+                "client:get", volume=self.volume, bucket=self.name,
+                key=info.get("key", ""), bytes=length) as sp:
             with resilience.start("key_read"):
                 if info.get("inline") is not None:
                     out = self._read_inline(info, offset, length)

@@ -61,7 +61,7 @@ from ozone_tpu.codec.pipeline import _start_d2h
 from ozone_tpu.storage.ids import StorageError
 from ozone_tpu.utils.config import env_float
 from ozone_tpu.utils.metrics import MetricsRegistry, registry
-from ozone_tpu.utils.tracing import Tracer
+from ozone_tpu.utils.tracing import Stage, Tracer
 
 log = logging.getLogger(__name__)
 
@@ -78,6 +78,10 @@ DEFAULT_STARVE_MS = 250.0
 DEFAULT_QOS = {"interactive": 4.0, "bulk": 1.0}
 #: seed for the dispatch-time EWMA before the first dispatch lands
 _DISPATCH_EWMA_SEED_S = 0.005
+#: an idle dispatcher books its wait at least this often, so that
+#: `idle_seconds` advances while it waits: a scrape, a benchmark window
+#: or a profiler session that opens mid-wait is off by at most one tick
+_IDLE_TICK_S = 0.05
 
 
 def enabled() -> bool:
@@ -440,7 +444,15 @@ class CodecService:
                             entries, rows = self._pack_locked(
                                 lane, reason)
                         else:
-                            self._cond.wait(self._next_wakeup_locked(now))
+                            # starved: no lane ready (nothing queued, or
+                            # a partial batch lingering), nothing in
+                            # flight to complete
+                            wake = self._next_wakeup_locked(now)
+                            with Stage("codec:idle",
+                                       METRICS.histogram("idle_seconds")):
+                                self._cond.wait(
+                                    _IDLE_TICK_S if wake is None
+                                    else min(wake, _IDLE_TICK_S))
                             continue
                 if spilled:
                     # outside the lock: absorption resolves (and may
@@ -470,64 +482,72 @@ class CodecService:
 
     def _dispatch(self, lane: _Lane, entries, rows: int,
                   reason: str) -> None:
-        now = time.monotonic()
-        now_wall = time.time()
-        ops = len(entries)
-        tracer = Tracer.instance()
-        # one shared dispatch span id per device dispatch: every
-        # coalesced submission's span tags it, making cross-request
-        # batching visible from any participating trace
-        d_tid, d_sid = tracer._new_id(), tracer._new_id()
-        fill_pct = round(100.0 * rows / lane.width, 1)
-        lane_desc = str(lane.lane_key)[:120]
-        with self._lock:
-            # fairness accounting under the lock: submit()'s SFQ
-            # activation floor does a read-modify-write of the same
-            # vtime entries from other threads
+        # the host work before the launch: fairness accounting, closing
+        # out the riders' queue waits, the staging copy
+        with Stage("codec:pack", METRICS.histogram("pack_seconds")):
+            now = time.monotonic()
+            ops = len(entries)
+            tracer = Tracer.instance()
+            # one shared dispatch span id per device dispatch: every
+            # coalesced submission's span tags it, making cross-request
+            # batching visible from any participating trace
+            d_tid, d_sid = tracer._new_id(), tracer._new_id()
+            fill_pct = round(100.0 * rows / lane.width, 1)
+            lane_desc = str(lane.lane_key)[:120]
+            with self._lock:
+                # fairness accounting under the lock: submit()'s SFQ
+                # activation floor does a read-modify-write of the same
+                # vtime entries from other threads
+                for sub, off, take, _row in entries:
+                    w = self.weights.get(sub.cls, 1.0)
+                    self._vtime[sub.cls] = \
+                        self._vtime.get(sub.cls, 0.0) + take / w
             for sub, off, take, _row in entries:
-                w = self.weights.get(sub.cls, 1.0)
-                self._vtime[sub.cls] = \
-                    self._vtime.get(sub.cls, 0.0) + take / w
-        for sub, off, take, _row in entries:
-            if off == 0:
-                wait = now - sub.t_enq
-                tid = sub.trace_ctx.split(":", 1)[0]
-                METRICS.histogram("queue_wait_seconds").observe(wait, tid)
-                METRICS.histogram(
-                    f"queue_wait_{sub.cls}_seconds").observe(wait, tid)
-                if sub.trace_ctx:
-                    tracer.record_span(
-                        "codec:queue_wait", child_of=sub.trace_ctx,
-                        start=sub.t_enq_wall, duration=wait,
-                        lane=lane_desc, qos=sub.cls, fill_pct=fill_pct,
-                        dispatch_span=d_sid)
-                if sub.tail:
-                    METRICS.counter("tail_flushes").inc()
-        head = entries[0]
-        if ops == 1 and head[2] == rows == lane.width:
-            # one submission covering the whole batch: dispatch its own
-            # (contiguous) rows without a staging copy — the bulk-sweep
-            # fast path, byte-identical to the pre-service pipeline
-            sub, off, take, _ = head
-            batch = sub.stripes[off:off + take]
-            if not batch.flags.c_contiguous:
-                batch = np.ascontiguousarray(batch)
-        else:
-            shape = (lane.width,) + tuple(head[0].stripes.shape[1:])
-            batch = np.zeros(shape, dtype=head[0].stripes.dtype)
-            for sub, off, take, row in entries:
-                batch[row:row + take] = sub.stripes[off:off + take]
+                if off == 0:
+                    wait = now - sub.t_enq
+                    tid = sub.trace_ctx.split(":", 1)[0]
+                    METRICS.histogram("queue_wait_seconds").observe(
+                        wait, tid)
+                    METRICS.histogram(
+                        f"queue_wait_{sub.cls}_seconds").observe(wait, tid)
+                    if sub.trace_ctx:
+                        tracer.record_span(
+                            "codec:queue_wait", child_of=sub.trace_ctx,
+                            start=sub.t_enq_wall, duration=wait,
+                            mono=sub.t_enq, lane=lane_desc, qos=sub.cls,
+                            fill_pct=fill_pct, dispatch_span=d_sid)
+                    if sub.tail:
+                        METRICS.counter("tail_flushes").inc()
+            head = entries[0]
+            if ops == 1 and head[2] == rows == lane.width:
+                # one submission covering the whole batch: dispatch its
+                # own (contiguous) rows without a staging copy — the
+                # bulk-sweep fast path, byte-identical to the pre-service
+                # pipeline
+                sub, off, take, _ = head
+                batch = sub.stripes[off:off + take]
+                if not batch.flags.c_contiguous:
+                    batch = np.ascontiguousarray(batch)
+            else:
+                shape = (lane.width,) + tuple(head[0].stripes.shape[1:])
+                batch = np.zeros(shape, dtype=head[0].stripes.dtype)
+                for sub, off, take, row in entries:
+                    batch[row:row + take] = sub.stripes[off:off + take]
         t0 = time.monotonic()
+        t0_wall = time.time()
         try:
-            outs = lane.fn(batch)
+            # the implicit H2D and the enqueue
+            with Stage("codec:launch",
+                       METRICS.histogram("launch_seconds")):
+                outs = lane.fn(batch)
+                if not isinstance(outs, tuple):
+                    outs = (outs,)
+                for a in outs:
+                    # eager D2H under the next batch's host work
+                    _start_d2h(a)
         except BaseException as e:  # noqa: BLE001 - per-dispatch fault
             self._resolve_error(entries, e)
             return
-        if not isinstance(outs, tuple):
-            outs = (outs,)
-        for a in outs:
-            # eager D2H under the next batch's host work
-            _start_d2h(a)
         METRICS.counter("dispatches").inc()
         METRICS.counter("stripes_dispatched").inc(rows)
         METRICS.counter("slots_dispatched").inc(lane.width)
@@ -542,7 +562,7 @@ class CodecService:
         METRICS.gauge("last_coalesced_operations").set(ops)
         with self._lock:
             METRICS.gauge("queue_depth").set(self._queue_depth_locked())
-        self._inflight.append((entries, outs, t0, time.time(),
+        self._inflight.append((entries, outs, t0, t0_wall,
                                (d_tid, d_sid, fill_pct, reason,
                                 lane_desc, ops, rows, lane.width)))
 
@@ -550,7 +570,8 @@ class CodecService:
         entries, outs, t0, t0_wall, dctx = rec
         d_tid, d_sid, fill_pct, reason, lane_desc, ops, rows, width = dctx
         try:
-            host = tuple(np.asarray(a) for a in outs)
+            with Stage("codec:d2h", METRICS.histogram("d2h_seconds")):
+                host = tuple(np.asarray(a) for a in outs)
         except BaseException as e:  # noqa: BLE001 - D2H fault
             self._resolve_error(entries, e)
             return
@@ -562,7 +583,7 @@ class CodecService:
         # the shared dispatch span (own trace, id known to every rider)
         tracer.record_span(
             "codec:device_dispatch", child_of=f"{d_tid}:",
-            span_id=d_sid, start=t0_wall, duration=dt,
+            span_id=d_sid, start=t0_wall, duration=dt, mono=t0,
             lane=lane_desc, ops=ops, rows=rows, width=width,
             fill_pct=fill_pct, reason=reason)
         for sub, off, take, _row in entries:
@@ -572,9 +593,10 @@ class CodecService:
             if sub.trace_ctx:
                 tracer.record_span(
                     "codec:dispatch", child_of=sub.trace_ctx,
-                    start=t0_wall, duration=dt, lane=lane_desc,
-                    qos=sub.cls, stripes=take, fill_pct=fill_pct,
-                    dispatch_span=d_sid, dispatch_trace=d_tid)
+                    start=t0_wall, duration=dt, mono=t0,
+                    lane=lane_desc, qos=sub.cls, stripes=take,
+                    fill_pct=fill_pct, dispatch_span=d_sid,
+                    dispatch_trace=d_tid)
         for sub, off, take, row in entries:
             sub.parts.append(
                 (off, take, tuple(a[row:row + take] for a in host)))
@@ -629,6 +651,15 @@ class CodecService:
                               if slots else 0.0)
         snap["ops_per_dispatch"] = (
             snap.get("coalesced_operations", 0) / disp if disp else 0.0)
+        # where the ONE dispatcher thread's time went since start: a
+        # large idle share says it is starved, a large pack / launch /
+        # d2h share says which of its own stages paces the chip; `hold`
+        # is how long batches sat in the double buffer
+        took = {k: METRICS.histogram(f"{k}_seconds").total
+                for k in ("idle", "pack", "launch", "d2h")}
+        took["hold"] = max(0.0, METRICS.histogram("dispatch_seconds").total
+                           - took["launch"] - took["d2h"])
+        snap["dispatcher_seconds"] = took
         with self._lock:
             snap["queue_depth"] = self._queue_depth_locked()
             snap["lanes"] = len(self._lanes)

@@ -45,6 +45,7 @@ from ozone_tpu.storage.ids import (
 )
 from ozone_tpu.utils.checksum import Checksum, ChecksumType
 from ozone_tpu.utils.metrics import MetricsRegistry
+from ozone_tpu.utils.tracing import Tracer
 
 log = logging.getLogger(__name__)
 
@@ -103,28 +104,33 @@ class ECReconstructionCoordinator:
         # reconstruction-job boundary: one deadline (operator opt-in via
         # OZONE_TPU_OP_DEADLINE_S) covers listing, every block's
         # recover+write chain, and the target close/cleanup
-        with resilience.start("reconstruction"):
-            self._reconstruct_container_group(cmd)
+        # The root span is the flight recorder's unit for a repair.
+        with Tracer.instance().operation(
+                "repair:container", container=cmd.container_id,
+                lost=sorted(cmd.targets)) as sp, \
+                resilience.start("reconstruction"):
+            sp.tags["bytes"] = self._reconstruct_container_group(cmd)
 
     def _reconstruct_container_group(self,
-                                     cmd: ReconstructionCommand) -> None:
-        opts = cmd.replication
-        n = opts.all_units
+                                     cmd: ReconstructionCommand) -> int:
+        """Returns the bytes rebuilt onto the targets."""
+        tracer = Tracer.instance()
         targets = sorted(cmd.targets)
         created: list[tuple[str, int]] = []
         try:
-            # 2. RECOVERING containers on targets
-            for idx in targets:
-                dn = cmd.targets[idx]
-                self.clients.get(dn).create_container(
-                    cmd.container_id,
-                    replica_index=idx,
-                    state=ContainerState.RECOVERING,
-                )
-                created.append((dn, idx))
+            with tracer.span("repair:prepare"):
+                # 2. RECOVERING containers on targets
+                for idx in targets:
+                    dn = cmd.targets[idx]
+                    self.clients.get(dn).create_container(
+                        cmd.container_id,
+                        replica_index=idx,
+                        state=ContainerState.RECOVERING,
+                    )
+                    created.append((dn, idx))
 
-            # 1. block list from any source
-            blocks = self._list_blocks(cmd)
+                # 1. block list from any source
+                blocks = self._list_blocks(cmd)
 
             # 3.-4. per block: recover + write + putBlock. Independent
             # chains run through a small pool so survivor reads of one
@@ -133,22 +139,31 @@ class ECReconstructionCoordinator:
             if self.max_parallel_blocks > 1 and len(blocks) > 1:
                 from concurrent.futures import ThreadPoolExecutor
 
+                # pool threads start with no span of their own: carry
+                # the deadline and the trace context over, as
+                # ec_writer._act does
+                deadline, ctx = resilience.current(), tracer.inject()
+
+                def one(bd: BlockData) -> int:
+                    with resilience.activate(deadline), \
+                            tracer.activate(ctx):
+                        return self._reconstruct_block(cmd, bd, targets)
+
                 with ThreadPoolExecutor(
                         max_workers=self.max_parallel_blocks,
                         thread_name_prefix="ec-recon") as pool:
-                    list(pool.map(
-                        lambda bd: self._reconstruct_block(
-                            cmd, bd, targets), blocks))
+                    rebuilt = sum(pool.map(one, blocks))
             else:
-                for bd in blocks:
-                    self._reconstruct_block(cmd, bd, targets)
+                rebuilt = sum(self._reconstruct_block(cmd, bd, targets)
+                              for bd in blocks)
 
-            # close targets
-            for idx in targets:
-                self.clients.get(cmd.targets[idx]).close_container(
-                    cmd.container_id
-                )
+            with tracer.span("repair:close"):
+                for idx in targets:
+                    self.clients.get(cmd.targets[idx]).close_container(
+                        cmd.container_id
+                    )
             self.metrics.counter("groups_reconstructed").inc()
+            return rebuilt
         except Exception:
             # 5. cleanup RECOVERING containers on failure
             for dn, _idx in created:
@@ -202,7 +217,19 @@ class ECReconstructionCoordinator:
 
     def _reconstruct_block(
         self, cmd: ReconstructionCommand, bd: BlockData, targets: list[int]
-    ) -> None:
+    ) -> int:
+        """Rebuild one block group's lost units; returns their bytes.
+        Under `repair:block` the reader spans its survivor reads
+        (net:read_chunks) and its decode (codec:queue_wait,
+        codec:dispatch); `repair:write` is the targets' share."""
+        with Tracer.instance().span("repair:block",
+                                    block=bd.block_id.local_id):
+            return self._reconstruct_block_traced(cmd, bd, targets)
+
+    def _reconstruct_block_traced(
+        self, cmd: ReconstructionCommand, bd: BlockData, targets: list[int]
+    ) -> int:
+        tracer = Tracer.instance()
         opts = cmd.replication
         cell = opts.cell_size
         bpc = effective_bpc(cell, self.bpc)
@@ -242,18 +269,23 @@ class ECReconstructionCoordinator:
                     # one batched stream per rebuilt unit per batch when
                     # the target serves it, per-chunk verbs against
                     # older/pre-finalize targets
-                    write_unit_stream(
-                        self.clients.get(cmd.targets[idx]),
-                        group.block_id, pairs)
+                    with tracer.span("repair:write", unit=u,
+                                     chunks=len(pairs)):
+                        write_unit_stream(
+                            self.clients.get(cmd.targets[idx]),
+                            group.block_id, pairs)
 
+        rebuilt = 0
         for ti, idx in enumerate(targets):
             dn = self.clients.get(cmd.targets[idx])
             infos = [written[ti][s] for s in sorted(written[ti])]
-            dn.put_block(BlockData(
-                group.block_id, infos,
-                block_group_length=group.length,
-            ))
+            with tracer.span("repair:write", unit=idx - 1, commit=True):
+                dn.put_block(BlockData(
+                    group.block_id, infos,
+                    block_group_length=group.length,
+                ))
+            nbytes = sum(i.length for i in infos)
+            rebuilt += nbytes
             self.metrics.counter("blocks_reconstructed").inc()
-            self.metrics.counter("bytes_reconstructed").inc(
-                sum(i.length for i in infos)
-            )
+            self.metrics.counter("bytes_reconstructed").inc(nbytes)
+        return rebuilt

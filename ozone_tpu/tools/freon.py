@@ -139,6 +139,7 @@ def _client_hist_extras() -> dict:
     record what the monitoring plane will actually see (bucket-quantile
     estimates over every op since process start, warmups included)."""
     from ozone_tpu.client.ozone_client import METRICS as client_ops
+    from ozone_tpu.utils.tracing import Tracer
 
     out: dict = {}
     for verb in ("put", "get"):
@@ -147,6 +148,11 @@ def _client_hist_extras() -> dict:
             out[f"hist_{verb}_ms"] = {
                 p: round(1e3 * v, 3)
                 for p, v in h.percentiles().items()}
+    # where the mean operation spent its time: critical-path ms per
+    # stage over this process's PUTs / GETs / repairs (warm-ups included)
+    stages = Tracer.instance().recorder.stage_means()
+    if stages:
+        out["op_stage_ms"] = stages
     return out
 
 

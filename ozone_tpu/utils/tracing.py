@@ -8,15 +8,23 @@ GrpcServerInterceptor propagate it). Here spans are collected in-process
 (ring buffer, queryable/exportable) and the context string rides the
 net/wire.py JSON header under "traceId"; the RPC layer injects/extracts
 automatically.
+
+Clocks: a span's `start` is wall-clock time, because it is exported
+and laid beside other processes' spans; its `duration`, and everything
+attributed inside one process (the per-operation stage record), comes
+from `time.monotonic()`, the clock a load generator's window is on.
+`stage()` brackets a leaf interval of a loop that owns its thread and
+mirrors it to the JAX profiler, the device trace's clock.
 """
 
 from __future__ import annotations
 
 import random
 import re
+import sys
 import threading
 import time
-from collections import deque
+from collections import OrderedDict, deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Optional
@@ -36,6 +44,17 @@ class Span:
     #: point-in-time annotations ({"t", "name", ...attrs}); retry /
     #: hedge / breaker decisions land here rather than as child spans
     events: list = field(default_factory=list)
+    #: start on time.monotonic() (this process only; never exported)
+    mono: float = 0.0
+    #: opened by Tracer.operation(): as a root it leaves a stage record
+    op: bool = False
+
+
+#: traces with finished spans whose root has not finished here yet (in
+#: a daemon most never will: their root is the caller's), and the spans
+#: kept of any one of them; the oldest trace goes first
+MAX_OPEN_TRACES = 1024
+MAX_TRACE_SPANS = 4096
 
 
 class Tracer:
@@ -43,14 +62,17 @@ class Tracer:
 
     _instance: Optional["Tracer"] = None
 
-    def __init__(self, max_spans: int = 10_000, sample_rate: float = 1.0):
+    def __init__(self, max_spans: int = 10_000):
         self.spans: deque[Span] = deque(maxlen=max_spans)
-        self.sample_rate = sample_rate
         self._lock = threading.Lock()
         #: filled by an attached SpanExporter; None = local-only mode
         self._export_q: Optional[deque] = None
-        #: tail-based slow-trace retention (per-op SLO, env-tunable)
+        #: tail-based slow-trace retention (per-op SLO, env-tunable) and
+        #: the per-operation stage records
         self.recorder = FlightRecorder()
+        #: trace id -> its finished spans, handed to the recorder when
+        #: the root finishes: a root never scans the span ring
+        self._open: "OrderedDict[str, list[Span]]" = OrderedDict()
 
     @classmethod
     def instance(cls) -> "Tracer":
@@ -66,6 +88,17 @@ class Tracer:
         return getattr(_local, "span", None)
 
     @contextmanager
+    def operation(self, name: str, **tags):
+        """A span around one user-visible operation (a PUT, a GET, a
+        container repair). Where it is the root of its trace, the
+        flight recorder keeps its stage record (FlightRecorder.
+        operations) whether or not it was slow; nested under another
+        operation it is an ordinary child."""
+        with self.span(name, **tags) as s:
+            s.op = True
+            yield s
+
+    @contextmanager
     def span(self, name: str, child_of: Optional[str] = None, **tags):
         """Start a span; child_of is an imported context string
         ("traceid:spanid") from a remote caller."""
@@ -77,36 +110,46 @@ class Tracer:
         else:
             trace_id, parent_id = self._new_id(), ""
         s = Span(trace_id, self._new_id(), parent_id, name, time.time(),
-                 tags=dict(tags))
+                 tags=dict(tags), mono=time.monotonic())
         prev = self.current()
         _local.span = s
         try:
             yield s
         finally:
-            s.duration = time.time() - s.start
+            s.duration = time.monotonic() - s.mono
             _local.span = prev
             self._finish(s)
 
     def _finish(self, s: Span) -> None:
-        if random.random() < self.sample_rate:
-            with self._lock:
-                self.spans.append(s)
-                if self._export_q is not None:
-                    self._export_q.append(s)
-            if not s.parent_id:
-                # root finished last: the whole local trace is in the
-                # buffer, so tail-based retention can decide now
-                self.recorder.offer(s, self.traces(s.trace_id))
+        with self._lock:
+            self.spans.append(s)
+            if self._export_q is not None:
+                self._export_q.append(s)
+            if s.parent_id:
+                held = self._open.get(s.trace_id)
+                if held is None:
+                    held = self._open[s.trace_id] = []
+                    if len(self._open) > MAX_OPEN_TRACES:
+                        self._open.popitem(last=False)
+                if len(held) < MAX_TRACE_SPANS:
+                    held.append(s)
+                return
+            held = self._open.pop(s.trace_id, [])
+        # the root finished last: `held` is the whole local trace
+        held.append(s)
+        self.recorder.root_finished(s, held)
 
     def record_span(self, name: str, *, child_of: str = "",
                     start: float, duration: float, span_id: str = "",
-                    **tags) -> Span:
+                    mono: Optional[float] = None, **tags) -> Span:
         """Record an already-measured interval as a finished span.
 
         Needed where the measuring thread is not the owning thread —
         e.g. the codec-service dispatcher closing out a submission's
         queue-wait on behalf of the submitting request — so a
-        contextmanager span can't bracket the interval."""
+        contextmanager span can't bracket the interval. `mono` is the
+        interval's start on time.monotonic(); without it the interval
+        is taken to have just ended."""
         if child_of:
             trace_id, parent_id = (child_of.split(":") + [""])[:2]
         else:
@@ -115,8 +158,10 @@ class Tracer:
                 trace_id, parent_id = cur.trace_id, cur.span_id
             else:
                 trace_id, parent_id = self._new_id(), ""
+        if mono is None:
+            mono = time.monotonic() - duration
         s = Span(trace_id, span_id or self._new_id(), parent_id, name,
-                 start, duration, tags=dict(tags))
+                 start, duration, tags=dict(tags), mono=mono)
         self._finish(s)
         return s
 
@@ -163,19 +208,52 @@ class Tracer:
             out = [s for s in out if s.trace_id == trace_id]
         return out
 
-    def export_json(self) -> list[dict]:
-        return [
-            {
-                "traceId": s.trace_id,
-                "spanId": s.span_id,
-                "parentId": s.parent_id,
-                "name": s.name,
-                "start": s.start,
-                "durationMs": round(s.duration * 1e3, 3),
-                "tags": s.tags,
-            }
-            for s in self.traces()
-        ]
+
+_annotation = None  # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+def _profiler_annotation():
+    """jax.profiler.TraceAnnotation where this process has imported
+    JAX, else None: tracing itself never imports it."""
+    global _annotation
+    if _annotation is None:
+        prof = getattr(sys.modules.get("jax"), "profiler", None)
+        _annotation = getattr(prof, "TraceAnnotation", None)
+    return _annotation
+
+
+class Stage:
+    """One leaf stage of a loop that owns its thread (the codec
+    dispatcher's idle / pack / launch / d2h): `with Stage(name, h):`.
+    Its seconds on the monotonic clock go into the histogram `h`; while
+    a profiler session is on, it is also an event `name` on this thread
+    in the profiler's own trace, on the device trace's clock, so a
+    device idle gap can be named after it. Outside a session the mirror
+    costs one `is_enabled()` call. Stages never nest. Spans that
+    enclose other work are not mirrored: one would cover every gap
+    whole and name none."""
+
+    __slots__ = ("name", "histogram", "_annotation", "_t0")
+
+    def __init__(self, name: str, histogram):
+        self.name = name
+        self.histogram = histogram
+
+    def __enter__(self) -> "Stage":
+        annotation = _profiler_annotation()
+        if annotation is not None and annotation.is_enabled():
+            self._annotation = annotation(self.name)
+            self._annotation.__enter__()
+        else:
+            self._annotation = None
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        seconds = time.monotonic() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        self.histogram.observe(seconds)
 
 
 def span_json(s: Span, service: str = "") -> dict:
@@ -247,16 +325,23 @@ class FlightRecorder:
     exceeds its per-op SLO is pinned — with its critical path — into a
     bounded ring, surviving the span buffer / collector LRU. The
     always-on flight recorder that answers "where did that P99 PUT
-    spend its time" after the fact (tail sampling, not head sampling)."""
+    spend its time" after the fact (tail sampling, not head sampling).
+
+    Beside the slow traces it keeps, for EVERY finished operation root
+    (Tracer.operation), slow or not, one compact stage record in a ring
+    of its own that no child span and no parentless RPC span can evict:
+    where the mean operation spends its time, not only the P99."""
+
+    #: operation records kept; a 10 s benchmark window holds ~150
+    MAX_OPERATIONS = 8192
 
     def __init__(self, max_traces: int = 0):
-        from collections import OrderedDict
-
         from ozone_tpu.utils.config import env_int
 
         self.max_traces = max_traces or env_int(
             "OZONE_TPU_TRACE_SLOW_RING", 64)
         self._ring: "OrderedDict[str, dict]" = OrderedDict()
+        self._ops: deque[dict] = deque(maxlen=self.MAX_OPERATIONS)
         self._lock = threading.Lock()
 
     @staticmethod
@@ -272,12 +357,67 @@ class FlightRecorder:
         key = re.sub(r"[^A-Za-z0-9]+", "_", op).strip("_").upper()
         return env_float(f"OZONE_TPU_TRACE_SLO_{key}_MS", default) / 1e3
 
+    def root_finished(self, root: Span, spans: list[Span]) -> None:
+        """A root span finished in this process; `spans` is its whole
+        local trace, the root included."""
+        if root.op:
+            # on the monotonic clock: the stages then partition the
+            # root's own duration, whatever the wall clock did meanwhile
+            path = critical_path([
+                {"spanId": s.span_id, "parentId": s.parent_id,
+                 "name": s.name, "start": s.mono,
+                 "durationMs": s.duration * 1e3} for s in spans])
+            rec = {"root": root.name, "traceId": root.trace_id,
+                   "end": root.mono + root.duration,
+                   "durationUs": int(round(root.duration * 1e6)),
+                   "stages": {st["stage"]: st["micros"] for st in path}}
+            with self._lock:
+                self._ops.append(rec)
+        self.offer(root, spans)
+
+    def operations(self, root: str = "", t0: float = float("-inf"),
+                   t1: float = float("inf")) -> list[dict]:
+        """Stage records {root, traceId, end, durationUs, stages:
+        {stage: micros}} of the finished operations named `root` (all,
+        if empty) whose END lies in [t0, t1) on time.monotonic(),
+        oldest first. A record's stages sum to its durationUs (to the
+        rounding of each stage)."""
+        with self._lock:
+            ops = list(self._ops)
+        return [r for r in ops
+                if (not root or r["root"] == root) and t0 <= r["end"] < t1]
+
+    def stage_means(self, root: str = "") -> dict:
+        """{root: {"n", "mean_ms", "stage_ms": {stage: mean ms per
+        operation, largest first}}} over the records kept: where the
+        MEAN operation spent its time (a freon summary's `op_stage_ms`)."""
+        by_root: dict[str, list[dict]] = {}
+        for r in self.operations(root):
+            by_root.setdefault(r["root"], []).append(r)
+        out = {}
+        for name, ops in by_root.items():
+            sums: dict[str, int] = {}
+            for r in ops:
+                for stage, us in r["stages"].items():
+                    sums[stage] = sums.get(stage, 0) + us
+            out[name] = {
+                "n": len(ops),
+                "mean_ms": round(sum(r["durationUs"] for r in ops)
+                                 / len(ops) / 1e3, 3),
+                "stage_ms": {st: round(us / len(ops) / 1e3, 3)
+                             for st, us in sorted(sums.items(),
+                                                  key=lambda kv: -kv[1])}}
+        return out
+
     def offer(self, root, spans: list) -> bool:
         """Retain the trace if its root exceeded the op's SLO. `root`
         and `spans` may be Span objects or span_json dicts."""
+        name, seconds = (
+            (root.name, root.duration) if isinstance(root, Span)
+            else (root["name"], root.get("durationMs", 0.0) / 1e3))
+        if seconds < self.slo_s(name):
+            return False  # before anything is copied or serialised
         rj = span_json(root) if isinstance(root, Span) else root
-        if rj.get("durationMs", 0.0) / 1e3 < self.slo_s(rj["name"]):
-            return False
         sj = [span_json(s) if isinstance(s, Span) else s for s in spans]
         entry = {
             "traceId": rj["traceId"],
@@ -420,8 +560,6 @@ class TraceCollector:
     trace stitched across services. Bounded LRU over trace ids."""
 
     def __init__(self, server=None, max_traces: int = 2000):
-        from collections import OrderedDict
-
         self._traces: "OrderedDict[str, dict]" = OrderedDict()
         self.max_traces = max_traces
         self._lock = threading.Lock()
