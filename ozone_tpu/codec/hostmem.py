@@ -284,7 +284,8 @@ _pool_lock = threading.Lock()
 
 
 def pool() -> HostBufferPool:
-    """The process-wide pool (client recv slabs, stream relays)."""
+    """The process-wide pool (client recv slabs, stream relays, the
+    codec service's staging batches)."""
     global _pool
     with _pool_lock:
         if _pool is None:

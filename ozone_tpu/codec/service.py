@@ -6,14 +6,32 @@ shape is many small concurrent PUTs/GETs, each far too small to fill a
 stripe batch, all contending for the device. This module applies the
 continuous-batching idea from LLM serving (Orca, OSDI '22) to the
 GF(2^8) codec: a per-process, thread-safe `CodecService` owns the device
-and runs a dispatcher loop that drains a submission queue of stripe work
-(encode, decode/recover, re-encode) from ANY concurrent operation, packs
-same-shape stripes into constant-shape fused batches (zero-padded tail,
-so the plan caches in `codec/fused.py` keep serving ONE compiled program
-per shape — no new XLA compiles), double-buffers dispatches exactly like
-`DeviceBatchPipeline`, and completes per-submitter futures as results
-land. The same consolidation argument f4 (OSDI '14) makes for warm-blob
-IO, applied to device dispatches.
+and takes stripe work (encode, decode/recover, re-encode) from ANY
+concurrent operation. Same-shape stripes are packed WHERE THEY ARE
+PRODUCED: `submit` reserves the next free rows of its lane's open
+staging batch under the service lock (FIFO; a submission larger than the
+free rows continues into the next batch) and then copies its rows into
+them on the submitter's own thread, outside the lock — so concurrent
+submitters copy in parallel, under the launch, device pass and D2H of
+the batch before. Batches are constant-shape (padded tail, so the plan
+caches in `codec/fused.py` keep serving ONE compiled program per shape —
+no new XLA compiles). The ONE dispatcher thread never copies payload
+bytes: it launches what is already packed, double-buffers dispatches
+exactly like `DeviceBatchPipeline`, and completes per-submitter futures
+as results land. The same consolidation argument f4 (OSDI '14) makes
+for warm-blob IO, applied to device dispatches.
+
+Staging buffers are recycled, never allocated per dispatch: page-aligned
+leases of `codec/hostmem.py`'s pool, kept on the service's own small
+free list per (shape, dtype) and handed back in `_complete`, once the
+batch's outputs are host arrays (only then is the asynchronous H2D of
+the launch certainly over). Pad rows are NOT zeroed: they hold whatever
+an earlier batch left, and every output row of a pad row is dropped by
+the per-rider slicing in `_complete`. A submission that alone covers a
+whole batch width while its lane has no partly reserved batch open is
+not copied at all: the dispatcher launches its own contiguous rows (the
+bulk-sweep path). That choice reads only the submission's shape and the
+lane's state.
 
 Policy layer:
 
@@ -23,7 +41,7 @@ Policy layer:
   queueing.
 - **Max linger** (``OZONE_TPU_CODEC_LINGER_MS``): bounds the added
   latency for lone stripes — a submission that cannot fill its lane's
-  batch width dispatches (zero-padded) after at most the linger.
+  batch width dispatches (padded) after at most the linger.
 - **Weighted fair scheduling** (``OZONE_TPU_CODEC_QOS``): per-class
   service weights so a bulk lifecycle or reconstruction sweep cannot
   starve interactive reads; a starvation guard preempts fairness when a
@@ -35,7 +53,7 @@ Lanes: submissions coalesce per (semantic key, batch width, QoS class)
 stay in separate lanes so FIFO packing can never schedule interactive
 stripes at a bulk submission's weight. Lanes are ephemeral: a
 lane exists only while it has queued stripes, and binds the fused
-callable its first submitter resolved — so backend choice (device vs
+callable (and the row shape) its first submitter brought — so backend choice (device vs
 native twin) and test instrumentation stay with the submitting layer.
 
 ``OZONE_TPU_CODEC_SERVICE=0`` disables the service; every refactored
@@ -57,6 +75,7 @@ from typing import Any, Callable, Optional
 
 import numpy as np
 
+from ozone_tpu.codec import hostmem
 from ozone_tpu.codec.pipeline import _start_d2h
 from ozone_tpu.storage.ids import StorageError
 from ozone_tpu.utils.config import env_float
@@ -82,6 +101,11 @@ _DISPATCH_EWMA_SEED_S = 0.005
 #: `idle_seconds` advances while it waits: a scrape, a benchmark window
 #: or a profiler session that opens mid-wait is off by at most one tick
 _IDLE_TICK_S = 0.05
+#: staging buffers kept per (shape, dtype): the one being filled, the two
+#: the depth-1 double buffer can have in flight (one just launched, one
+#: being completed) and one spare. A burst beyond it leases from the
+#: shared pool and gives back to it.
+_STAGING_KEEP = 4
 
 
 def enabled() -> bool:
@@ -117,11 +141,13 @@ class _Sub:
     """One submission: `n` same-shape stripes from one operation."""
 
     __slots__ = ("stripes", "n", "future", "cls", "deadline", "t_enq",
-                 "t_enq_wall", "trace_ctx", "tail", "taken",
+                 "t_enq_wall", "t_ready", "trace_ctx", "tail", "taken",
                  "pending_parts", "parts")
 
     def __init__(self, stripes: np.ndarray, future: Future, cls: str,
                  deadline, tail: bool):
+        #: kept until the rows have launched: a borrowed batch is a view
+        #: of it, and a spilled lane hands it to the mesh executor
         self.stripes = stripes
         self.n = int(stripes.shape[0])
         self.future = future
@@ -129,12 +155,15 @@ class _Sub:
         self.deadline = deadline
         self.t_enq = time.monotonic()
         self.t_enq_wall = time.time()
+        #: when its rows were all in place (monotonic): the start of its
+        #: queue wait. `inf` while the submitter is still copying
+        self.t_ready = self.t_enq
         #: submitter's trace context: the dispatcher runs on its own
         #: thread, so per-submission spans must join the operation's
         #: trace explicitly, not via the thread-local span stack
         self.trace_ctx = Tracer.instance().inject()
         self.tail = tail
-        self.taken = 0          # stripes already packed into dispatches
+        self.taken = 0          # stripes already taken for a launch
         self.pending_parts = 0  # dispatched parts not yet completed
         self.parts: list[tuple] = []  # (offset, take, host outs tuple)
 
@@ -142,24 +171,47 @@ class _Sub:
         return self.deadline.t_end if self.deadline is not None else math.inf
 
 
+class _Batch:
+    """One constant-shape dispatch being assembled. `buf` is either a
+    recycled staging buffer ([width, ...]) whose reserved rows the
+    submitters fill, or (`borrowed`) one submission's own contiguous
+    `width` rows, launched as they are."""
+
+    __slots__ = ("buf", "borrowed", "entries", "rows", "unfilled")
+
+    def __init__(self, buf: np.ndarray, borrowed: bool):
+        self.buf = buf
+        self.borrowed = borrowed
+        #: the riders, (sub, offset in sub, take, first row in buf)
+        self.entries: list[tuple[_Sub, int, int, int]] = []
+        self.rows = 0       # rows reserved (a failed fill's pads included)
+        self.unfilled = 0   # reserved parts whose copy has not landed
+
+
 class _Lane:
     """One coalescing lane: same semantic key, same stripe shape, same
     batch width, same QoS class (classes get separate lanes so a bulk
     submission queued ahead of an interactive one in FIFO order can
-    never drag it down to bulk scheduling weight). FIFO of submissions
-    with undispatched stripes."""
+    never drag it down to bulk scheduling weight). FIFO of the batches
+    being filled, and of the submissions with unlaunched stripes in
+    them."""
 
-    __slots__ = ("lane_key", "fn", "width", "cls", "subs", "queued",
-                 "min_deadline_t", "last_served")
+    __slots__ = ("lane_key", "fn", "width", "cls", "row_shape", "dtype",
+                 "subs", "batches", "queued", "min_deadline_t",
+                 "last_served")
 
     def __init__(self, lane_key: tuple, fn: Callable, width: int,
-                 cls: str):
+                 cls: str, row_shape: tuple, dtype: np.dtype):
         self.lane_key = lane_key
         self.fn = fn
         self.width = max(1, int(width))
         self.cls = cls
+        self.row_shape = row_shape
+        self.dtype = dtype
+        #: only the last can have free rows
+        self.batches: deque[_Batch] = deque()  # ozlint: allow[bounded-queue] -- holds the reserved rows of lane.subs, which the scheduler's queue_depth gauge governs (see below)
         self.subs: deque[_Sub] = deque()  # ozlint: allow[bounded-queue] -- lane depth is governed by the weighted-fair scheduler's queue_depth gauge, which the admission SLO shedder watches; bounding here would drop accepted work
-        self.queued = 0  # undispatched stripes across subs
+        self.queued = 0  # reserved, unlaunched rows across batches
         self.min_deadline_t = math.inf
         self.last_served = 0.0  # 0 = never dispatched from
 
@@ -171,8 +223,10 @@ class CodecService:
     returns a Future resolving to the tuple of host arrays `fn` produces
     for exactly those `n` stripes (outputs are sliced out of the fused
     batch along axis 0). Submissions sharing (key, width) coalesce into
-    one dispatch; the dispatcher zero-pads every batch to the lane width
-    so each lane runs ONE compiled program.
+    one dispatch; every batch is padded to the lane width so each lane
+    runs ONE compiled program. `submit` returns once the caller's rows
+    are in their batch (reserve under the lock, fill outside it, commit
+    under it): it takes as long as copying those rows.
     """
 
     def __init__(self):
@@ -193,6 +247,8 @@ class CodecService:
         #: survives an idle period
         self._vclock = 0.0
         self._queued_cls: dict[str, int] = {}  # class -> queued subs
+        #: recycled staging buffers, (shape, dtype.str) -> free list
+        self._staging: dict[tuple, list[np.ndarray]] = {}
         self._inflight: deque[tuple] = deque()  # ozlint: allow[bounded-queue] -- holds only dispatched-to-device batches; depth is bounded by the double-buffer dispatch loop (at most prefetch_depth entries)
         self._dispatch_ewma_s = _DISPATCH_EWMA_SEED_S
         self._running = True
@@ -229,8 +285,15 @@ class CodecService:
                 raise RuntimeError("codec service is shut down")
             lane = self._lanes.get(lane_key)
             if lane is None:
-                lane = self._lanes[lane_key] = _Lane(lane_key, fn,
-                                                     width, qos)
+                lane = _Lane(lane_key, fn, width, qos,
+                             tuple(stripes.shape[1:]), stripes.dtype)
+            elif (lane.row_shape, lane.dtype) != (stripes.shape[1:],
+                                                  stripes.dtype):
+                raise ValueError(
+                    f"codec submission of {stripes.dtype}"
+                    f"{list(stripes.shape[1:])} stripes into a lane of "
+                    f"{lane.dtype}{list(lane.row_shape)}: {lane_key!r}")
+            self._lanes[lane_key] = lane
             if not self._queued_cls.get(qos):
                 # WFQ activation floor: a class becoming backlogged
                 # joins at the system virtual clock
@@ -238,13 +301,148 @@ class CodecService:
                                        self._vclock)
             self._queued_cls[qos] = self._queued_cls.get(qos, 0) + 1
             lane.subs.append(sub)
-            lane.queued += sub.n
             lane.min_deadline_t = min(lane.min_deadline_t,
                                       sub.deadline_t())
+            fills = self._reserve_locked(lane, sub)
             METRICS.counter("submissions").inc()
             METRICS.gauge("queue_depth").set(self._queue_depth_locked())
             self._cond.notify()
+        if fills:
+            self._fill(lane, sub, fills)
         return fut
+
+    # ---------------------------------------------- reserve, fill, commit
+    def _reserve_locked(self, lane: _Lane, sub: _Sub) -> list[tuple]:
+        """Give `sub` the lane's next free rows, FIFO: the rest of the
+        open staging batch first, then new batches (the cross-request
+        coalescing step). Returns the parts the submitter has to copy,
+        (batch, entry). A stretch of `sub` that alone covers a whole
+        width, with no partly reserved batch open, is not staged: the
+        batch borrows those rows as they lie in `sub.stripes`."""
+        fills: list[tuple] = []
+        off = 0
+        try:
+            while off < sub.n:
+                batch = lane.batches[-1] if lane.batches else None
+                if batch is None or batch.rows == lane.width:
+                    own = sub.stripes[off:off + lane.width]
+                    if len(own) == lane.width and own.flags.c_contiguous:
+                        batch = _Batch(own, borrowed=True)
+                    else:
+                        batch = _Batch(self._lease_staging_locked(lane),
+                                       borrowed=False)
+                    lane.batches.append(batch)
+                take = min(sub.n - off, lane.width - batch.rows)
+                entry = (sub, off, take, batch.rows)
+                batch.entries.append(entry)
+                batch.rows += take
+                lane.queued += take
+                if not batch.borrowed:
+                    batch.unfilled += 1
+                    fills.append((batch, entry))
+                off += take
+        except BaseException:  # a staging lease that cannot be had
+            sub.taken += sub.n - off  # never reserved
+            self._abandon_locked(lane, sub, fills)
+            raise
+        if fills:
+            sub.t_ready = math.inf
+        return fills
+
+    def _fill(self, lane: _Lane, sub: _Sub, fills: list[tuple]) -> None:
+        """The staging copy, on the submitter's own thread and outside
+        the lock: one slice assignment per part (numpy drops the GIL for
+        the bytes), each committed as it lands so its batch can launch.
+        A submitter that fails here fails alone: its rows become pads."""
+        t0, t0_wall = time.monotonic(), time.time()
+        landed = 0
+        try:
+            with Stage("codec:submit_pack",
+                       METRICS.histogram("submit_pack_seconds")):
+                for batch, (_, off, take, row) in fills:
+                    rows = sub.stripes[off:off + take]
+                    batch.buf[row:row + take] = rows
+                    hostmem.count_copy(rows.nbytes, warn=False,
+                                       site="codec_service.submit")
+                    with self._cond:
+                        batch.unfilled -= 1
+                        landed += 1
+                        if landed == len(fills):
+                            sub.t_ready = time.monotonic()
+                        self._cond.notify()
+        except BaseException as e:
+            with self._cond:
+                self._abandon_locked(lane, sub, fills[landed:])
+                self._cond.notify()
+            if not sub.future.done():
+                sub.future.set_exception(e)
+            raise
+        if sub.trace_ctx:
+            Tracer.instance().record_span(
+                "codec:submit_pack", child_of=sub.trace_ctx,
+                start=t0_wall, duration=sub.t_ready - t0, mono=t0,
+                lane=str(lane.lane_key)[:120], qos=sub.cls, stripes=sub.n)
+
+    def _abandon_locked(self, lane: _Lane, sub: _Sub,
+                        unlanded: list[tuple]) -> None:
+        """`sub`'s submitter failed mid-fill. The rows it did not fill
+        stay reserved, as pad rows, and count as taken; parts that had
+        landed ride on unanswered. The batches still launch for their
+        other riders (one left with none is handed back in `_dispatch`)."""
+        for batch, entry in unlanded:
+            batch.entries.remove(entry)
+            batch.unfilled -= 1
+            if batch in lane.batches:
+                # (a batch already taken for its launch counted them)
+                sub.taken += entry[2]
+        if sub.taken == sub.n and sub in lane.subs:
+            self._retire_locked(lane, sub)
+            self._settle_lane_locked(lane)
+
+    def _lease_staging_locked(self, lane: _Lane) -> np.ndarray:
+        shape = (lane.width,) + lane.row_shape
+        METRICS.counter("staging_buffers_leased").inc()
+        free = self._staging.get((shape, lane.dtype.str))
+        if free:
+            METRICS.counter("staging_buffers_reused").inc()
+            return free.pop()
+        # the array pins its lease: the pages go back to the pool when
+        # the service drops the buffer
+        with hostmem.pool().lease(
+                lane.dtype.itemsize * math.prod(shape)) as lease:
+            return lease.array().view(lane.dtype).reshape(shape)
+
+    def _give_staging_locked(self, batch: _Batch) -> None:
+        if batch.borrowed:
+            return
+        free = self._staging.setdefault(
+            (batch.buf.shape, batch.buf.dtype.str), [])
+        if len(free) < _STAGING_KEEP:
+            free.append(batch.buf)
+
+    def _retire_locked(self, lane: _Lane, sub: _Sub) -> None:
+        """`sub` has no unlaunched stripes left in the lane."""
+        if lane.subs[0] is sub:
+            lane.subs.popleft()
+        else:
+            lane.subs.remove(sub)
+        self._class_left_locked(sub.cls)
+
+    def _class_left_locked(self, cls: str) -> None:
+        left = self._queued_cls.get(cls, 1) - 1
+        if left > 0:
+            self._queued_cls[cls] = left
+        else:
+            self._queued_cls.pop(cls, None)
+
+    def _settle_lane_locked(self, lane: _Lane) -> None:
+        if lane.subs:
+            lane.min_deadline_t = min(s.deadline_t() for s in lane.subs)
+            return
+        # ephemeral lanes: drop the fn binding once drained
+        if self._lanes.get(lane.lane_key) is lane:
+            del self._lanes[lane.lane_key]
+        lane.min_deadline_t = math.inf
 
     # ------------------------------------------------------- scheduling
     def _queue_depth_locked(self) -> int:
@@ -316,35 +514,19 @@ class CodecService:
                     lane.min_deadline_t - margin)
         return None if math.isinf(t) else max(0.0, t - now)
 
-    def _pack_locked(self, lane: _Lane, reason: str):
-        """Take up to `width` stripes from the lane head, FIFO across
-        submissions (the cross-request coalescing step)."""
-        entries: list[tuple[_Sub, int, int, int]] = []
+    def _take_locked(self, lane: _Lane) -> _Batch:
+        """Take the lane's oldest batch for the launch. Its rows were
+        reserved at submit; copies into them may still be landing."""
         lane.last_served = time.monotonic()
-        row = 0
-        while lane.subs and row < lane.width:
-            sub = lane.subs[0]
-            take = min(sub.n - sub.taken, lane.width - row)
-            entries.append((sub, sub.taken, take, row))
+        batch = lane.batches.popleft()
+        lane.queued -= batch.rows
+        for sub, _off, take, _row in batch.entries:
             sub.taken += take
             sub.pending_parts += 1
             if sub.taken == sub.n:
-                lane.subs.popleft()
-                left = self._queued_cls.get(sub.cls, 1) - 1
-                if left > 0:
-                    self._queued_cls[sub.cls] = left
-                else:
-                    self._queued_cls.pop(sub.cls, None)
-            row += take
-            lane.queued -= take
-        if not lane.subs:
-            # ephemeral lanes: drop the fn binding once drained
-            self._lanes.pop(lane.lane_key, None)
-            lane.min_deadline_t = math.inf
-        else:
-            lane.min_deadline_t = min(
-                s.deadline_t() for s in lane.subs)
-        return entries, row
+                self._retire_locked(lane, sub)
+        self._settle_lane_locked(lane)
+        return batch
 
     # ------------------------------------------------------------ spill
     def _collect_spill_locked(self) -> list[tuple]:
@@ -371,7 +553,10 @@ class CodecService:
                            key=lambda ln: -ln.queued):
             if depth <= watermark:
                 break
-            if not lane.subs or any(s.taken for s in lane.subs):
+            if not lane.subs or any(s.taken for s in lane.subs) \
+                    or any(b.unfilled for b in lane.batches):
+                # a copy still landing in a buffer about to be handed
+                # back: this lane spills on a later pass
                 continue
             key = lane.lane_key[0]
             ok = mex.accepts_cached(key)
@@ -384,11 +569,11 @@ class CodecService:
                 continue
             self._lanes.pop(lane.lane_key, None)
             for sub in lane.subs:
-                left = self._queued_cls.get(sub.cls, 1) - 1
-                if left > 0:
-                    self._queued_cls[sub.cls] = left
-                else:
-                    self._queued_cls.pop(sub.cls, None)
+                self._class_left_locked(sub.cls)
+            # the mesh packs from `sub.stripes`: the rows staged here
+            # are dropped and their buffers handed back
+            for batch in lane.batches:
+                self._give_staging_locked(batch)
             depth -= lane.queued
             spilled.append((mex, key, lane))
         real = [s for s in spilled if s[2] is not None]
@@ -424,7 +609,7 @@ class CodecService:
     def _loop(self) -> None:
         try:
             while True:
-                entries = None
+                batch = None
                 spilled = None
                 with self._cond:
                     now = time.monotonic()
@@ -432,7 +617,7 @@ class CodecService:
                     picked = self._pick_lane_locked(now)
                     if picked is not None:
                         lane, reason = picked
-                        entries, rows = self._pack_locked(lane, reason)
+                        batch = self._take_locked(lane)
                     elif not self._inflight and not spilled:
                         if not self._running:
                             if not self._lanes:
@@ -441,8 +626,7 @@ class CodecService:
                             # flush it rather than strand the futures
                             lane = next(iter(self._lanes.values()))
                             reason = "linger"
-                            entries, rows = self._pack_locked(
-                                lane, reason)
+                            batch = self._take_locked(lane)
                         else:
                             # starved: no lane ready (nothing queued, or
                             # a partial batch lingering), nothing in
@@ -458,15 +642,15 @@ class CodecService:
                     # outside the lock: absorption resolves (and may
                     # compile) mesh programs; submitters keep flowing
                     self._spill(spilled)
-                if entries is not None:
-                    self._dispatch(lane, entries, rows, reason)
+                if batch is not None:
+                    self._dispatch(lane, batch, reason)
                     # depth-1 double buffer: keep ONE older batch in
                     # flight; complete it only once the next dispatch
                     # is on the device (the _flush_queue overlap)
                     if len(self._inflight) > 1:
                         self._complete(self._inflight.popleft())
                 elif self._inflight:
-                    # nothing packable right now: never hold results
+                    # nothing to launch right now: never hold results
                     # hostage waiting for more work
                     self._complete(self._inflight.popleft())
         except BaseException:  # noqa: BLE001 - dispatcher must not die silently
@@ -480,21 +664,17 @@ class CodecService:
                 self._running = False
             self._fail_pending(RuntimeError("codec service stopped"))
 
-    def _dispatch(self, lane: _Lane, entries, rows: int,
-                  reason: str) -> None:
-        # the host work before the launch: fairness accounting, closing
-        # out the riders' queue waits, the staging copy
+    def _dispatch(self, lane: _Lane, batch: _Batch, reason: str) -> None:
+        # the host work before the launch: waiting out a copy that is
+        # still landing, fairness accounting, closing out the riders'
+        # queue waits. No payload byte is touched on this thread.
         with Stage("codec:pack", METRICS.histogram("pack_seconds")):
-            now = time.monotonic()
-            ops = len(entries)
-            tracer = Tracer.instance()
-            # one shared dispatch span id per device dispatch: every
-            # coalesced submission's span tags it, making cross-request
-            # batching visible from any participating trace
-            d_tid, d_sid = tracer._new_id(), tracer._new_id()
-            fill_pct = round(100.0 * rows / lane.width, 1)
-            lane_desc = str(lane.lane_key)[:120]
-            with self._lock:
+            with self._cond:
+                while batch.unfilled:
+                    # every part is committed or abandoned under this
+                    # lock, with a notify: no wakeup can be missed
+                    self._cond.wait()
+                entries = batch.entries
                 # fairness accounting under the lock: submit()'s SFQ
                 # activation floor does a read-modify-write of the same
                 # vtime entries from other threads
@@ -502,9 +682,25 @@ class CodecService:
                     w = self.weights.get(sub.cls, 1.0)
                     self._vtime[sub.cls] = \
                         self._vtime.get(sub.cls, 0.0) + take / w
+                if not entries:
+                    # every rider's submitter failed mid-fill
+                    self._give_staging_locked(batch)
+                    return
+            now = time.monotonic()
+            ops = len(entries)
+            rows = sum(take for _sub, _off, take, _row in entries)
+            tracer = Tracer.instance()
+            # one shared dispatch span id per device dispatch: every
+            # coalesced submission's span tags it, making cross-request
+            # batching visible from any participating trace
+            d_tid, d_sid = tracer._new_id(), tracer._new_id()
+            fill_pct = round(100.0 * rows / lane.width, 1)
+            lane_desc = str(lane.lane_key)[:120]
             for sub, off, take, _row in entries:
                 if off == 0:
-                    wait = now - sub.t_enq
+                    # from its rows being in place to its first launch;
+                    # nothing where a later part of it is still landing
+                    wait = max(0.0, now - sub.t_ready)
                     tid = sub.trace_ctx.split(":", 1)[0]
                     METRICS.histogram("queue_wait_seconds").observe(
                         wait, tid)
@@ -513,33 +709,18 @@ class CodecService:
                     if sub.trace_ctx:
                         tracer.record_span(
                             "codec:queue_wait", child_of=sub.trace_ctx,
-                            start=sub.t_enq_wall, duration=wait,
-                            mono=sub.t_enq, lane=lane_desc, qos=sub.cls,
+                            start=time.time() - wait, duration=wait,
+                            mono=now - wait, lane=lane_desc, qos=sub.cls,
                             fill_pct=fill_pct, dispatch_span=d_sid)
                     if sub.tail:
                         METRICS.counter("tail_flushes").inc()
-            head = entries[0]
-            if ops == 1 and head[2] == rows == lane.width:
-                # one submission covering the whole batch: dispatch its
-                # own (contiguous) rows without a staging copy — the
-                # bulk-sweep fast path, byte-identical to the pre-service
-                # pipeline
-                sub, off, take, _ = head
-                batch = sub.stripes[off:off + take]
-                if not batch.flags.c_contiguous:
-                    batch = np.ascontiguousarray(batch)
-            else:
-                shape = (lane.width,) + tuple(head[0].stripes.shape[1:])
-                batch = np.zeros(shape, dtype=head[0].stripes.dtype)
-                for sub, off, take, row in entries:
-                    batch[row:row + take] = sub.stripes[off:off + take]
         t0 = time.monotonic()
         t0_wall = time.time()
         try:
             # the implicit H2D and the enqueue
             with Stage("codec:launch",
                        METRICS.histogram("launch_seconds")):
-                outs = lane.fn(batch)
+                outs = lane.fn(batch.buf)
                 if not isinstance(outs, tuple):
                     outs = (outs,)
                 for a in outs:
@@ -547,9 +728,13 @@ class CodecService:
                     _start_d2h(a)
         except BaseException as e:  # noqa: BLE001 - per-dispatch fault
             self._resolve_error(entries, e)
+            with self._lock:
+                self._give_staging_locked(batch)
             return
         METRICS.counter("dispatches").inc()
         METRICS.counter("stripes_dispatched").inc(rows)
+        METRICS.counter("stripes_borrowed" if batch.borrowed
+                        else "stripes_packed_at_submit").inc(rows)
         METRICS.counter("slots_dispatched").inc(lane.width)
         METRICS.counter("coalesced_operations").inc(ops)
         if ops > 1:
@@ -564,16 +749,26 @@ class CodecService:
             METRICS.gauge("queue_depth").set(self._queue_depth_locked())
         self._inflight.append((entries, outs, t0, t0_wall,
                                (d_tid, d_sid, fill_pct, reason,
-                                lane_desc, ops, rows, lane.width)))
+                                lane_desc, ops, rows, lane.width), batch))
 
     def _complete(self, rec: tuple) -> None:
-        entries, outs, t0, t0_wall, dctx = rec
+        entries, outs, t0, t0_wall, dctx, batch = rec
         d_tid, d_sid, fill_pct, reason, lane_desc, ops, rows, width = dctx
         try:
             with Stage("codec:d2h", METRICS.histogram("d2h_seconds")):
                 host = tuple(np.asarray(a) for a in outs)
         except BaseException as e:  # noqa: BLE001 - D2H fault
             self._resolve_error(entries, e)
+            host = None
+        # the outputs are host arrays (or lost): the launch's asynchronous
+        # H2D is over and the staging buffer can be refilled. Not one
+        # whose memory an output still shows (a `fn` that hands its input
+        # back): that one is dropped, not recycled
+        if not batch.borrowed and not any(
+                np.may_share_memory(a, batch.buf) for a in host or ()):
+            with self._lock:
+                self._give_staging_locked(batch)
+        if host is None:
             return
         dt = time.monotonic() - t0
         self._dispatch_ewma_s += 0.2 * (dt - self._dispatch_ewma_s)
@@ -633,6 +828,7 @@ class CodecService:
             subs = [s for lane in self._lanes.values() for s in lane.subs]
             self._lanes.clear()
             self._queued_cls.clear()
+            self._staging.clear()
             inflight, self._inflight = list(self._inflight), deque()  # ozlint: allow[bounded-queue] -- drain/reset of the bounded in-flight deque above, not a new queue
         for rec in inflight:
             for sub, _o, _t, _r in rec[0]:

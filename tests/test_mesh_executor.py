@@ -283,11 +283,15 @@ def test_close_fails_pending_and_rejects_submits():
 
 
 # ----------------------------------------------------------- spill path
-def test_service_spill_redirects_whole_lane(executor, monkeypatch):
+@pytest.mark.parametrize("width", [1, 4],
+                         ids=["borrowed_rows", "rows_reserved_in_staging"])
+def test_service_spill_redirects_whole_lane(executor, monkeypatch, width):
     """Watermark-triggered overflow: with the service dispatcher pinned
     on a slow lane and the queue past the watermark, untouched lanes
     whose keys the mesh accepts move wholesale to the executor — and
-    their futures still resolve bit-exactly."""
+    their futures still resolve bit-exactly. At width 4 the lone rows
+    were already copied into staging batches at submit: the mesh packs
+    from the submitters' own rows and the buffers are handed back."""
     monkeypatch.setenv("OZONE_TPU_MESH_SPILL", "1")
     monkeypatch.setenv("OZONE_TPU_MESH_SPILL_WATERMARK", "4")
     monkeypatch.setattr(mesh_executor, "_executor", executor)
@@ -312,10 +316,20 @@ def test_service_spill_redirects_whole_lane(executor, monkeypatch):
         plug = svc.submit(("encode", "slow-plug"), slow_fn,
                           np.zeros((1, 4), dtype=np.uint8), width=1)
         time.sleep(0.05)
-        futs = [svc.submit(enc_key, None, d, width=1) for d in datas]
+        futs = [svc.submit(enc_key, None, d, width=width) for d in datas]
         release.set()
         plug.result(timeout=30)
         results = [f.result(timeout=60) for f in futs]
+        if width > 1:
+            # the three reserved buffers came back: the next batch of
+            # that shape is packed into one of them
+            r0 = codec_service.METRICS.counter(
+                "staging_buffers_reused").value
+            (echo,) = svc.submit(("echo",), lambda b: (b.copy(),),
+                                 datas[0], width=width).result(timeout=30)
+            assert np.array_equal(echo, datas[0])
+            assert codec_service.METRICS.counter(
+                "staging_buffers_reused").value == r0 + 1
     finally:
         release.set()
         svc.close()

@@ -128,7 +128,13 @@ def test_prometheus_text_golden_every_registry_renders():
     RES.counter("hedges_fired").inc(0)
     # the shared codec service's documented family (docs/OPERATIONS.md
     # "Shared codec service"): dashboards key on these names
+    from ozone_tpu.codec import service as codec_service
     from ozone_tpu.codec.service import METRICS as CODEC
+
+    # a service an earlier test of this process left running books an
+    # idle tick every 50 ms: between the two scrapes compared at the end
+    # that is a diff
+    codec_service.reset_for_tests()
 
     for name in ("submissions", "dispatches", "stripes_dispatched",
                  "slots_dispatched", "coalesced_operations",

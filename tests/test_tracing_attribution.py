@@ -66,12 +66,23 @@ def _rand(shape, seed=0):
 
 
 # ---------------------------------------------- cross-request linkage
-def test_concurrent_ops_record_shared_dispatch_span(svc):
+@pytest.fixture
+def patient_svc(monkeypatch):
+    """A service whose linger is no race between two submits on a
+    loaded rig (the default is 2 ms); a batch they fill waits for none."""
+    monkeypatch.setenv("OZONE_TPU_CODEC_LINGER_MS", "500")
+    cs.reset_for_tests()
+    yield cs.get_service()
+    cs.reset_for_tests()
+
+
+def test_concurrent_ops_record_shared_dispatch_span(patient_svc):
     """Two operations whose stripes coalesce into ONE fused device
     dispatch each record a codec:dispatch span carrying the SAME
     dispatch_span id — and that id names the shared
     codec:device_dispatch span, so an operator holding either trace can
     pivot to the batch (and from it to every rider)."""
+    svc = patient_svc
     t = Tracer.instance()
     fn = make_fused_encoder(SPEC)
     a, b = _rand((2, 3, CELL), 1), _rand((2, 3, CELL), 2)
@@ -99,10 +110,14 @@ def test_concurrent_ops_record_shared_dispatch_span(svc):
     assert len(shared) == 1
     assert shared[0].tags["ops"] == 2
     assert shared[0].trace_id not in (ra.trace_id, rb.trace_id)
-    # each rider also closed out its queue-wait against the same batch
+    # each rider also closed out its queue-wait against the same batch,
+    # and its own copy into the batch lies before that wait
     for tid in (ra.trace_id, rb.trace_id):
         waits = [s for s in t.traces(tid) if s.name == "codec:queue_wait"]
         assert waits and waits[0].tags["dispatch_span"] == shared_id
+        (copy,) = [s for s in t.traces(tid)
+                   if s.name == "codec:submit_pack"]
+        assert copy.mono + copy.duration <= waits[0].mono + 1e-6
 
 
 def test_codec_histograms_export_bucket_lines(svc):
