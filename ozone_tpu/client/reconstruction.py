@@ -8,8 +8,11 @@ heartbeat-command at a time; this module is the storm-shaped datapath
 for the same work: enumerate every container the dead node touched,
 build the per-container ReconstructionCommands the same way
 `scm/replication_manager.py:_emit_reconstruction` does (first live
-source per index, placement-chosen targets excluding every present
-holder), and run them CONCURRENTLY through one shared
+source per index, targets by the SCM's own rack-scatter rule,
+`scm/placement.rack_scatter`, excluding every present holder), from the
+SCM's container and node LISTINGS — the same plain values whether `scm`
+is the in-process StorageContainerManager or a GrpcScmClient on a served
+cluster — and run them CONCURRENTLY through one shared
 `ECReconstructionCoordinator` wired to the mesh executor — so decode
 batches from different containers (same erasure pattern, which a
 homogeneous cluster guarantees) coalesce into full-width mesh dispatches
@@ -23,20 +26,20 @@ that did NOT coalesce shows dispatches >= batches.
 from __future__ import annotations
 
 import logging
+import random
 import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Optional
 
-from ozone_tpu.scm.pipeline import ReplicationType
-from ozone_tpu.storage.ids import ContainerState
+from ozone_tpu.scm.pipeline import ReplicationConfig, ReplicationType
+from ozone_tpu.scm.placement import PlacementError, rack_scatter
 from ozone_tpu.storage.reconstruction import (
     ECReconstructionCoordinator,
     ReconstructionCommand,
 )
 from ozone_tpu.utils.checksum import ChecksumType
 from ozone_tpu.utils.metrics import registry
-from ozone_tpu.utils.tracing import Tracer
 
 log = logging.getLogger(__name__)
 
@@ -73,11 +76,12 @@ class ReconstructionStorm:
     """Repair every EC container a dead datanode held, data-parallel
     across the mesh.
 
-    `scm` is a StorageContainerManager (its .containers/.nodes/.placement
-    drive planning); `clients` the DatanodeClientFactory reaching the
-    surviving nodes. `executor` defaults to the process mesh executor
-    when one can exist (`mesh_executor.maybe_executor()`); with no mesh
-    the storm still runs, through the shared single-chip codec service.
+    `scm` answers `list_containers()` and `list_nodes()`: an in-process
+    StorageContainerManager or a GrpcScmClient, planned from alike;
+    `clients` is the DatanodeClientFactory reaching the surviving nodes.
+    `executor` defaults to the process mesh executor when one can exist
+    (`mesh_executor.maybe_executor()`); with no mesh the storm still
+    runs, through the shared single-chip codec service.
     """
 
     def __init__(self, scm, clients, executor=None,
@@ -91,10 +95,12 @@ class ReconstructionStorm:
         self.clients = clients
         self.executor = (executor if executor is not None
                          else mesh_executor.maybe_executor())
-        #: containers repairing at once: each container's storm worker
-        #: streams its own survivor reads and target writes while ALL
-        #: their decode batches coalesce in the shared mesh lane — the
-        #: concurrency here is what FILLS the mesh-wide batches
+        #: reconstruction streams (upstream's
+        #: hdds.datanode.replication.streams.limit): containers
+        #: repairing at once. Each stream reads its own survivors and
+        #: writes its own targets while ALL their decode batches meet in
+        #: the shared mesh lanes — the concurrency here is what FILLS
+        #: the mesh-wide batches
         self.max_parallel_containers = max(1, int(max_parallel_containers))
         self.coordinator = ECReconstructionCoordinator(
             clients,
@@ -108,35 +114,43 @@ class ReconstructionStorm:
     def plan(self, dead_dn_id: str) -> list[ReconstructionCommand]:
         """ReconstructionCommands for every EC container with a replica
         on the dead node, built the `_emit_reconstruction` way: first
-        surviving holder per index as source, placement-chosen targets
-        excluding every present holder AND the dead node. Containers
-        with too few survivors are skipped (and counted by the caller
-        as unrecoverable) — a storm must never wedge on a lost cause.
+        surviving holder per index as source, targets excluding every
+        present holder AND the dead node. Containers with too few
+        survivors are skipped (and counted by the caller as
+        unrecoverable) — a storm must never wedge on a lost cause.
 
         Commands come back sorted by recoverability, fewest surviving
         indexes first: the stripes closest to losing data permanently
         repair earliest, so a second failure mid-storm costs the least
         (carry-over fix: PR 12's planner ordered containers by SCM
         enumeration order)."""
+        nodes = sorted(self.scm.list_nodes(), key=lambda n: n["dn_id"])
+        known = {n["dn_id"] for n in nodes}
+        # the placement policies' candidate set, once for the whole plan
+        eligible = [(n["rack"], n["dn_id"]) for n in nodes
+                    if n["state"] == "HEALTHY"
+                    and n["op_state"] == "IN_SERVICE"
+                    and n["healthy_volumes"] != 0]
         cmds: list[tuple[int, ReconstructionCommand]] = []
-        for c in self.scm.containers.containers():
-            if c.replication.type is not ReplicationType.EC:
+        for c in self.scm.list_containers():
+            replication = ReplicationConfig.parse(c["replication"])
+            if replication.type is not ReplicationType.EC:
                 continue
-            if c.state is ContainerState.DELETED:
+            if c["state"] == "DELETED":
                 continue
-            if dead_dn_id not in c.replicas:
+            if all(r["dn_id"] != dead_dn_id for r in c["replicas"]):
                 continue
             present: dict[int, list[str]] = {}
-            for dn_id, r in c.replicas.items():
-                if dn_id == dead_dn_id:
+            for r in c["replicas"]:
+                if r["dn_id"] == dead_dn_id:
                     continue
-                if r.state in ("UNHEALTHY", "DELETED", "INVALID"):
+                if r["state"] in ("UNHEALTHY", "DELETED", "INVALID"):
                     continue
-                node = self.scm.nodes.get(dn_id)
-                if node is None:
+                if r["dn_id"] not in known:
                     continue
-                present.setdefault(r.replica_index, []).append(dn_id)
-            ec = c.replication.ec
+                present.setdefault(r["replica_index"], []).append(
+                    r["dn_id"])
+            ec = replication.ec
             missing = sorted(
                 set(range(1, ec.all_units + 1)) - set(present))
             if not missing:
@@ -160,22 +174,27 @@ class ReconstructionStorm:
                 METRICS.counter("unrecoverable").inc()
                 log.warning(
                     "storm: container %s unrecoverable (%d/%d indexes "
-                    "survive)", c.id, len(present), ec.data_units)
+                    "survive)", c["id"], len(present), ec.data_units)
                 continue
             sources = {i: dns[0] for i, dns in present.items()}
-            exclude = [dn for dns in present.values() for dn in dns]
-            exclude.append(dead_dn_id)
+            exclude = {dn for dns in present.values() for dn in dns}
+            exclude.add(dead_dn_id)
             try:
-                chosen = self.scm.placement.choose(len(missing), exclude)
-            except Exception:  # noqa: BLE001 - placement exhausted: skip, report
+                # the SCM's own choosing rule; the draw is seeded by the
+                # container, so one listing gives one plan wherever it
+                # is read
+                chosen = rack_scatter(
+                    [e for e in eligible if e[1] not in exclude],
+                    len(missing), random.Random(c["id"]))
+            except PlacementError:
                 METRICS.counter("placement_failures").inc()
-                log.exception("storm: no targets for container %s", c.id)
+                log.warning("storm: no targets for container %s", c["id"])
                 continue
             cmds.append((len(present), ReconstructionCommand(
-                container_id=c.id,
+                container_id=c["id"],
                 replication=ec,
                 sources=sources,
-                targets={i: n.dn_id for i, n in zip(missing, chosen)},
+                targets=dict(zip(missing, chosen)),
             )))
         # most-at-risk first: ascending surviving-index count, container
         # id as the deterministic tiebreak
@@ -183,6 +202,17 @@ class ReconstructionStorm:
         return [cmd for _survivors, cmd in cmds]
 
     # ------------------------------------------------------------ drive
+    def repair_container(self, cmd: ReconstructionCommand) -> None:
+        """One stream's unit of work: rebuild one container's lost
+        replicas through the shared coordinator, so that its decode
+        batches meet every other stream's in the mesh lanes. The
+        coordinator's `repair:container` operation is the root of the
+        repair's trace (its stage record is the flight recorder's).
+        `repair_datanode` drives this over a plan; a time-bounded drill
+        drives it over commands of its own, on up to
+        `max_parallel_containers` threads."""
+        self.coordinator.reconstruct_container_group(cmd)
+
     def repair_datanode(self, dead_dn_id: str) -> StormReport:
         """The storm: plan, then repair containers concurrently through
         the shared coordinator. Returns the report with mesh dispatch
@@ -203,16 +233,13 @@ class ReconstructionStorm:
         METRICS.gauge("containers_in_flight").set(0)
 
         def repair(cmd: ReconstructionCommand) -> Optional[str]:
-            with Tracer.instance().span("storm:container",
-                                        container=cmd.container_id,
-                                        dead_dn=dead_dn_id):
-                try:
-                    self.coordinator.reconstruct_container_group(cmd)
-                    return None
-                except Exception as e:  # noqa: BLE001 - per-container fault isolation
-                    log.exception("storm: container %s repair failed",
-                                  cmd.container_id)
-                    return f"{type(e).__name__}: {e}"
+            try:
+                self.repair_container(cmd)
+                return None
+            except Exception as e:  # noqa: BLE001 - per-container fault isolation
+                log.exception("storm: container %s repair failed",
+                              cmd.container_id)
+                return f"{type(e).__name__}: {e}"
 
         with ThreadPoolExecutor(
                 max_workers=self.max_parallel_containers,
@@ -239,3 +266,4 @@ class ReconstructionStorm:
             report.mesh_multi_op_dispatches = delta("multi_op_dispatches")
             report.mesh_max_inflight = self.executor._max_inflight
         return report
+

@@ -80,7 +80,12 @@ from ozone_tpu.codec.pipeline import _start_d2h
 from ozone_tpu.storage.ids import StorageError
 from ozone_tpu.utils.config import env_float
 from ozone_tpu.utils.metrics import MetricsRegistry, registry
-from ozone_tpu.utils.tracing import Stage, Tracer
+from ozone_tpu.utils.tracing import (
+    IDLE_TICK_S,
+    Stage,
+    Tracer,
+    dispatcher_seconds,
+)
 
 log = logging.getLogger(__name__)
 
@@ -97,10 +102,6 @@ DEFAULT_STARVE_MS = 250.0
 DEFAULT_QOS = {"interactive": 4.0, "bulk": 1.0}
 #: seed for the dispatch-time EWMA before the first dispatch lands
 _DISPATCH_EWMA_SEED_S = 0.005
-#: an idle dispatcher books its wait at least this often, so that
-#: `idle_seconds` advances while it waits: a scrape, a benchmark window
-#: or a profiler session that opens mid-wait is off by at most one tick
-_IDLE_TICK_S = 0.05
 #: staging buffers kept per (shape, dtype): the one being filled, the two
 #: the depth-1 double buffer can have in flight (one just launched, one
 #: being completed) and one spare. A burst beyond it leases from the
@@ -635,8 +636,8 @@ class CodecService:
                             with Stage("codec:idle",
                                        METRICS.histogram("idle_seconds")):
                                 self._cond.wait(
-                                    _IDLE_TICK_S if wake is None
-                                    else min(wake, _IDLE_TICK_S))
+                                    IDLE_TICK_S if wake is None
+                                    else min(wake, IDLE_TICK_S))
                             continue
                 if spilled:
                     # outside the lock: absorption resolves (and may
@@ -847,15 +848,8 @@ class CodecService:
                               if slots else 0.0)
         snap["ops_per_dispatch"] = (
             snap.get("coalesced_operations", 0) / disp if disp else 0.0)
-        # where the ONE dispatcher thread's time went since start: a
-        # large idle share says it is starved, a large pack / launch /
-        # d2h share says which of its own stages paces the chip; `hold`
-        # is how long batches sat in the double buffer
-        took = {k: METRICS.histogram(f"{k}_seconds").total
-                for k in ("idle", "pack", "launch", "d2h")}
-        took["hold"] = max(0.0, METRICS.histogram("dispatch_seconds").total
-                           - took["launch"] - took["d2h"])
-        snap["dispatcher_seconds"] = took
+        # where the ONE dispatcher thread's time went since start
+        snap["dispatcher_seconds"] = dispatcher_seconds(METRICS)
         with self._lock:
             snap["queue_depth"] = self._queue_depth_locked()
             snap["lanes"] = len(self._lanes)

@@ -361,26 +361,7 @@ class ScmGrpcService:
         return wire.pack(out)
 
     def _list_containers(self, req: bytes) -> bytes:
-        """Container listing for admin/repair tools (`ozone admin
-        container list` analog)."""
-        return wire.pack({
-            "containers": [
-                {
-                    "id": c.id,
-                    "state": c.state.value,
-                    "replication": str(c.replication),
-                    "nodes": c.pipeline.nodes if c.pipeline else [],
-                    "used_bytes": c.used_bytes,
-                    # snapshot: heartbeat threads mutate replicas live
-                    "replicas": [
-                        {"dn_id": r.dn_id, "state": r.state,
-                         "replica_index": r.replica_index}
-                        for r in list(c.replicas.values())
-                    ],
-                }
-                for c in self.scm.containers.containers()
-            ],
-        })
+        return wire.pack({"containers": self.scm.list_containers()})
 
     def _status(self, req: bytes) -> bytes:
         return wire.pack(
@@ -388,23 +369,7 @@ class ScmGrpcService:
                 "safemode": self.scm.safemode.in_safemode(),
                 "safemode_status": self.scm.safemode.status(),
                 "block_tokens": getattr(self.scm, "block_tokens", False),
-                "nodes": [
-                    {
-                        "dn_id": n.dn_id,
-                        "rack": n.rack,
-                        "state": n.state.value,
-                        "op_state": n.op_state.value,
-                        # usage columns (ozone admin datanode usageinfo):
-                        "capacity_bytes": n.capacity_bytes,
-                        "used_bytes": n.used_bytes,
-                        "used_pct": round(
-                            100.0 * n.used_bytes / n.capacity_bytes, 2)
-                        if n.capacity_bytes else None,
-                        "healthy_volumes": n.healthy_volumes,
-                        "layout_version": n.layout_version,
-                    }
-                    for n in self.scm.nodes.nodes()
-                ],
+                "nodes": self.scm.list_nodes(),
                 "containers": len(self.scm.containers.containers()),
             }
         )
@@ -562,6 +527,9 @@ class GrpcScmClient:
 
     def list_containers(self) -> list[dict]:
         return self._call("ListContainers", {})["containers"]
+
+    def list_nodes(self) -> list[dict]:
+        return self.status()["nodes"]
 
     def node_addresses(self) -> dict[str, str]:
         return self._call("NodeAddresses", {})["addresses"]

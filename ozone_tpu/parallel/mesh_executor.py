@@ -24,6 +24,16 @@ result. This module gives the mesh the same treatment
   reconstruction storm over many containers becomes a few wide
   dispatches instead of per-container dribbles.
 
+The ONE dispatcher thread books every stretch of its time to one of
+four leaf stages that never nest — `mesh:idle` (no lane ready, nothing
+in flight), `mesh:pack` (closing out queue waits, zeroing and filling
+the staged batch), `mesh:launch` (the H2D to every device and the
+enqueue), `mesh:d2h` (pulling a batch's outputs) — each a histogram of
+registry `mesh` and, in a profiler session, an event on the profiler's
+clock (`utils/tracing.Stage`, as `codec/service.py` books `codec:*`).
+`mesh:queue_wait` and `mesh:device_dispatch` are spans of the
+SUBMITTING operation's trace and land in its stage record.
+
 Backend policy mirrors `codec/fused.py`: on CPU-only hosts (where XLA's
 GF(2) bit-matmul runs orders of magnitude slower than the AVX2 nibble
 coder) a lane's program resolves to the **native host twin sharded
@@ -55,7 +65,12 @@ from ozone_tpu.codec.pipeline import _start_d2h
 from ozone_tpu.parallel import sharded
 from ozone_tpu.utils.config import env_float, env_int
 from ozone_tpu.utils.metrics import MetricsRegistry, registry
-from ozone_tpu.utils.tracing import Tracer
+from ozone_tpu.utils.tracing import (
+    IDLE_TICK_S,
+    Stage,
+    Tracer,
+    dispatcher_seconds,
+)
 
 log = logging.getLogger(__name__)
 
@@ -480,7 +495,13 @@ class MeshExecutor:
                                         if ln.subs)
                             entries, rows = self._pack_locked(lane)
                         else:
-                            self._cond.wait(self._next_wakeup_locked(now))
+                            # starved: no lane ready, nothing in flight
+                            wake = self._next_wakeup_locked(now)
+                            with Stage("mesh:idle",
+                                       METRICS.histogram("idle_seconds")):
+                                self._cond.wait(
+                                    IDLE_TICK_S if wake is None
+                                    else min(wake, IDLE_TICK_S))
                             continue
                 if entries is not None:
                     self._dispatch(lane, entries, rows)
@@ -501,52 +522,62 @@ class MeshExecutor:
             self._fail_pending(RuntimeError("mesh executor stopped"))
 
     def _dispatch(self, lane: _Lane, entries, rows: int) -> None:
-        now = time.monotonic()
         ops = len(entries)
         tracer = Tracer.instance()
         lane_desc = str(lane.lane_key)[:120]
-        for sub, off, take, _row in entries:
-            if off == 0:
-                wait = now - sub.t_enq
-                tid = sub.trace_ctx.split(":", 1)[0]
-                METRICS.histogram("queue_wait_seconds").observe(wait, tid)
-                if sub.trace_ctx:
-                    tracer.record_span(
-                        "mesh:queue_wait", child_of=sub.trace_ctx,
-                        start=sub.t_enq_wall, duration=wait,
-                        lane=lane_desc, qos=sub.cls)
-        head = entries[0]
-        staged = None
-        if ops == 1 and head[2] == rows == lane.width and head[1] == 0 \
-                and head[0].n == lane.width \
-                and head[0].stripes.flags.c_contiguous:
-            # one submission covering the whole batch: dispatch its own
-            # rows without a staging copy
-            batch = head[0].stripes
-        else:
-            shape = (lane.width,) + tuple(head[0].stripes.shape[1:])
-            staged = batch = self._take_staging(
-                shape, head[0].stripes.dtype)
-            for sub, off, take, row in entries:
-                batch[row:row + take] = sub.stripes[off:off + take]
-            if rows < lane.width:
-                batch[rows:] = 0  # constant-shape zero-padded tail
+        # the host work before the launch, on this one thread: closing
+        # out the riders' queue waits, then zeroing and filling the
+        # staged batch (every payload byte of a coalesced dispatch)
+        with Stage("mesh:pack", METRICS.histogram("pack_seconds")):
+            now = time.monotonic()
+            for sub, off, take, _row in entries:
+                if off == 0:
+                    wait = now - sub.t_enq
+                    tid = sub.trace_ctx.split(":", 1)[0]
+                    METRICS.histogram("queue_wait_seconds").observe(
+                        wait, tid)
+                    if sub.trace_ctx:
+                        tracer.record_span(
+                            "mesh:queue_wait", child_of=sub.trace_ctx,
+                            start=sub.t_enq_wall, duration=wait,
+                            mono=sub.t_enq, lane=lane_desc, qos=sub.cls)
+            head = entries[0]
+            staged = None
+            if ops == 1 and head[2] == rows == lane.width \
+                    and head[1] == 0 and head[0].n == lane.width \
+                    and head[0].stripes.flags.c_contiguous:
+                # one submission covering the whole batch: dispatch its
+                # own rows without a staging copy
+                batch = head[0].stripes
+            else:
+                shape = (lane.width,) + tuple(head[0].stripes.shape[1:])
+                staged = batch = self._take_staging(
+                    shape, head[0].stripes.dtype)
+                for sub, off, take, row in entries:
+                    batch[row:row + take] = sub.stripes[off:off + take]
+                if rows < lane.width:
+                    batch[rows:] = 0  # constant-shape zero-padded tail
         t0 = time.monotonic()
+        t0_wall = time.time()
         with tracer.span("mesh:dispatch", lane=lane_desc, ops=ops,
                          rows=rows, width=lane.width,
                          devices=self.n_devices):
             try:
-                outs = lane.program.fn(batch)
+                # the implicit H2D to every device and the enqueue
+                with Stage("mesh:launch",
+                           METRICS.histogram("launch_seconds")):
+                    outs = lane.program.fn(batch)
+                    if not isinstance(outs, tuple):
+                        outs = (outs,)
+                    for a in outs:
+                        # eager D2H: the pull overlaps the next batch's
+                        # staging
+                        _start_d2h(a)
             except BaseException as e:  # noqa: BLE001 - per-dispatch fault
                 if staged is not None:
                     self._give_staging(staged)
                 self._resolve_error(entries, e)
                 return
-            if not isinstance(outs, tuple):
-                outs = (outs,)
-            for a in outs:
-                # eager D2H: the pull overlaps the next batch's staging
-                _start_d2h(a)
         METRICS.counter("dispatches").inc()
         METRICS.counter("stripes_dispatched").inc(rows)
         METRICS.counter("slots_dispatched").inc(lane.width)
@@ -556,12 +587,13 @@ class MeshExecutor:
         METRICS.gauge("batch_fill_pct").set(100.0 * rows / lane.width)
         # devices holding a shard of the last dispatch's output: n on a
         # real SPMD dispatch, 0 for the host twin's numpy arrays
-        METRICS.gauge("output_shards").set(
-            len(getattr(outs[0], "addressable_shards", ())))
+        shards = len(getattr(outs[0], "addressable_shards", ()))
+        METRICS.gauge("output_shards").set(shards)
+        METRICS.counter("output_shards_dispatched").inc(shards)
         with self._lock:
             METRICS.gauge("queue_depth").set(self._queue_depth_locked())
         self._inflight.append(
-            (entries, outs, staged, t0, time.time(),
+            (entries, outs, staged, t0, t0_wall,
              (lane_desc, ops, rows, lane.width)))
         depth_now = len(self._inflight)
         self._max_inflight = max(self._max_inflight, depth_now)
@@ -573,7 +605,8 @@ class MeshExecutor:
         entries, outs, staged, t0, t0_wall, dctx = rec
         lane_desc, ops, rows, width = dctx
         try:
-            host = tuple(np.asarray(a) for a in outs)
+            with Stage("mesh:d2h", METRICS.histogram("d2h_seconds")):
+                host = tuple(np.asarray(a) for a in outs)
         except BaseException as e:  # noqa: BLE001 - D2H fault
             if staged is not None:
                 self._give_staging(staged)
@@ -591,7 +624,7 @@ class MeshExecutor:
             if sub.trace_ctx:
                 tracer.record_span(
                     "mesh:device_dispatch", child_of=sub.trace_ctx,
-                    start=t0_wall, duration=dt, lane=lane_desc,
+                    start=t0_wall, duration=dt, mono=t0, lane=lane_desc,
                     qos=sub.cls, stripes=take, ops=ops, rows=rows,
                     width=width)
         for sub, off, take, row in entries:
@@ -640,6 +673,7 @@ class MeshExecutor:
                               if slots else 0.0)
         snap["ops_per_dispatch"] = (
             snap.get("coalesced_operations", 0) / disp if disp else 0.0)
+        snap["dispatcher_seconds"] = dispatcher_seconds(METRICS)
         with self._lock:
             snap["queue_depth"] = self._queue_depth_locked()
             snap["lanes"] = len(self._lanes)

@@ -120,7 +120,9 @@ def _sharded_fused_encoder_cached(
 
     batch_sharding = NamedSharding(mesh, P(axis))
 
-    def fn(data):
+    # a name of its own: the device trace's module line then reads
+    # `jit_sharded_fused_encode(`, apart from the single-chip `jit_fn(`
+    def sharded_fused_encode(data):
         parity = gf_apply(data, a)
         if k_dev is None:
             crcs = jnp.zeros(
@@ -137,7 +139,7 @@ def _sharded_fused_encoder_cached(
         return parity, crcs
 
     return jax.jit(
-        fn,
+        sharded_fused_encode,
         in_shardings=batch_sharding,
         out_shardings=(batch_sharding, batch_sharding),
     )
@@ -163,23 +165,23 @@ def _sharded_decode_apply_cached(mesh: Mesh, axis: str, with_crc: bool,
     replicated = NamedSharding(mesh, P())
 
     if not with_crc:
-        def fn_nocrc(valid_units, a):
+        def sharded_decode_apply_nocrc(valid_units, a):
             rec = gf_apply(valid_units, a)
             return rec, jnp.zeros(rec.shape[:2] + (0,), jnp.uint32)
 
         return jax.jit(
-            fn_nocrc,
+            sharded_decode_apply_nocrc,
             in_shardings=(batch_sharding, replicated),
             out_shardings=(batch_sharding, batch_sharding),
         )
 
-    def fn(valid_units, a, k_dev):
+    def sharded_decode_apply(valid_units, a, k_dev):
         rec = gf_apply(valid_units, a)
         crcs = crc_device.crc_slices(rec, k_dev, zeros_crc)
         return rec, crcs
 
     return jax.jit(
-        fn,
+        sharded_decode_apply,
         in_shardings=(batch_sharding, replicated, replicated),
         out_shardings=(batch_sharding, batch_sharding),
     )

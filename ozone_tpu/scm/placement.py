@@ -9,9 +9,12 @@ from __future__ import annotations
 
 import random
 from collections import defaultdict
-from typing import Optional, Sequence
+from typing import Optional, Sequence, TypeVar
 
 from ozone_tpu.scm.node_manager import NodeInfo, NodeManager
+
+
+T = TypeVar("T")
 
 
 class PlacementError(Exception):
@@ -61,35 +64,41 @@ class CapacityPlacement(PlacementPolicy):
         return self.rng.sample(pool, count)
 
 
+def rack_scatter(cands: Sequence[tuple[str, T]], count: int,
+                 rng: random.Random) -> list[T]:
+    """`count` of the candidates `cands` ((rack, node) pairs), scattered
+    across racks round-robin, most populous racks in the draw first
+    (SCMContainerPlacementRackScatter). The one choosing rule of EC
+    placement: the SCM's policy below draws with its own generator, a
+    repair storm that plans from the SCM's listings
+    (client/reconstruction.py) with one seeded by the container."""
+    if len(cands) < count:
+        raise PlacementError(
+            f"need {count} nodes, only {len(cands)} available"
+        )
+    by_rack: dict[str, list[T]] = defaultdict(list)
+    for rack, n in cands:
+        by_rack[rack].append(n)
+    for nodes in by_rack.values():
+        rng.shuffle(nodes)
+    racks = sorted(by_rack, key=lambda r: -len(by_rack[r]))
+    rng.shuffle(racks)
+    chosen: list[T] = []
+    while len(chosen) < count:
+        for r in racks:
+            if by_rack[r] and len(chosen) < count:
+                chosen.append(by_rack[r].pop())
+    return chosen
+
+
 class RackScatterPlacement(PlacementPolicy):
     """EC placement: scatter across racks, round-robin by rack
     (SCMContainerPlacementRackScatter)."""
 
     def choose(self, count, excluded=()):
-        cands = self._candidates(excluded)
-        if len(cands) < count:
-            raise PlacementError(
-                f"need {count} nodes, only {len(cands)} available"
-            )
-        by_rack: dict[str, list[NodeInfo]] = defaultdict(list)
-        for n in cands:
-            by_rack[n.rack].append(n)
-        for nodes in by_rack.values():
-            self.rng.shuffle(nodes)
-        racks = sorted(by_rack, key=lambda r: -len(by_rack[r]))
-        self.rng.shuffle(racks)
-        chosen: list[NodeInfo] = []
-        while len(chosen) < count:
-            progressed = False
-            for r in racks:
-                if by_rack[r] and len(chosen) < count:
-                    chosen.append(by_rack[r].pop())
-                    progressed = True
-            if not progressed:
-                break
-        if len(chosen) < count:
-            raise PlacementError("insufficient nodes across racks")
-        return chosen
+        return rack_scatter(
+            [(n.rack, n) for n in self._candidates(excluded)], count,
+            self.rng)
 
     @staticmethod
     def validate(racks_used: int, total_racks: int, count: int) -> bool:

@@ -198,6 +198,48 @@ class StorageContainerManager:
         self.metrics.counter("block_delete_txs").inc(len(tx_ids))
         return tx_ids
 
+    # -------------------------------------------------------------- listings
+    def list_containers(self) -> list[dict]:
+        """Every container with its replicas, as plain values: what the
+        ListContainers RPC answers (`ozone admin container list`
+        analog) and what repair tools plan from, in-process or remote."""
+        return [
+            {
+                "id": c.id,
+                "state": c.state.value,
+                "replication": str(c.replication),
+                "nodes": c.pipeline.nodes if c.pipeline else [],
+                "used_bytes": c.used_bytes,
+                # snapshot: heartbeat threads mutate replicas live
+                "replicas": [
+                    {"dn_id": r.dn_id, "state": r.state,
+                     "replica_index": r.replica_index}
+                    for r in list(c.replicas.values())
+                ],
+            }
+            for c in self.containers.containers()
+        ]
+
+    def list_nodes(self) -> list[dict]:
+        """Every registered datanode as plain values (the `nodes` of the
+        Status RPC; `ozone admin datanode list` / `usageinfo` analog)."""
+        return [
+            {
+                "dn_id": n.dn_id,
+                "rack": n.rack,
+                "state": n.state.value,
+                "op_state": n.op_state.value,
+                "capacity_bytes": n.capacity_bytes,
+                "used_bytes": n.used_bytes,
+                "used_pct": round(
+                    100.0 * n.used_bytes / n.capacity_bytes, 2)
+                if n.capacity_bytes else None,
+                "healthy_volumes": n.healthy_volumes,
+                "layout_version": n.layout_version,
+            }
+            for n in self.nodes.nodes()
+        ]
+
     # ------------------------------------------------------------- admin ops
     def decommission(self, dn_id: str) -> None:
         """Start draining a node (NodeDecommissionManager.java:60): out of

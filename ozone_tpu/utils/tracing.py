@@ -222,9 +222,17 @@ def _profiler_annotation():
     return _annotation
 
 
+#: a loop that books its idle wait as a `Stage` waits at most this long
+#: at a time, so that its `idle_seconds` advances while it waits: a
+#: scrape, a benchmark window or a profiler session that opens mid-wait
+#: is off by at most one tick
+IDLE_TICK_S = 0.05
+
+
 class Stage:
-    """One leaf stage of a loop that owns its thread (the codec
-    dispatcher's idle / pack / launch / d2h): `with Stage(name, h):`.
+    """One leaf stage of a loop that owns its thread (the codec and
+    the mesh dispatcher's idle / pack / launch / d2h): `with
+    Stage(name, h):`.
     Its seconds on the monotonic clock go into the histogram `h`; while
     a profiler session is on, it is also an event `name` on this thread
     in the profiler's own trace, on the device trace's clock, so a
@@ -254,6 +262,19 @@ class Stage:
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
         self.histogram.observe(seconds)
+
+
+def dispatcher_seconds(metrics) -> dict:
+    """Where a dispatcher thread's time went since start, from the
+    stage histograms of its registry: a large idle share says it is
+    starved, a large pack / launch / d2h share says which of its own
+    stages paces the chip; `hold` is how long batches sat in flight
+    beyond their own launch and pull."""
+    took = {k: metrics.histogram(f"{k}_seconds").total
+            for k in ("idle", "pack", "launch", "d2h")}
+    took["hold"] = max(0.0, metrics.histogram("dispatch_seconds").total
+                       - took["launch"] - took["d2h"])
+    return took
 
 
 def span_json(s: Span, service: str = "") -> dict:

@@ -157,7 +157,12 @@ def test_prometheus_text_golden_every_registry_renders():
     # the mesh-executor family (docs/OPERATIONS.md "Mesh executor"):
     # touching the module-level registry must NOT require (or create)
     # a running executor — dashboards scrape single-chip hosts too
+    from ozone_tpu.parallel import mesh_executor
     from ozone_tpu.parallel.mesh_executor import METRICS as MESH
+
+    # as for the codec service above: a running executor books
+    # `mesh:idle` ticks between the two scrapes
+    mesh_executor.reset_for_tests()
 
     for name in ("submissions", "dispatches", "stripes_dispatched",
                  "slots_dispatched", "coalesced_operations",
