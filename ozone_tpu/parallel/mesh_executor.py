@@ -16,7 +16,8 @@ result. This module gives the mesh the same treatment
   in-flight window.
 - **Depth-N in-flight batches** (``OZONE_TPU_MESH_DEPTH``, default 2):
   dispatch N+1 launches while batches N..N-depth+1 are still on the
-  devices; results harvest without blocking the submission path.
+  devices; at most `depth` batches are launched and not yet pulled,
+  plus the one being pulled.
 - **A submission-queue front end mirroring `codec/service.py` lanes**:
   concurrent operations submit stripes keyed by the same semantic keys
   (`encode_key` / `decode_key`); the dispatcher coalesces them into
@@ -24,13 +25,28 @@ result. This module gives the mesh the same treatment
   reconstruction storm over many containers becomes a few wide
   dispatches instead of per-container dribbles.
 
-The ONE dispatcher thread books every stretch of its time to one of
-four leaf stages that never nest — `mesh:idle` (no lane ready, nothing
-in flight), `mesh:pack` (closing out queue waits, zeroing and filling
-the staged batch), `mesh:launch` (the H2D to every device and the
-enqueue), `mesh:d2h` (pulling a batch's outputs) — each a histogram of
+TWO long-lived threads share the work on a batch, and each books every
+stretch of its loop to leaf stages that never nest, each a histogram of
 registry `mesh` and, in a profiler session, an event on the profiler's
-clock (`utils/tracing.Stage`, as `codec/service.py` books `codec:*`).
+clock (`utils/tracing.Stage`, as `codec/service.py` books `codec:*`):
+
+- the dispatcher (`mesh-executor`) owns the lanes and everything up to
+  the launch: `mesh:idle` (no lane ready and room in the window: it is
+  starved), `mesh:pack` (closing out queue waits, zeroing and filling
+  the staged batch), `mesh:launch` (the H2D to every device, the
+  enqueue and the start of the eager D2H), `mesh:window_full` (`depth`
+  batches launched and not yet taken by the completer: it waits for
+  that thread, not for work). It never pulls and never resolves.
+- the completer (`mesh-completer`) owns a batch from the launch on, in
+  launch order and as soon as there is one: `mesh:completer_idle`
+  (nothing launched), `mesh:d2h` (pulling the outputs to host arrays),
+  `mesh:complete` (everything after the pull: the staging buffer back
+  to the pool, the riders' `mesh:device_dispatch` spans, their rows
+  sliced, split submissions joined, the futures resolved).
+
+What the two share (the in-flight FIFO, the staging pool, a split
+submission's part bookkeeping) is guarded by the executor's one lock;
+every wait on either thread has a tick (`IDLE_TICK_S`).
 `mesh:queue_wait` and `mesh:device_dispatch` are spans of the
 SUBMITTING operation's trace and land in its stage record.
 
@@ -55,7 +71,7 @@ import os
 import threading
 import time
 from collections import deque
-from concurrent.futures import Future
+from concurrent.futures import Future, InvalidStateError
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Optional
 
@@ -87,6 +103,14 @@ MAX_DISPATCH_WIDTH = 256
 #: added-latency bound for a partial mesh batch waiting for co-batching
 #: (the codec service's linger, applied to the mesh front end)
 DEFAULT_LINGER_MS = 2.0
+#: how long `close()` (and a dispatcher that ends by itself) waits for
+#: the two threads to drain what was submitted; what is still pending
+#: after it fails
+CLOSE_TIMEOUT_S = 60.0
+#: the leaf stages of the two threads, the dispatcher's then the
+#: completer's (`stats()["dispatcher_seconds"]`)
+STAGES = ("idle", "pack", "launch", "window_full",
+          "completer_idle", "d2h", "complete")
 
 
 def mesh_depth() -> int:
@@ -196,7 +220,8 @@ class MeshExecutor:
     codec-service semantic key and returns a Future of the host output
     tuple for exactly those stripes. Submissions sharing (key, width,
     qos) coalesce into full-width mesh dispatches; up to
-    ``mesh_depth()`` dispatches stay in flight.
+    ``mesh_depth()`` dispatches stay in flight, plus the one the
+    completer thread is pulling.
     """
 
     def __init__(self, mesh=None, depth: Optional[int] = None,
@@ -214,10 +239,21 @@ class MeshExecutor:
         self.linger_s = env_float("OZONE_TPU_MESH_LINGER_MS",
                                   DEFAULT_LINGER_MS) / 1000.0
         self._lock = threading.Lock()
+        #: the dispatcher waits here: a submission, room in the window,
+        #: shutdown
         self._cond = threading.Condition(self._lock)
+        #: the completer waits here: a batch was launched, shutdown
+        self._launched = threading.Condition(self._lock)
         self._lanes: dict[tuple, _Lane] = {}
         self._programs: dict[tuple, Optional[_MeshProgram]] = {}
+        #: launched batches the completer has not taken yet, in launch
+        #: order: the window, at most `depth` long
         self._inflight: deque[tuple] = deque()
+        #: batches packed and not yet resolved: the window, the one
+        #: being packed or launched and the one being pulled
+        self._open = 0
+        #: the batch the completer has taken and not yet resolved
+        self._pulling: Optional[tuple] = None
         #: host staging buffers: (shape, dtype str) -> free list; the
         #: in-flight window recycles depth+1 buffers per lane shape
         self._staging: dict[tuple, list[np.ndarray]] = {}
@@ -230,10 +266,19 @@ class MeshExecutor:
             max_workers=self.n_devices, thread_name_prefix="mesh-dev")
         self._dispatch_ewma_s = 0.005
         self._running = True
+        #: set when the dispatcher has left its loop: nothing more will
+        #: be launched, the completer drains the window and ends
+        self._dispatcher_done = False
         METRICS.gauge("devices").set(self.n_devices)
         METRICS.gauge("depth").set(self.depth)
+        for stage in STAGES:
+            # a stage that never happened reads 0, not nothing
+            METRICS.histogram(f"{stage}_seconds")
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="mesh-executor")
+        self._completer = threading.Thread(
+            target=self._completer_loop, daemon=True, name="mesh-completer")
+        self._completer.start()
         self._thread.start()
 
     # ------------------------------------------------------ program cache
@@ -480,45 +525,51 @@ class MeshExecutor:
     def _loop(self) -> None:
         try:
             while True:
-                entries = None
                 with self._cond:
+                    if len(self._inflight) >= self.depth:
+                        if not self._completer.is_alive():
+                            break  # nobody will ever make room
+                        # ahead of the completer: wait for it to take a
+                        # batch, and let the lanes fill meanwhile
+                        with Stage("mesh:window_full",
+                                   METRICS.histogram("window_full_seconds")):
+                            self._cond.wait(IDLE_TICK_S)
+                        continue
                     now = time.monotonic()
                     lane = self._ready_lane_locked(now)
-                    if lane is not None:
-                        entries, rows = self._pack_locked(lane)
-                    elif not self._inflight:
-                        if not self._running:
-                            if not self._lanes or not any(
-                                    ln.subs for ln in self._lanes.values()):
-                                break
-                            lane = next(ln for ln in self._lanes.values()
-                                        if ln.subs)
-                            entries, rows = self._pack_locked(lane)
-                        else:
-                            # starved: no lane ready, nothing in flight
-                            wake = self._next_wakeup_locked(now)
-                            with Stage("mesh:idle",
-                                       METRICS.histogram("idle_seconds")):
-                                self._cond.wait(
-                                    IDLE_TICK_S if wake is None
-                                    else min(wake, IDLE_TICK_S))
-                            continue
-                if entries is not None:
-                    self._dispatch(lane, entries, rows)
-                    # depth-N buffering: keep up to `depth` mesh batches
-                    # in flight; harvest the oldest only once the window
-                    # is over-full, so launches never wait on pulls
-                    while len(self._inflight) > self.depth:
-                        self._complete(self._inflight.popleft())
-                elif self._inflight:
-                    # nothing packable: never hold results hostage
-                    self._complete(self._inflight.popleft())
+                    if lane is None and not self._running:
+                        # shutting down: what is queued still goes out
+                        lane = next((ln for ln in self._lanes.values()
+                                     if ln.subs), None)
+                        if lane is None:
+                            break
+                    if lane is None:
+                        # starved: no lane ready
+                        wake = self._next_wakeup_locked(now)
+                        with Stage("mesh:idle",
+                                   METRICS.histogram("idle_seconds")):
+                            self._cond.wait(
+                                IDLE_TICK_S if wake is None
+                                else min(wake, IDLE_TICK_S))
+                        continue
+                    entries, rows = self._pack_locked(lane)
+                    self._open += 1
+                    self._max_inflight = max(self._max_inflight, self._open)
+                self._dispatch(lane, entries, rows)
+                # the batch is the completer's now: were this the last
+                # reference, the riders' rows would be freed at the next
+                # pack, under the lock
+                del entries
         except BaseException:  # noqa: BLE001 - dispatcher must not die silently
             log.exception("mesh executor dispatcher crashed")
             raise
         finally:
             with self._lock:
                 self._running = False
+                self._dispatcher_done = True
+                self._launched.notify_all()
+            # what was launched still lands; then whatever is left fails
+            self._completer.join(timeout=CLOSE_TIMEOUT_S)
             self._fail_pending(RuntimeError("mesh executor stopped"))
 
     def _dispatch(self, lane: _Lane, entries, rows: int) -> None:
@@ -577,6 +628,7 @@ class MeshExecutor:
                 if staged is not None:
                     self._give_staging(staged)
                 self._resolve_error(entries, e)
+                self._close_batch()
                 return
         METRICS.counter("dispatches").inc()
         METRICS.counter("stripes_dispatched").inc(rows)
@@ -590,19 +642,59 @@ class MeshExecutor:
         shards = len(getattr(outs[0], "addressable_shards", ()))
         METRICS.gauge("output_shards").set(shards)
         METRICS.counter("output_shards_dispatched").inc(shards)
-        with self._lock:
+        with self._cond:
             METRICS.gauge("queue_depth").set(self._queue_depth_locked())
-        self._inflight.append(
-            (entries, outs, staged, t0, t0_wall,
-             (lane_desc, ops, rows, lane.width)))
-        depth_now = len(self._inflight)
-        self._max_inflight = max(self._max_inflight, depth_now)
-        METRICS.gauge("inflight_depth").set(depth_now)
-        METRICS.gauge("inflight_per_device").set(depth_now)
-        METRICS.gauge("max_inflight_depth").set(self._max_inflight)
+            # from here on the batch is the completer's
+            self._inflight.append(
+                (entries, outs, staged, t0, t0_wall,
+                 (lane_desc, ops, rows, lane.width)))
+            self._launched.notify()
+            METRICS.gauge("inflight_depth").set(self._open)
+            METRICS.gauge("inflight_per_device").set(self._open)
+            METRICS.gauge("max_inflight_depth").set(self._max_inflight)
 
-    def _complete(self, rec: tuple) -> None:
-        entries, outs, staged, t0, t0_wall, dctx = rec
+    def _close_batch(self, pulled: bool = False) -> None:
+        """One packed batch is resolved (or failed) and counts no more.
+        A pulled batch's record goes with it: its device arrays and the
+        riders' rows are freed here, after the lock is released (tens
+        of MiB a rider: their `munmap` must not hold up the lanes)."""
+        rec = None
+        with self._lock:
+            if pulled:
+                rec, self._pulling = self._pulling, None
+            self._open -= 1
+            METRICS.gauge("inflight_depth").set(self._open)
+        del rec
+
+    # -------------------------------------------------------- completer
+    def _completer_loop(self) -> None:
+        try:
+            while True:
+                with self._launched:
+                    if not self._inflight:
+                        if self._dispatcher_done:
+                            break
+                        with Stage(
+                                "mesh:completer_idle",
+                                METRICS.histogram("completer_idle_seconds")):
+                            self._launched.wait(IDLE_TICK_S)
+                        continue
+                    self._pulling = self._inflight.popleft()
+                    self._cond.notify_all()  # room in the window
+                self._complete()
+        except BaseException:  # noqa: BLE001 - completer must not die silently
+            log.exception("mesh executor completer crashed")
+            raise
+        finally:
+            # without a completer nothing resolves: refuse what comes
+            with self._cond:
+                self._running = False
+                self._cond.notify_all()
+
+    def _complete(self) -> None:
+        """Pull, slice and resolve the batch in `_pulling`, and let go
+        of it: whatever fails here fails that batch alone."""
+        entries, outs, staged, t0, t0_wall, dctx = self._pulling
         lane_desc, ops, rows, width = dctx
         try:
             with Stage("mesh:d2h", METRICS.histogram("d2h_seconds")):
@@ -611,49 +703,73 @@ class MeshExecutor:
             if staged is not None:
                 self._give_staging(staged)
             self._resolve_error(entries, e)
+            self._close_batch(pulled=True)
             return
-        if staged is not None:
-            self._give_staging(staged)
-        dt = time.monotonic() - t0
-        self._dispatch_ewma_s += 0.2 * (dt - self._dispatch_ewma_s)
-        METRICS.histogram("dispatch_seconds").observe(
-            dt, entries[0][0].trace_ctx.split(":", 1)[0])
-        METRICS.gauge("inflight_depth").set(len(self._inflight))
-        tracer = Tracer.instance()
-        for sub, off, take, _row in entries:
-            if sub.trace_ctx:
-                tracer.record_span(
-                    "mesh:device_dispatch", child_of=sub.trace_ctx,
-                    start=t0_wall, duration=dt, mono=t0, lane=lane_desc,
-                    qos=sub.cls, stripes=take, ops=ops, rows=rows,
-                    width=width)
-        for sub, off, take, row in entries:
-            sub.parts.append(
-                (off, take, tuple(a[row:row + take] for a in host)))
-            sub.pending_parts -= 1
-            if sub.taken == sub.n and sub.pending_parts == 0:
-                _resolve_sub(sub)
+        # everything after the pull, until the last rider is resolved
+        # and the batch's record, device arrays and riders' rows are
+        # let go of
+        with Stage("mesh:complete", METRICS.histogram("complete_seconds")):
+            try:
+                if staged is not None:
+                    self._give_staging(staged)
+                dt = time.monotonic() - t0
+                self._dispatch_ewma_s += 0.2 * (dt - self._dispatch_ewma_s)
+                METRICS.histogram("dispatch_seconds").observe(
+                    dt, entries[0][0].trace_ctx.split(":", 1)[0])
+                tracer = Tracer.instance()
+                for sub, off, take, _row in entries:
+                    if sub.trace_ctx:
+                        tracer.record_span(
+                            "mesh:device_dispatch", child_of=sub.trace_ctx,
+                            start=t0_wall, duration=dt, mono=t0,
+                            lane=lane_desc, qos=sub.cls, stripes=take,
+                            ops=ops, rows=rows, width=width)
+                # a split submission's other parts are packed by the
+                # dispatcher meanwhile: its bookkeeping under the lock,
+                # the join of its parts outside it
+                whole = []
+                with self._lock:
+                    for sub, off, take, row in entries:
+                        sub.parts.append(
+                            (off, take,
+                             tuple(a[row:row + take] for a in host)))
+                        sub.pending_parts -= 1
+                        if sub.taken == sub.n and sub.pending_parts == 0:
+                            whole.append(sub)
+                for sub in whole:
+                    _resolve_sub(sub)
+            except BaseException as e:  # noqa: BLE001 - this batch's fault alone
+                log.exception("mesh completion failed")
+                self._resolve_error(entries, e)
+                if not isinstance(e, Exception):
+                    raise
+            finally:
+                sub = whole = entries = outs = host = None
+                self._close_batch(pulled=True)
 
     @staticmethod
     def _resolve_error(entries, e: BaseException) -> None:
-        done = set()
-        for sub, _off, _take, _row in entries:
-            if id(sub) not in done:
-                done.add(id(sub))
-                if not sub.future.done():
-                    sub.future.set_exception(e)
+        for sub in {id(en[0]): en[0] for en in entries}.values():
+            _settle(sub.future, error=e)
 
     def _fail_pending(self, e: BaseException) -> None:
         with self._lock:
             subs = [s for lane in self._lanes.values() for s in lane.subs]
             self._lanes.clear()
-            inflight, self._inflight = list(self._inflight), deque()
+            inflight = list(self._inflight)
+            self._inflight.clear()
+            self._open -= len(inflight)
+            self._cond.notify_all()
+            # a batch still being pulled stays the completer's (its
+            # buffer, its count); its riders wait no longer
+            pulling = self._pulling
         for rec in inflight:
-            for sub, _o, _t, _r in rec[0]:
-                subs.append(sub)
+            if rec[2] is not None:
+                self._give_staging(rec[2])
+        for rec in inflight + ([pulling] if pulling else []):
+            subs.extend(entry[0] for entry in rec[0])
         for s in subs:
-            if not s.future.done():
-                s.future.set_exception(e)
+            _settle(s.future, error=e)
 
     # ---------------------------------------------------------- control
     def compile_counts(self) -> int:
@@ -673,11 +789,11 @@ class MeshExecutor:
                               if slots else 0.0)
         snap["ops_per_dispatch"] = (
             snap.get("coalesced_operations", 0) / disp if disp else 0.0)
-        snap["dispatcher_seconds"] = dispatcher_seconds(METRICS)
+        snap["dispatcher_seconds"] = dispatcher_seconds(METRICS, STAGES)
         with self._lock:
             snap["queue_depth"] = self._queue_depth_locked()
             snap["lanes"] = len(self._lanes)
-            snap["inflight"] = len(self._inflight)
+            snap["inflight"] = self._open
             progs = [p for p in self._programs.values() if p is not None]
             snap["programs"] = len(progs)
             snap["programs_host_twin"] = sum(
@@ -697,31 +813,48 @@ class MeshExecutor:
         t_end = time.monotonic() + timeout_s
         while time.monotonic() < t_end:
             with self._lock:
-                if not self._inflight and \
-                        self._queue_depth_locked() == 0:
+                if not self._open and self._queue_depth_locked() == 0:
                     return
             time.sleep(0.002)
 
     def close(self) -> None:
+        """Stop and join both threads: what was submitted still drains,
+        for `CLOSE_TIMEOUT_S` at most; what is pending after it fails."""
+        t_end = time.monotonic() + CLOSE_TIMEOUT_S
         with self._cond:
             self._running = False
             self._cond.notify_all()
-        self._thread.join(timeout=60.0)
+            self._launched.notify_all()
+        for thread in (self._thread, self._completer):
+            thread.join(timeout=max(0.0, t_end - time.monotonic()))
         self._fail_pending(RuntimeError("mesh executor shut down"))
         self._workers.shutdown(wait=False)
 
 
+def _settle(future: Future, result=None, error=None) -> None:
+    """Resolve `future` unless the other thread (or a shutdown) already
+    has: a future is settled exactly once."""
+    try:
+        if error is not None:
+            future.set_exception(error)
+        else:
+            future.set_result(result)
+    except InvalidStateError:  # ozlint: allow[error-swallowing] -- settled already by the other thread or a shutdown: exactly once is the contract
+        pass
+
+
 def _resolve_sub(sub: _Sub) -> None:
+    """All parts of `sub` are host arrays: join them in offset order."""
     if sub.future.done():
         return
     if len(sub.parts) == 1:
-        sub.future.set_result(sub.parts[0][2])
+        _settle(sub.future, sub.parts[0][2])
         return
     sub.parts.sort(key=lambda p: p[0])
     outs = tuple(
         np.concatenate([p[2][i] for p in sub.parts], axis=0)
         for i in range(len(sub.parts[0][2])))
-    sub.future.set_result(outs)
+    _settle(sub.future, outs)
 
 
 class MeshPipeline:

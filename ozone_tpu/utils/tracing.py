@@ -264,14 +264,14 @@ class Stage:
         self.histogram.observe(seconds)
 
 
-def dispatcher_seconds(metrics) -> dict:
-    """Where a dispatcher thread's time went since start, from the
-    stage histograms of its registry: a large idle share says it is
-    starved, a large pack / launch / d2h share says which of its own
-    stages paces the chip; `hold` is how long batches sat in flight
-    beyond their own launch and pull."""
-    took = {k: metrics.histogram(f"{k}_seconds").total
-            for k in ("idle", "pack", "launch", "d2h")}
+def dispatcher_seconds(metrics,
+                       stages=("idle", "pack", "launch", "d2h")) -> dict:
+    """Where a dispatcher's time went since start, from the stage
+    histograms of its registry (`stages`: those of every thread it
+    runs): a large idle share says it is starved, a large pack / launch
+    / d2h share says which of its own stages paces the chip; `hold` is
+    how long batches sat in flight beyond their own launch and pull."""
+    took = {k: metrics.histogram(f"{k}_seconds").total for k in stages}
     took["hold"] = max(0.0, metrics.histogram("dispatch_seconds").total
                        - took["launch"] - took["d2h"])
     return took

@@ -3,6 +3,7 @@ coalescing into full-width dispatches, depth-N in-flight buffering,
 staging reuse, codec-service spill, and the `pad_batch` /
 plan-cache-key edges the executor leans on."""
 
+import sys
 import threading
 import time
 
@@ -193,8 +194,8 @@ def test_cross_operation_coalescing_single_dispatch(executor):
 
 def test_inflight_depth_reaches_window(executor):
     """Depth-N buffering: with a backlog of full batches the dispatcher
-    keeps depth+1 dispatches outstanding before harvesting the oldest —
-    launches never wait on pulls."""
+    keeps `depth` dispatches launched behind the one the completer is
+    pulling, and never more."""
     key = ("encode", "synthetic-depth")
     executor._programs[key] = _identity_program(delay_s=0.005)
     base = executor._max_inflight
@@ -236,6 +237,53 @@ def test_multi_dispatch_submission_reassembles(executor):
     big = np.arange(20 * 4, dtype=np.uint8).reshape(20, 4)
     out = executor.submit(key, big, width=1).result(timeout=30)
     assert np.array_equal(out[0], big)
+
+
+def test_split_submission_resolves_once_under_a_racing_completer(
+        executor, monkeypatch):
+    """A submission split over two dispatches: the completer resolves
+    part one while the dispatcher packs part two. 200 rounds, two
+    submitters: each future resolves exactly once, only when all its
+    parts are host arrays, byte-exact and in offset order."""
+    monkeypatch.setattr(executor, "linger_s", 0.0)
+    key = ("encode", "synthetic-split-race")
+    executor._programs[key] = _identity_program()
+    joined: list = []  # the futures themselves: an id can come again
+    resolve = mesh_executor._resolve_sub
+
+    def counting(sub):
+        assert sub.taken == sub.n and sub.pending_parts == 0
+        assert len(sub.parts) == 2  # 12 rows over a lane of 8
+        joined.append(sub.future)
+        resolve(sub)
+
+    monkeypatch.setattr(mesh_executor, "_resolve_sub", counting)
+    wrong: list[int] = []
+
+    def submitter(n: int):
+        rng = np.random.default_rng(5 + n)
+        for r in range(100):
+            rows = rng.integers(0, 256, (12, 16), dtype=np.uint8)
+            fut = executor.submit(key, rows.copy(), width=1)
+            if not np.array_equal(fut.result(timeout=30)[0], rows):
+                wrong.append(100 * n + r)
+
+    threads = [threading.Thread(target=submitter, args=(n,))
+               for n in range(2)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)  # hand the GIL over mid-bookkeeping
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    executor.quiesce()
+    assert not wrong
+    assert len(joined) == len({id(f) for f in joined}) == 200
+    assert executor.stats()["inflight"] == 0
 
 
 def test_program_error_fails_future(executor):
