@@ -40,6 +40,7 @@ from ozone_tpu.codec.pipeline import (
     batched,
     decode_batch_size,
 )
+from ozone_tpu.parallel import dispatch
 from ozone_tpu.storage.ids import BlockData, ChunkInfo, StorageError
 from ozone_tpu.utils.checksum import ChecksumType
 from ozone_tpu.utils.tracing import Tracer
@@ -129,15 +130,14 @@ class ECBlockGroupReader:
         #: operation deadline captured at the public entry points and
         #: re-activated on reader-pool worker threads
         self._deadline: Optional[resilience.Deadline] = None
-        #: shared codec service (None = per-operation pipeline): decode
-        #: batches coalesce with other operations sharing the erasure
-        #: pattern (reconstruction storms, fleets of degraded readers)
+        #: the class its decode batches queue in: they coalesce with
+        #: other operations sharing the erasure pattern (reconstruction
+        #: storms, fleets of degraded readers)
         self._qos = qos_class
-        #: optional parallel.mesh_executor.MeshExecutor: decode batches
-        #: route through its persistent submission queue instead of the
-        #: single-chip service — many concurrent readers (a
-        #: reconstruction storm) coalesce into full-width mesh
-        #: dispatches on long-lived SPMD programs
+        #: optional parallel.mesh_executor.MeshExecutor, handed to the
+        #: door with every decode stream: a reader that was handed one
+        #: is one of many (a repair storm) and its bulk batches join
+        #: that executor's lanes; a reader handed none stays on one chip
         self._executor = executor
 
     # ---------------------------------------------------------------- helpers
@@ -476,17 +476,13 @@ class ECBlockGroupReader:
         batch = np.zeros((1, len(valid), self.cell), dtype=np.uint8)
         for vi, x in enumerate(valid):
             batch[0, vi] = self._peek_cell(x, stripe)
-        svc = codec_service.maybe_service()
-        if svc is not None:
-            # lone-stripe decode rides the service at width 1: no linger
-            # added to the latency-critical hedge, but concurrent hedges
-            # on the same pattern still serialize through one dispatcher
-            # instead of contending for the chip
-            rec, _crcs = codec_service.wait_result(svc.submit(
-                codec_service.decode_key(self.spec, valid, (u,)), fn,
-                batch, width=1, qos=self._qos, deadline=self._deadline))
-        else:
-            rec, _crcs = fn(batch)
+        # lone-stripe decode at width 1: no linger added to the
+        # latency-critical hedge, but concurrent hedges on the same
+        # pattern still serialize through one dispatcher instead of
+        # contending for the chip
+        rec, _crcs = codec_service.wait_result(dispatch.submit(
+            codec_service.decode_key(self.spec, valid, (u,)), fn,
+            batch, width=1, qos=self._qos, deadline=self._deadline))
         return np.asarray(rec)[0, 0]
 
     def _fanout_survivors(self, pool, fill_unit, valid: list[int],
@@ -730,32 +726,19 @@ class ECBlockGroupReader:
             yield out
 
     def _decode_pipe(self, valid: list[int], targets: list[int]):
-        """The recovery dispatch pipeline, best path first: persistent
-        mesh executor (decode batches join its submission queue, where
-        every other reader repairing the same erasure pattern — a
-        reconstruction storm is MANY groups with ONE pattern —
-        coalesces into full-width mesh dispatches on long-lived
-        programs), then the caller-supplied mesh, then the shared
-        single-chip codec service, then a per-operation pipeline."""
-        if self._executor is not None and self.mesh is None:
-            try:
-                return self._executor.pipeline(
-                    codec_service.decode_key(self.spec, valid, targets),
-                    width=self._decode_batch, qos=self._qos)
-            except KeyError:  # ozlint: allow[error-swallowing] -- no mesh program for this spec: fall through to the single-chip paths below
-                pass
-        fn = (self._mesh_decode_fn(valid, targets)
-              if self.mesh is not None
-              else make_fused_decoder(self.spec, valid, targets))
-        svc = codec_service.maybe_service() if self.mesh is None else None
-        if svc is not None:
-            # shared-service path: this read's decode batches share
-            # device dispatches with every other in-flight operation on
-            # the same erasure pattern
-            return codec_service.ServicePipeline(
-                svc, codec_service.decode_key(self.spec, valid, targets),
-                fn, width=self._decode_batch, qos=self._qos)
-        return DeviceBatchPipeline(fn)
+        """The recovery dispatch pipeline. Through the door
+        (`parallel/dispatch.py`) this read's decode batches share device
+        dispatches with every other operation on the same erasure
+        pattern, a reconstruction storm being MANY groups with ONE
+        pattern. A caller-supplied raw `mesh` (the datanode daemons'
+        route, ROADMAP D2b) runs the SPMD call per operation, unqueued."""
+        if self.mesh is not None:
+            return DeviceBatchPipeline(self._mesh_decode_fn(valid, targets))
+        return dispatch.pipeline(
+            codec_service.decode_key(self.spec, valid, targets),
+            make_fused_decoder(self.spec, valid, targets),
+            width=self._decode_batch, qos=self._qos,
+            executor=self._executor)
 
     def _mesh_decode_fn(self, valid: list[int], targets: list[int]):
         """Multi-chip decode (ECReconstructionCoordinator.java:146 run on

@@ -45,6 +45,7 @@ from ozone_tpu.codec import hostmem
 from ozone_tpu.codec import service as codec_service
 from ozone_tpu.codec.api import CoderOptions
 from ozone_tpu.codec.fused import FusedSpec, effective_bpc, make_fused_encoder
+from ozone_tpu.parallel import dispatch
 from ozone_tpu.scm.pipeline import Pipeline
 from ozone_tpu.storage.ids import (
     BlockData,
@@ -300,8 +301,8 @@ class ECKeyWriter:
         # the GIL, so the stripe wall-time is the slowest node, not the
         # sum (the reference's per-stream async BlockOutputStreams)
         self._rpc_pool: Optional[ThreadPoolExecutor] = None
-        # encode pipeline: the device batch in flight (stripes, parity,
-        # crcs device arrays); network writes of batch N overlap the
+        # encode pipeline: the batch in flight, (stripes, future of its
+        # parity and crcs); network writes of batch N overlap the
         # device encode + device->host pull of batch N+1
         self._pending: Optional[tuple] = None
 
@@ -345,65 +346,38 @@ class ECKeyWriter:
             return
         stripes, self._queue = self._queue, []
         batch = np.stack([s.data for s in stripes])  # [B, k, C]
-        svc = codec_service.maybe_service()
-        if svc is not None:
-            # shared-service path: a partial batch (the tail of a small
-            # PUT) is marked tail so it rides the linger path — it waits
-            # up to OZONE_TPU_CODEC_LINGER_MS to share its dispatch with
-            # OTHER operations' stripes instead of paying a full batch
-            # slot alone (counted in codec.service tail_flushes)
-            fut = svc.submit(
-                codec_service.encode_key(self._spec), self._fused, batch,
-                width=self.stripe_batch, qos=self._qos,
-                tail=len(stripes) < self.stripe_batch,
-                deadline=self._deadline)
-            prev, self._pending = self._pending, (stripes, fut)
-        else:
-            with Tracer.instance().span("codec:device_dispatch",
-                                        rows=len(stripes),
-                                        width=self.stripe_batch,
-                                        direct=True):
-                parity_dev, crcs_dev = self._fused(batch)  # async dispatch
-                for a in (parity_dev, crcs_dev):
-                    # start the D2H transfer eagerly where the backend
-                    # supports it, so it runs under the previous batch's
-                    # network writes
-                    try:
-                        a.copy_to_host_async()
-                    except (AttributeError, RuntimeError):  # ozlint: allow[error-swallowing] -- optional eager-D2H hint; backends without it fall back to sync pull
-                        pass
-            prev, self._pending = self._pending, (stripes, parity_dev,
-                                                  crcs_dev)
+        # a partial batch (the tail of a small PUT) is marked tail so it
+        # rides the linger path: it waits up to OZONE_TPU_CODEC_LINGER_MS
+        # to share its dispatch with OTHER operations' stripes instead of
+        # paying a full batch slot alone (counted in tail_flushes)
+        fut = dispatch.submit(
+            codec_service.encode_key(self._spec), self._fused, batch,
+            width=self.stripe_batch, qos=self._qos,
+            tail=len(stripes) < self.stripe_batch,
+            deadline=self._deadline)
+        prev, self._pending = self._pending, (stripes, fut)
         if prev is not None:
-            self._write_batch(*self._resolve_pending(prev))
+            self._write_pending(prev)
 
-    @staticmethod
-    def _resolve_pending(prev: tuple) -> tuple:
-        """(stripes, parity, crcs) of an in-flight batch, whether it
-        rode the shared codec service (future) or a direct dispatch
-        (device arrays)."""
-        if len(prev) == 2:
-            stripes, fut = prev
-            parity, crcs = codec_service.wait_result(fut)
-            return stripes, parity, crcs
-        return prev
+    def _write_pending(self, prev: tuple) -> None:
+        stripes, fut = prev
+        self._write_batch(stripes, *codec_service.wait_result(fut))
 
     def _drain_pending(self) -> None:
         prev, self._pending = self._pending, None
         if prev is not None:
-            self._write_batch(*self._resolve_pending(prev))
+            self._write_pending(prev)
 
     def _write_batch(self, stripes, parity_dev, crcs_dev) -> None:
         """Write one encoded batch. The batched-RPC path writes each run
         of stripes bound for one group as ONE WriteChunksCommit stream
         per unit — all the run's chunk frames plus the piggybacked
         putBlock, so the transport round trip is paid once per run
-        instead of twice per stripe (docs/PERF.md per-layer table: the
-        round trip dominates). Ack watermark and rollback move to run
-        granularity, still finer than the reference's block-granular
-        streaming mode. Falls back to the per-stripe path (commit order
-        defines the ack watermark, as in flushStripeFromQueue:526) when
-        a member lacks the verb."""
+        instead of twice per stripe (the round trip dominates). Ack
+        watermark and rollback move to run granularity, still finer
+        than the reference's block-granular streaming mode. Falls back
+        to the per-stripe path (commit order defines the ack watermark,
+        as in flushStripeFromQueue:526) when a member lacks the verb."""
         with Tracer.instance().span("ec:flush", stripes=len(stripes)):
             self._write_batch_traced(stripes, parity_dev, crcs_dev)
 

@@ -87,7 +87,7 @@ def _sendmsg_all(sock: socket.socket, parts: list) -> None:
     frame headers and payload views leave the process zero-copy in a
     handful of syscalls instead of two writes per chunk. On shared-core
     rigs the per-chunk wakeup this replaces — not bandwidth — dominated
-    PUT latency (docs/PERF.md round 6)."""
+    PUT latency."""
     mv = [p if isinstance(p, memoryview) else memoryview(p) for p in parts]
     i = 0
     while i < len(mv):

@@ -175,10 +175,8 @@ def re_encode_xor_key_to_rs(
         make_fused_reencoder,
         reencode_layout_crcs,
     )
-    from ozone_tpu.codec.pipeline import (
-        DeviceBatchPipeline,
-        decode_batch_size,
-    )
+    from ozone_tpu.codec.pipeline import decode_batch_size
+    from ozone_tpu.parallel import dispatch
     from ozone_tpu.utils.checksum import Checksum
 
     info = om.lookup_key(volume, bucket, key)
@@ -285,17 +283,12 @@ def re_encode_xor_key_to_rs(
         # depth-1 pipeline over stripe windows: the ec_writer's
         # _flush_queue structure on the conversion path — target writes
         # of window N overlap the device pass + D2H of window N+1.
-        # Routed through the shared codec service (bulk class) when
-        # enabled so conversion windows coalesce with other operations'
+        # Bulk class: conversion windows coalesce with other operations'
         # stripes and defer to interactive traffic.
-        svc = codec_service.maybe_service()
-        if svc is not None:
-            lane_key = (codec_service.reencode_key(spec, lost) if parity_ok
-                        else codec_service.encode_key(spec))
-            pipe = codec_service.ServicePipeline(
-                svc, lane_key, fn, width=window, qos="bulk")
-        else:
-            pipe = DeviceBatchPipeline(fn)
+        pipe = dispatch.pipeline(
+            codec_service.reencode_key(spec, lost) if parity_ok
+            else codec_service.encode_key(spec),
+            fn, width=window, qos="bulk")
         health = getattr(clients, "health", None)
         for s0 in range(0, stripes, window):
             resilience.check_deadline("re_encode_window")

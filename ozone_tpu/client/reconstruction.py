@@ -79,9 +79,12 @@ class ReconstructionStorm:
     `scm` answers `list_containers()` and `list_nodes()`: an in-process
     StorageContainerManager or a GrpcScmClient, planned from alike;
     `clients` is the DatanodeClientFactory reaching the surviving nodes.
-    `executor` defaults to the process mesh executor when one can exist
-    (`mesh_executor.maybe_executor()`); with no mesh the storm still
-    runs, through the shared single-chip codec service.
+    `executor` is the mesh executor the storm REPORTS on (quiesce, the
+    `mesh_*` deltas of its report) and hands, through its coordinator,
+    to the door with every decode stream; it defaults to the
+    process-wide one. Which queue a decode joins is the door's decision
+    (`parallel/dispatch.py`): with no mesh the storm still runs,
+    through the single-chip codec service.
     """
 
     def __init__(self, scm, clients, executor=None,

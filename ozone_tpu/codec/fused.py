@@ -144,7 +144,7 @@ def _prefer_host_coder() -> bool:
     magnitude faster) -> native twin; any other platform -> the jitted
     program. A backend that fails to initialise raises here: a process
     that cannot reach its chip must not finish on the host.
-    OZONE_TPU_FUSED_BACKEND=jax|native overrides (tests, bench.py)."""
+    OZONE_TPU_FUSED_BACKEND=jax|native overrides (tests)."""
     forced = os.environ.get("OZONE_TPU_FUSED_BACKEND", "")
     if forced == "jax":
         return False
@@ -247,8 +247,7 @@ def _decode_apply_jit(valid_units: jax.Array, a_bits: jax.Array,
     (batch, erasure count, cell, bpc) — pattern churn during multi-unit
     failures swaps the tiny device matrix, never the compiled program.
     The old per-(valid, erased) lru_cache of jitted closures evicted
-    whole executables under churn and recompiled mid-read (the measured
-    21% decode spread in BENCH_r05)."""
+    whole executables under churn and recompiled mid-read."""
     rec = gf_apply(valid_units, a_bits)  # [B, e, C]
     crcs = crc_device.crc_slices(rec, k_planes, zeros_crc)
     return rec, crcs
@@ -262,7 +261,7 @@ def _decode_apply_nocrc_jit(valid_units: jax.Array, a_bits: jax.Array):
 
 def decode_jit_cache_size() -> int:
     """Compiled fused-decode executables currently cached. The
-    pattern-churn tests/bench probe this to assert that a NEW erasure
+    pattern-churn tests probe this to assert that a NEW erasure
     pattern of an already-seen shape costs zero recompiles."""
     return int(_decode_apply_jit._cache_size()
                + _decode_apply_nocrc_jit._cache_size())

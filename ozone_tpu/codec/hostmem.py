@@ -20,7 +20,7 @@ leases feeding the gRPC datapath in Apache Ozone — the same argument
 (allocation reuse + explicit lifetime beats GC'd byte[] churn) applied
 to the Python side of the sidecar protocol.
 
-Env knobs (documented in docs/PERF.md):
+Env knobs:
   OZONE_TPU_POOL_MAX_MIB        total bytes the pool *retains* on free
                                 lists (default 256). Leases above the
                                 retention budget are released to the OS.

@@ -1,18 +1,10 @@
-"""Depth-1 device batch pipeline: the decode twin of the writer's
-in-flight encode batch.
+"""The raw `mesh=` route's depth-1 wrapper, and the decode batch size.
 
-`ec_writer._flush_queue` keeps ONE encoded batch in flight so network
-writes of batch N overlap the device encode + device->host pull of batch
-N+1. This module extracts that structure so the READ/repair side — the
-degraded client read (`client/ec_reader`), offline reconstruction
-(`storage/reconstruction`) and the XOR->RS re-encode (`client/re_encode`)
-— drives the same overlap: unit fetch / target writes of one batch run
-under the device decode+CRC and D2H pull of the next.
-
-Works with any fused fn returning a device array or tuple of them (the
-native host twin returns numpy; then submit() degrades to synchronous
-calls with zero overhead, which is correct — there is nothing to
-overlap on the host path).
+`DeviceBatchPipeline` keeps one unqueued device call in flight for a
+caller that was handed a raw device mesh (the datanode daemons'
+reconstruction coordinator, `client/ec_reader._decode_pipe`): the call
+of batch N+1 is dispatched before batch N's outputs are pulled. Every
+other consumer goes through `parallel/dispatch.py`.
 """
 
 from __future__ import annotations
@@ -42,8 +34,7 @@ def decode_batch_size(default: int = DEFAULT_DECODE_BATCH) -> int:
 
 def _start_d2h(out: Any) -> None:
     # eager D2H where the backend supports it: the pull runs under the
-    # caller's host work on the previous batch (same trick as
-    # ec_writer._flush_queue)
+    # caller's host work on the previous batch
     try:
         out.copy_to_host_async()  # ozlint: allow[span-on-dispatch] -- the D2H hint helper itself; every caller brackets it in its own dispatch span
     except (AttributeError, RuntimeError):  # ozlint: allow[error-swallowing] -- optional eager-D2H hint; backends without it fall back to sync pull
@@ -66,7 +57,7 @@ class DeviceBatchPipeline:
         if not isinstance(outs, tuple):
             outs = (outs,)
         for a in outs:
-            _start_d2h(a)  # ozlint: allow[span-on-dispatch] -- per-operation pipeline: the owning op (ec:flush / ec:read) brackets submit() in its span
+            _start_d2h(a)  # ozlint: allow[span-on-dispatch] -- per-operation pipeline: the owning op (repair:block / ec:read) brackets submit() in its span
         prev, self._pending = self._pending, (ctx, outs)
         return self._to_host(prev)
 

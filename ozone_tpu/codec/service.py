@@ -1,9 +1,8 @@
 """Shared codec service: cross-request continuous batching for the chip.
 
-Every perf number so far was measured with ONE operation owning the whole
-`DeviceBatchPipeline`; at millions-of-users concurrency the real traffic
-shape is many small concurrent PUTs/GETs, each far too small to fill a
-stripe batch, all contending for the device. This module applies the
+At millions-of-users concurrency the real traffic shape is many small
+concurrent PUTs/GETs, each far too small to fill a stripe batch, all
+contending for the device. This module applies the
 continuous-batching idea from LLM serving (Orca, OSDI '22) to the
 GF(2^8) codec: a per-process, thread-safe `CodecService` owns the device
 and takes stripe work (encode, decode/recover, re-encode) from ANY
@@ -16,10 +15,10 @@ submitters copy in parallel, under the launch, device pass and D2H of
 the batch before. Batches are constant-shape (padded tail, so the plan
 caches in `codec/fused.py` keep serving ONE compiled program per shape —
 no new XLA compiles). The ONE dispatcher thread never copies payload
-bytes: it launches what is already packed, double-buffers dispatches
-exactly like `DeviceBatchPipeline`, and completes per-submitter futures
-as results land. The same consolidation argument f4 (OSDI '14) makes
-for warm-blob IO, applied to device dispatches.
+bytes: it launches what is already packed, keeps one older dispatch
+in flight under the next (a depth-1 double buffer), and completes
+per-submitter futures as results land. The same consolidation argument
+f4 (OSDI '14) makes for warm-blob IO, applied to device dispatches.
 
 Staging buffers are recycled, never allocated per dispatch: page-aligned
 leases of `codec/hostmem.py`'s pool, kept on the service's own small
@@ -56,9 +55,11 @@ lane exists only while it has queued stripes, and binds the fused
 callable (and the row shape) its first submitter brought — so backend choice (device vs
 native twin) and test instrumentation stay with the submitting layer.
 
-``OZONE_TPU_CODEC_SERVICE=0`` disables the service; every refactored
-caller keeps its per-operation `DeviceBatchPipeline` as the degraded
-no-service fallback.
+Consumers do not call this module to submit: `parallel/dispatch.py`
+decides which queue a batch joins, this one or the mesh executor's.
+What the two schedulers share is written here, the lower of the two:
+the submission record (`_Sub`), the join of a split submission
+(`_resolve_sub`, `_settle`, `_resolve_error`) and `wait_result`.
 """
 
 from __future__ import annotations
@@ -69,9 +70,9 @@ import os
 import threading
 import time
 from collections import deque
-from concurrent.futures import Future
+from concurrent.futures import Future, InvalidStateError
 from concurrent.futures import TimeoutError as _FutTimeout
-from typing import Any, Callable, Optional
+from typing import Callable, Optional
 
 import numpy as np
 
@@ -109,11 +110,6 @@ _DISPATCH_EWMA_SEED_S = 0.005
 _STAGING_KEEP = 4
 
 
-def enabled() -> bool:
-    """The service disable switch (OZONE_TPU_CODEC_SERVICE=0)."""
-    return os.environ.get("OZONE_TPU_CODEC_SERVICE", "1") != "0"
-
-
 def qos_weights() -> dict[str, float]:
     """Parse OZONE_TPU_CODEC_QOS ("cls=weight,cls=weight"); unknown
     classes default to weight 1."""
@@ -131,15 +127,17 @@ def qos_weights() -> dict[str, float]:
 
 
 def _ambient_deadline():
-    """The submitter's operation deadline, if any (lazy import: codec
-    must stay importable without the client layer)."""
+    """The submitter's operation deadline, if any: the one place where
+    the schedulers read the client layer's context (lazy import: codec
+    must stay importable without it)."""
     from ozone_tpu.client import resilience
 
     return resilience.current()
 
 
 class _Sub:
-    """One submission: `n` same-shape stripes from one operation."""
+    """One submission: `n` same-shape stripes from one operation, in
+    either scheduler's lanes."""
 
     __slots__ = ("stripes", "n", "future", "cls", "deadline", "t_enq",
                  "t_enq_wall", "t_ready", "trace_ctx", "tail", "taken",
@@ -147,8 +145,8 @@ class _Sub:
 
     def __init__(self, stripes: np.ndarray, future: Future, cls: str,
                  deadline, tail: bool):
-        #: kept until the rows have launched: a borrowed batch is a view
-        #: of it, and a spilled lane hands it to the mesh executor
+        #: kept until the rows have launched: the service's borrowed
+        #: batch is a view of it, and the mesh executor packs from it
         self.stripes = stripes
         self.n = int(stripes.shape[0])
         self.future = future
@@ -157,7 +155,9 @@ class _Sub:
         self.t_enq = time.monotonic()
         self.t_enq_wall = time.time()
         #: when its rows were all in place (monotonic): the start of its
-        #: queue wait. `inf` while the submitter is still copying
+        #: queue wait in the service, `inf` while the submitter is still
+        #: copying. The mesh executor packs on its dispatcher and counts
+        #: from `t_enq`
         self.t_ready = self.t_enq
         #: submitter's trace context: the dispatcher runs on its own
         #: thread, so per-submission spans must join the operation's
@@ -375,8 +375,7 @@ class CodecService:
             with self._cond:
                 self._abandon_locked(lane, sub, fills[landed:])
                 self._cond.notify()
-            if not sub.future.done():
-                sub.future.set_exception(e)
+            _settle(sub.future, error=e)
             raise
         if sub.trace_ctx:
             Tracer.instance().record_span(
@@ -529,97 +528,18 @@ class CodecService:
         self._settle_lane_locked(lane)
         return batch
 
-    # ------------------------------------------------------------ spill
-    def _collect_spill_locked(self) -> list[tuple]:
-        """Whole-lane overflow redirection to the mesh executor: when
-        the single-chip queue depth crosses the spill watermark, pop
-        entire lanes whose submissions are all still untouched (no
-        stripe dispatched yet — a spilled future must be served wholly
-        by one executor) and hand them to the mesh. Pops deepest-first
-        and keeps the watermark's worth of work here: the single chip
-        stays fed while the overflow drains on the neighbors."""
-        from ozone_tpu.parallel import mesh_executor
-
-        if not mesh_executor.spill_enabled():
-            return []
-        depth = self._queue_depth_locked()
-        watermark = mesh_executor.spill_watermark()
-        if depth <= watermark:
-            return []
-        mex = mesh_executor.maybe_executor()
-        if mex is None:
-            return []
-        spilled: list[tuple] = []
-        for lane in sorted(self._lanes.values(),
-                           key=lambda ln: -ln.queued):
-            if depth <= watermark:
-                break
-            if not lane.subs or any(s.taken for s in lane.subs) \
-                    or any(b.unfilled for b in lane.batches):
-                # a copy still landing in a buffer about to be handed
-                # back: this lane spills on a later pass
-                continue
-            key = lane.lane_key[0]
-            ok = mex.accepts_cached(key)
-            if ok is not True:
-                if ok is None:
-                    # unknown key: warm it outside the lock; next
-                    # iteration spills it (resolution may compile, and
-                    # submitters must not stall behind that)
-                    spilled.append((mex, key, None))
-                continue
-            self._lanes.pop(lane.lane_key, None)
-            for sub in lane.subs:
-                self._class_left_locked(sub.cls)
-            # the mesh packs from `sub.stripes`: the rows staged here
-            # are dropped and their buffers handed back
-            for batch in lane.batches:
-                self._give_staging_locked(batch)
-            depth -= lane.queued
-            spilled.append((mex, key, lane))
-        real = [s for s in spilled if s[2] is not None]
-        if real:
-            METRICS.counter("mesh_spill_lanes").inc(len(real))
-            METRICS.counter("mesh_spill_stripes").inc(
-                sum(lane.queued for _, _, lane in real))
-            METRICS.gauge("queue_depth").set(depth)
-        return spilled
-
-    @staticmethod
-    def _spill(spilled: list[tuple]) -> None:
-        """Absorb popped lanes into the mesh executor (outside the
-        service lock: program resolution may compile). Entries with no
-        lane are resolution warm-ups for keys the peek didn't know."""
-        for mex, key, lane in spilled:
-            if lane is None:
-                try:
-                    mex.accepts(key)
-                except Exception:  # noqa: BLE001 - warm-up only; lane stayed queued here
-                    log.exception("mesh warm-up failed for %r", key)
-                continue
-            _, width, qos = lane.lane_key
-            try:
-                mex.absorb(key, width, qos, list(lane.subs))
-            except BaseException as e:  # noqa: BLE001 - spill must never strand futures
-                log.exception("mesh spill failed for %r", key)
-                for sub in lane.subs:
-                    if not sub.future.done():
-                        sub.future.set_exception(e)
-
     # ------------------------------------------------------- dispatcher
     def _loop(self) -> None:
         try:
             while True:
                 batch = None
-                spilled = None
                 with self._cond:
                     now = time.monotonic()
-                    spilled = self._collect_spill_locked()
                     picked = self._pick_lane_locked(now)
                     if picked is not None:
                         lane, reason = picked
                         batch = self._take_locked(lane)
-                    elif not self._inflight and not spilled:
+                    elif not self._inflight:
                         if not self._running:
                             if not self._lanes:
                                 break
@@ -639,10 +559,6 @@ class CodecService:
                                     IDLE_TICK_S if wake is None
                                     else min(wake, IDLE_TICK_S))
                             continue
-                if spilled:
-                    # outside the lock: absorption resolves (and may
-                    # compile) mesh programs; submitters keep flowing
-                    self._spill(spilled)
                 if batch is not None:
                     self._dispatch(lane, batch, reason)
                     # depth-1 double buffer: keep ONE older batch in
@@ -728,7 +644,7 @@ class CodecService:
                     # eager D2H under the next batch's host work
                     _start_d2h(a)
         except BaseException as e:  # noqa: BLE001 - per-dispatch fault
-            self._resolve_error(entries, e)
+            _resolve_error(entries, e)
             with self._lock:
                 self._give_staging_locked(batch)
             return
@@ -759,7 +675,7 @@ class CodecService:
             with Stage("codec:d2h", METRICS.histogram("d2h_seconds")):
                 host = tuple(np.asarray(a) for a in outs)
         except BaseException as e:  # noqa: BLE001 - D2H fault
-            self._resolve_error(entries, e)
+            _resolve_error(entries, e)
             host = None
         # the outputs are host arrays (or lost): the launch's asynchronous
         # H2D is over and the staging buffer can be refilled. Not one
@@ -798,31 +714,7 @@ class CodecService:
                 (off, take, tuple(a[row:row + take] for a in host)))
             sub.pending_parts -= 1
             if sub.taken == sub.n and sub.pending_parts == 0:
-                self._resolve(sub)
-
-    @staticmethod
-    def _resolve(sub: _Sub) -> None:
-        if sub.future.done():
-            # an earlier part of this (split) submission already failed
-            # the future; later parts complete harmlessly
-            return
-        if len(sub.parts) == 1:
-            sub.future.set_result(sub.parts[0][2])
-            return
-        sub.parts.sort(key=lambda p: p[0])
-        outs = tuple(
-            np.concatenate([p[2][i] for p in sub.parts], axis=0)
-            for i in range(len(sub.parts[0][2])))
-        sub.future.set_result(outs)
-
-    @staticmethod
-    def _resolve_error(entries, e: BaseException) -> None:
-        done = set()
-        for sub, _off, _take, _row in entries:
-            if id(sub) not in done:
-                done.add(id(sub))
-                if not sub.future.done():
-                    sub.future.set_exception(e)
+                _resolve_sub(sub)
 
     def _fail_pending(self, e: BaseException) -> None:
         with self._lock:
@@ -835,8 +727,7 @@ class CodecService:
             for sub, _o, _t, _r in rec[0]:
                 subs.append(sub)
         for s in subs:
-            if not s.future.done():
-                s.future.set_exception(e)
+            _settle(s.future, error=e)
 
     # ---------------------------------------------------------- control
     def stats(self) -> dict:
@@ -856,7 +747,6 @@ class CodecService:
             snap["inflight"] = len(self._inflight)
         snap["linger_ms"] = self.linger_s * 1000.0
         snap["weights"] = dict(self.weights)
-        snap["enabled"] = enabled()
         return snap
 
     def close(self) -> None:
@@ -878,12 +768,6 @@ def get_service() -> CodecService:
         if _service is None or not _service._running:
             _service = CodecService()
         return _service
-
-
-def maybe_service() -> Optional[CodecService]:
-    """The service, or None when disabled — the ONE check every
-    refactored datapath makes before choosing its fallback pipeline."""
-    return get_service() if enabled() else None
 
 
 def reset_for_tests() -> None:
@@ -914,9 +798,7 @@ def wait_result(fut: Future, grace_s: Optional[float] = None):
     margin — a near-expiry submission is being force-flushed, so the
     right behavior is to collect that partial-batch result, not to
     declare DEADLINE_EXCEEDED while it is already on the device."""
-    from ozone_tpu.client import resilience
-
-    d = resilience.current()
+    d = _ambient_deadline()
     if d is None:
         return fut.result()
     if grace_s is None:
@@ -934,38 +816,36 @@ def wait_result(fut: Future, grace_s: Optional[float] = None):
             f"service") from None
 
 
-class ServicePipeline:
-    """Drop-in twin of `codec.pipeline.DeviceBatchPipeline` backed by
-    the shared service: submit(batch, ctx) routes the batch through the
-    coalescing dispatcher and returns the PREVIOUS submission's host
-    results (ctx, outs) — so every depth-1 pipeline consumer (degraded
-    reads, re-encode, lifecycle tiering) keeps its overlap structure
-    and gains cross-request batching with a two-line change."""
+# ------------------------------------------- shared by both schedulers
+def _settle(future: Future, result=None, error=None) -> None:
+    """Resolve `future` unless another thread (or a shutdown) already
+    has: a future is settled exactly once."""
+    try:
+        if error is not None:
+            future.set_exception(error)
+        else:
+            future.set_result(result)
+    except InvalidStateError:  # ozlint: allow[error-swallowing] -- settled already by the other thread or a shutdown: exactly once is the contract
+        pass
 
-    def __init__(self, svc: CodecService, key: tuple, fn: Callable,
-                 width: int, qos: str = "interactive"):
-        self._svc = svc
-        self._key = key
-        self._fn = fn
-        self._width = max(1, int(width))
-        self._qos = qos
-        self._pending: Optional[tuple] = None
 
-    def submit(self, batch: np.ndarray, ctx: Any = None,
-               tail: bool = False) -> Optional[tuple]:
-        fut = self._svc.submit(self._key, self._fn, batch,
-                               width=self._width, qos=self._qos,
-                               tail=tail)
-        prev, self._pending = self._pending, (ctx, fut)
-        return self._to_host(prev)
+def _resolve_sub(sub: _Sub) -> None:
+    """All parts of `sub` are host arrays: join them in offset order."""
+    if sub.future.done():
+        # an earlier part of this (split) submission already failed
+        # the future; later parts complete harmlessly
+        return
+    if len(sub.parts) == 1:
+        _settle(sub.future, sub.parts[0][2])
+        return
+    sub.parts.sort(key=lambda p: p[0])
+    outs = tuple(
+        np.concatenate([p[2][i] for p in sub.parts], axis=0)
+        for i in range(len(sub.parts[0][2])))
+    _settle(sub.future, outs)
 
-    def drain(self) -> Optional[tuple]:
-        prev, self._pending = self._pending, None
-        return self._to_host(prev)
 
-    @staticmethod
-    def _to_host(entry: Optional[tuple]) -> Optional[tuple]:
-        if entry is None:
-            return None
-        ctx, fut = entry
-        return ctx, wait_result(fut)
+def _resolve_error(entries, e: BaseException) -> None:
+    """Fail every rider of a batch, (sub, offset, take, row) each."""
+    for sub in {id(en[0]): en[0] for en in entries}.values():
+        _settle(sub.future, error=e)

@@ -10,8 +10,8 @@ sealed extents (Huang et al., ATC '12) play in production stores.
   rules persist in OM bucket metadata through the replicated ring.
 - service.py: the leader-singleton sweeper — term-fenced like
   scm/sequence_id.py, resumable cursor committed through the ring.
-- executor.py: the datapath — many keys per DeviceBatchPipeline
-  submission through the fused encode+CRC, commit fenced against
+- executor.py: the datapath — many keys per device batch
+  through the fused encode+CRC, commit fenced against
   concurrent overwrites, old blocks retired via the SCM deletion chain.
 """
 

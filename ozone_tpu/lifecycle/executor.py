@@ -2,10 +2,9 @@
 
 The datapath half of the lifecycle subsystem. Where `client/re_encode.py`
 converts ONE key per call, this executor packs stripe windows from MANY
-keys into each `DeviceBatchPipeline` submission, so a sweep over
+keys into each pipeline submission, so a sweep over
 thousands of small cold keys still drives the fused encode+CRC kernel
-at full batch width (the property the acceptance bench `tiering_gib_s`
-measures). Every dispatch has the SAME [window, k, cell] shape — the
+at full batch width. Every dispatch has the SAME [window, k, cell] shape — the
 final partial window is zero-padded — so the whole sweep compiles ONE
 device program, exactly like the decode-plan cache keeps repair to one.
 
@@ -234,7 +233,7 @@ class TieringExecutor:
             spec = FusedSpec(conf.ec, ctype, bpc)
             packer = packers[key] = _SpecPacker(
                 self, make_fused_encoder(spec), conf.ec, ctype, bpc,
-                stats, spec=spec)
+                stats, spec)
         return packer
 
     def _pack_key(self, packer: "_SpecPacker", volume: str, bucket: str,
@@ -336,13 +335,12 @@ class TieringExecutor:
 
 class _SpecPacker:
     """Accumulates stripe windows across keys into constant-shape
-    device batches over one depth-1 DeviceBatchPipeline."""
+    device batches over one depth-1 pipeline."""
 
     def __init__(self, executor: TieringExecutor, fn, opts, ctype, bpc,
-                 stats: dict, spec=None):
+                 stats: dict, spec):
         from ozone_tpu.codec import service as codec_service
-        from ozone_tpu.codec.pipeline import DeviceBatchPipeline
-        from ozone_tpu.parallel import mesh_executor
+        from ozone_tpu.parallel import dispatch
 
         self.executor = executor
         self.opts = opts
@@ -350,33 +348,14 @@ class _SpecPacker:
         self.bpc = bpc
         self.stats = stats
         self.window = tier_batch_size()
-        # mesh lane first: on a multi-chip host a bulk tiering sweep is
-        # exactly the traffic the persistent mesh executor exists for —
-        # full-width windows coalescing with other sweeps into mesh-wide
-        # dispatches. Then the shared codec service (bulk class): sweep
-        # windows coalesce with other operations' stripes and the
-        # weighted fair scheduler keeps the sweep from starving
-        # interactive traffic; per-sweep DeviceBatchPipeline is the
-        # no-service fallback.
-        self.pipe = None
-        if spec is not None:
-            mex = mesh_executor.maybe_executor()
-            if mex is not None:
-                try:
-                    self.pipe = mex.pipeline(
-                        codec_service.encode_key(spec),
-                        width=self.window, qos="bulk")
-                except KeyError:
-                    self.pipe = None
-        if self.pipe is None:
-            svc = codec_service.maybe_service() if spec is not None \
-                else None
-            if svc is not None:
-                self.pipe = codec_service.ServicePipeline(
-                    svc, codec_service.encode_key(spec), fn,
-                    width=self.window, qos="bulk")
-            else:
-                self.pipe = DeviceBatchPipeline(fn)
+        # bulk class: on a multi-chip host a tiering sweep is exactly
+        # the traffic the mesh executor exists for (full-width windows
+        # coalescing with other sweeps into mesh-wide dispatches); on
+        # one chip the codec service's weighted fair scheduler keeps the
+        # sweep from starving interactive traffic
+        self.pipe = dispatch.pipeline(
+            codec_service.encode_key(spec), fn, width=self.window,
+            qos="bulk")
         self.host_checksum = Checksum(ctype, bpc)
         self.dispatches = 0
         self._reset_buffer()

@@ -122,9 +122,8 @@ def load() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None or _tried:
             return _lib
-        # -O3 -march=native: the coder kernels are perf-measured
-        # (bench.py CPU baseline); later flags override build_shared's
-        # -O2
+        # -O3 -march=native: the coder kernels are the datapath on hosts
+        # without a chip; later flags override build_shared's -O2
         so = build_shared(_SRC, _SO,
                           extra=("-O3", "-march=native", "-pthread"))
         _tried = True

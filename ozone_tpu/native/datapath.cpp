@@ -7,9 +7,9 @@
 // transport/server/GrpcXceiverService.java:42, keyvalue/helpers/
 // ChunkUtils.java:109-156): the reference moves chunk bytes through
 // native code end-to-end; a Python gRPC stack pays ~65% of every
-// WriteChunk round trip in interpreter-driven transport (docs/PERF.md
-// per-layer table). This sidecar owns frame parse -> pwrite/pread ->
-// CRC32C verify -> fsync on its own TCP listener inside the datanode
+// WriteChunk round trip in interpreter-driven transport. This sidecar
+// owns frame parse -> pwrite/pread -> CRC32C verify -> fsync on its
+// own TCP listener inside the datanode
 // process; Python keeps the control plane (token verification, write
 // fences, layout gates, block commits) via three callbacks that are
 // invoked once per STREAM, not per chunk.

@@ -678,8 +678,8 @@ class GrpcDatanodeClient:
         """Write `chunks` ([(ChunkInfo, payload array)]) and optionally
         commit `commit` (a BlockData) in ONE round trip: the PutBlock-
         piggybacking analog, batched. One ack covers the whole batch —
-        the transport-dominant per-chunk round trip (docs/PERF.md
-        per-layer table) collapses to one per batch."""
+        the transport-dominant per-chunk round trip collapses to one
+        per batch."""
         meta = {"block_id": block_id.to_json(), "sync": sync,
                 **self._btok(block_id)}
         if writer is not None:

@@ -1,10 +1,8 @@
 """Persistent mesh executor: the multi-chip datapath, kept fed.
 
-MULTICHIP_r05 proved the sharded codec correct (bit-exact DP encode,
-cross-process psum) but moved ~0.2 MiB/s/device, because every mesh call
-re-staged its batch, re-dispatched synchronously, and blocked for the
-result. This module gives the mesh the same treatment
-`DeviceBatchPipeline` + `CodecService` gave the single chip:
+A raw call of a sharded program re-stages its batch, dispatches
+synchronously and blocks for the result. This module gives the mesh
+the treatment `CodecService` gave the single chip:
 
 - **Long-lived compiled SPMD programs**, one per (FusedSpec, erasure
   pattern, batch width), resolved once per lane through
@@ -55,29 +53,36 @@ GF(2) bit-matmul runs orders of magnitude slower than the AVX2 nibble
 coder) a lane's program resolves to the **native host twin sharded
 across one worker thread per mesh device** — same contract, same
 coalescing, and trivially zero XLA compiles — while accelerator meshes
-run the jitted SPMD programs. `stats()["mode_*"]` reports which.
+run the jitted SPMD programs. `stats()["programs_host_twin"]` counts
+them.
 
-Spill: when ``OZONE_TPU_MESH_SPILL=1`` (off by default) the shared
-codec service redirects whole overflowing lanes here once its queue
-depth crosses ``OZONE_TPU_MESH_SPILL_WATERMARK`` — see
-`codec/service.py:_collect_spill_locked`.
+Consumers do not call this module to submit: `parallel/dispatch.py`
+decides which queue a batch joins. The submission record and the join
+of a split submission are `codec/service.py`'s, shared by both
+schedulers.
 """
 
 from __future__ import annotations
 
+import functools
 import logging
 import math
-import os
 import threading
 import time
 from collections import deque
-from concurrent.futures import Future, InvalidStateError
-from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Callable, Optional
+from concurrent.futures import Future, ThreadPoolExecutor
+from typing import Callable, Optional
 
 import numpy as np
 
 from ozone_tpu.codec.pipeline import _start_d2h
+from ozone_tpu.codec.service import (
+    _ambient_deadline,
+    _resolve_error,
+    _resolve_sub,
+    _settle,
+    _Sub,
+)
 from ozone_tpu.parallel import sharded
 from ozone_tpu.utils.config import env_float, env_int
 from ozone_tpu.utils.metrics import MetricsRegistry, registry
@@ -118,32 +123,6 @@ def mesh_depth() -> int:
     return max(1, env_int("OZONE_TPU_MESH_DEPTH", DEFAULT_DEPTH))
 
 
-def enabled() -> bool:
-    """The executor disable switch (OZONE_TPU_MESH=0)."""
-    return os.environ.get("OZONE_TPU_MESH", "1") != "0"
-
-
-def spill_enabled() -> bool:
-    """Codec-service overflow spill onto the mesh
-    (OZONE_TPU_MESH_SPILL=1; OFF by default — spilling helps only when
-    neighbor chips are otherwise idle, and moves interactive work onto
-    a path tuned for throughput, not latency)."""
-    return os.environ.get("OZONE_TPU_MESH_SPILL", "0") in (
-        "1", "true", "yes", "on")
-
-
-def spill_watermark() -> int:
-    """Queue-depth (stripes) past which the codec service starts
-    redirecting whole lanes to the mesh (OZONE_TPU_MESH_SPILL_WATERMARK)."""
-    return max(1, env_int("OZONE_TPU_MESH_SPILL_WATERMARK", 64))
-
-
-def _ambient_deadline():
-    from ozone_tpu.client import resilience
-
-    return resilience.current()
-
-
 class _MeshProgram:
     """One resolved, long-lived mesh program for a semantic key.
 
@@ -164,32 +143,6 @@ class _MeshProgram:
         """Compiled-executable census across this program's jitted
         callables; steady-state dispatches must not move it."""
         return sum(int(f._cache_size()) for f in self.jitted)
-
-
-class _Sub:
-    """One submission: `n` same-shape stripes from one operation."""
-
-    __slots__ = ("stripes", "n", "future", "cls", "deadline", "t_enq",
-                 "t_enq_wall", "trace_ctx", "tail", "taken",
-                 "pending_parts", "parts")
-
-    def __init__(self, stripes: np.ndarray, future: Future, cls: str,
-                 deadline, tail: bool):
-        self.stripes = stripes
-        self.n = int(stripes.shape[0])
-        self.future = future
-        self.cls = cls
-        self.deadline = deadline
-        self.t_enq = time.monotonic()
-        self.t_enq_wall = time.time()
-        self.trace_ctx = Tracer.instance().inject()
-        self.tail = tail
-        self.taken = 0
-        self.pending_parts = 0
-        self.parts: list[tuple] = []
-
-    def deadline_t(self) -> float:
-        return self.deadline.t_end if self.deadline is not None else math.inf
 
 
 class _Lane:
@@ -291,19 +244,23 @@ class MeshExecutor:
         return max(self.n_devices, -(-w // self.n_devices) * self.n_devices)
 
     def accepts(self, key: tuple) -> bool:
-        """Whether `key` resolves to a mesh program (spill eligibility).
-        May build (and on device backends compile) the program."""
+        """Whether `key` resolves to a mesh program. The first call for
+        a key builds (and on device backends compiles) the program."""
         return self._resolve(key) is not None
 
-    def accepts_cached(self, key: tuple) -> Optional[bool]:
-        """Non-blocking spill-eligibility peek: True/False when `key`
-        has already been resolved, None when unknown — callers holding
-        their own dispatch locks use this and warm unknown keys via
-        `accepts()` outside them (resolution may compile)."""
-        with self._lock:
-            if key not in self._programs:
-                return None
-            return self._programs[key] is not None
+    def pipeline(self, key: tuple, *, width: int,
+                 qos: str = "bulk") -> Callable[..., Future]:
+        """The lane `key`'s work joins, as `submit(stripes, *, tail,
+        deadline)` bound to it: the way the door
+        (`parallel/dispatch.py`) takes its mesh route. Raises KeyError
+        when the key has no mesh program; the door then sends the work
+        to the codec service. The benchmark's tests break this method
+        to show that a decode which leaves the mesh is reported
+        (`tests/benchmark_tests/test_bench_mesh.py`), so the name and
+        the KeyError stay."""
+        if not self.accepts(key):
+            raise KeyError(f"no mesh program for {key!r}")
+        return functools.partial(self.submit, key, width=width, qos=qos)
 
     def _resolve(self, key: tuple) -> Optional[_MeshProgram]:
         with self._lock:
@@ -349,7 +306,7 @@ class MeshExecutor:
             return _MeshProgram(jfn, (apply_fn,), False)
         # reencode and custom fns have no sharded twin (the re-encode
         # kernel's single fused dispatch doesn't decompose across the
-        # batch axis for free) — their lanes never spill here
+        # batch axis for free): the door keeps them on the codec service
         return None
 
     def _host_shard(self, single: Callable) -> Callable:
@@ -403,8 +360,7 @@ class MeshExecutor:
 
         `width` is the submitter's per-device batch width (the lane
         dispatches at ``dispatch_width(width)``). Raises KeyError when
-        the key has no mesh program — callers should have checked
-        `accepts()` or hold a pipeline from `pipeline()`.
+        the key has no mesh program: `pipeline()` answers that first.
         """
         if stripes.shape[0] < 1:
             raise ValueError("empty mesh submission")
@@ -415,11 +371,6 @@ class MeshExecutor:
             deadline = _ambient_deadline()
         fut: Future = Future()
         sub = _Sub(stripes, fut, qos, deadline, tail)
-        self._enqueue(key, prog, width, qos, [sub])
-        return fut
-
-    def _enqueue(self, key: tuple, prog: _MeshProgram, width: int,
-                 qos: str, subs: list) -> None:
         lane_key = (key, int(width), qos)
         lane_width = self.dispatch_width(width)
         with self._cond:
@@ -429,36 +380,14 @@ class MeshExecutor:
             if lane is None:
                 lane = self._lanes[lane_key] = _Lane(
                     lane_key, prog, lane_width, qos)
-            for sub in subs:
-                lane.subs.append(sub)
-                lane.queued += sub.n
-                lane.min_deadline_t = min(lane.min_deadline_t,
-                                          sub.deadline_t())
-                METRICS.counter("submissions").inc()
+            lane.subs.append(sub)
+            lane.queued += sub.n
+            lane.min_deadline_t = min(lane.min_deadline_t,
+                                      sub.deadline_t())
+            METRICS.counter("submissions").inc()
             METRICS.gauge("queue_depth").set(self._queue_depth_locked())
             self._cond.notify()
-
-    def absorb(self, key: tuple, width: int, qos: str,
-               subs: list) -> None:
-        """Take over queued submissions spilled from the codec service:
-        same future, same stripes, same deadline — only the dispatch
-        path changes. Caller guarantees no sub has partially-dispatched
-        stripes (the service only spills untouched lanes)."""
-        prog = self._resolve(key)
-        if prog is None:
-            raise KeyError(f"no mesh program for {key!r}")
-        METRICS.counter("spilled_lanes").inc()
-        METRICS.counter("spilled_stripes").inc(sum(s.n for s in subs))
-        self._enqueue(key, prog, width, qos, subs)
-
-    def pipeline(self, key: tuple, *, width: int,
-                 qos: str = "bulk") -> "MeshPipeline":
-        """A `ServicePipeline`-shaped front end over one mesh lane —
-        the two-line routing change for depth-1 pipeline consumers.
-        Raises KeyError when the key has no mesh program."""
-        if self._resolve(key) is None:
-            raise KeyError(f"no mesh program for {key!r}")
-        return MeshPipeline(self, key, width=width, qos=qos)
+        return fut
 
     # ------------------------------------------------------- scheduling
     def _queue_depth_locked(self) -> int:
@@ -627,7 +556,7 @@ class MeshExecutor:
             except BaseException as e:  # noqa: BLE001 - per-dispatch fault
                 if staged is not None:
                     self._give_staging(staged)
-                self._resolve_error(entries, e)
+                _resolve_error(entries, e)
                 self._close_batch()
                 return
         METRICS.counter("dispatches").inc()
@@ -702,7 +631,7 @@ class MeshExecutor:
         except BaseException as e:  # noqa: BLE001 - D2H fault
             if staged is not None:
                 self._give_staging(staged)
-            self._resolve_error(entries, e)
+            _resolve_error(entries, e)
             self._close_batch(pulled=True)
             return
         # everything after the pull, until the last rider is resolved
@@ -740,17 +669,12 @@ class MeshExecutor:
                     _resolve_sub(sub)
             except BaseException as e:  # noqa: BLE001 - this batch's fault alone
                 log.exception("mesh completion failed")
-                self._resolve_error(entries, e)
+                _resolve_error(entries, e)
                 if not isinstance(e, Exception):
                     raise
             finally:
                 sub = whole = entries = outs = host = None
                 self._close_batch(pulled=True)
-
-    @staticmethod
-    def _resolve_error(entries, e: BaseException) -> None:
-        for sub in {id(en[0]): en[0] for en in entries}.values():
-            _settle(sub.future, error=e)
 
     def _fail_pending(self, e: BaseException) -> None:
         with self._lock:
@@ -802,9 +726,6 @@ class MeshExecutor:
         snap["devices"] = self.n_devices
         snap["mesh_depth"] = self.depth
         snap["compile_counts"] = sum(p.compile_count() for p in progs)
-        snap["spill_enabled"] = spill_enabled()
-        snap["spill_watermark"] = spill_watermark()
-        snap["enabled"] = enabled()
         return snap
 
     def quiesce(self, timeout_s: float = 30.0) -> None:
@@ -831,66 +752,6 @@ class MeshExecutor:
         self._workers.shutdown(wait=False)
 
 
-def _settle(future: Future, result=None, error=None) -> None:
-    """Resolve `future` unless the other thread (or a shutdown) already
-    has: a future is settled exactly once."""
-    try:
-        if error is not None:
-            future.set_exception(error)
-        else:
-            future.set_result(result)
-    except InvalidStateError:  # ozlint: allow[error-swallowing] -- settled already by the other thread or a shutdown: exactly once is the contract
-        pass
-
-
-def _resolve_sub(sub: _Sub) -> None:
-    """All parts of `sub` are host arrays: join them in offset order."""
-    if sub.future.done():
-        return
-    if len(sub.parts) == 1:
-        _settle(sub.future, sub.parts[0][2])
-        return
-    sub.parts.sort(key=lambda p: p[0])
-    outs = tuple(
-        np.concatenate([p[2][i] for p in sub.parts], axis=0)
-        for i in range(len(sub.parts[0][2])))
-    _settle(sub.future, outs)
-
-
-class MeshPipeline:
-    """Drop-in twin of `DeviceBatchPipeline`/`ServicePipeline` backed by
-    one mesh lane: submit(batch, ctx) coalesces into full-width mesh
-    dispatches and returns the PREVIOUS submission's host results."""
-
-    def __init__(self, executor: MeshExecutor, key: tuple, *,
-                 width: int, qos: str = "bulk"):
-        self._ex = executor
-        self._key = key
-        self._width = max(1, int(width))
-        self._qos = qos
-        self._pending: Optional[tuple] = None
-
-    def submit(self, batch: np.ndarray, ctx: Any = None,
-               tail: bool = False) -> Optional[tuple]:
-        fut = self._ex.submit(self._key, batch, width=self._width,
-                              qos=self._qos, tail=tail)
-        prev, self._pending = self._pending, (ctx, fut)
-        return self._to_host(prev)
-
-    def drain(self) -> Optional[tuple]:
-        prev, self._pending = self._pending, None
-        return self._to_host(prev)
-
-    @staticmethod
-    def _to_host(entry: Optional[tuple]) -> Optional[tuple]:
-        if entry is None:
-            return None
-        ctx, fut = entry
-        from ozone_tpu.codec import service as codec_service
-
-        return ctx, codec_service.wait_result(fut)
-
-
 _executor: Optional[MeshExecutor] = None
 _executor_lock = threading.Lock()
 
@@ -905,13 +766,11 @@ def get_executor() -> MeshExecutor:
 
 
 def maybe_executor() -> Optional[MeshExecutor]:
-    """The executor when it can exist here: enabled AND more than one
-    device attached — the ONE check routed datapaths (lifecycle mesh
-    lane, reconstruction storms, codec-service spill) make before
-    falling back to their single-chip pipelines. A backend that fails
-    to initialise raises (see fused._prefer_host_coder)."""
-    if not enabled():
-        return None
+    """The executor when it can exist here: more than one device
+    attached. The door (`parallel/dispatch.py`) asks this for encode
+    sweeps; repair harnesses ask it and hand the answer to their
+    coordinator. A backend that fails to initialise raises (see
+    fused._prefer_host_coder)."""
     import jax
 
     if jax.device_count() < 2:

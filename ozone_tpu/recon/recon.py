@@ -738,8 +738,8 @@ class ReconServer:
                     # continuous-batching health, next to lifecycle —
                     # its main bulk consumer)
                     "/api/codec": recon.codec_view,
-                    # persistent mesh executor: multi-chip dispatch,
-                    # coalescing and spill accounting (the fleet
+                    # persistent mesh executor: multi-chip dispatch
+                    # and coalescing accounting (the fleet
                     # reconstruction/bulk-tiering datapath's health)
                     "/api/mesh": recon.mesh_view,
                     # admission-control panel: per-hop controller
@@ -827,30 +827,24 @@ class ReconServer:
         codec work."""
         from ozone_tpu.codec import service as codec_service
 
-        if not codec_service.enabled():
-            return {"enabled": False}
         svc = codec_service._service
         if svc is None or not svc._running:
-            return {"enabled": True, "started": False}
+            return {"started": False}
         return svc.stats()
 
     def mesh_view(self) -> dict:
         """Persistent mesh executor snapshot for the dashboard panel:
         dispatch/fill/coalescing accounting, in-flight depth, program
-        census (device vs host-twin) and spill knob echo
+        census (device vs host-twin)
         (parallel/mesh_executor.stats). PEEKS at the singleton exactly
         like codec_view — a monitoring GET must never be the thing that
         spawns the mesh-owning dispatcher (or builds a mesh) in a
         process that does no mesh work."""
         from ozone_tpu.parallel import mesh_executor
 
-        if not mesh_executor.enabled():
-            return {"enabled": False}
         ex = mesh_executor._executor
         if ex is None or not ex._running:
-            return {"enabled": True, "started": False,
-                    "spill_enabled": mesh_executor.spill_enabled(),
-                    "spill_watermark": mesh_executor.spill_watermark()}
+            return {"started": False}
         return ex.stats()
 
     def admission_view(self) -> dict:

@@ -221,8 +221,7 @@ RECON_INDEX_HTML = """<!doctype html>
   <h2>Mesh executor</h2>
   <div class="sub">persistent multi-chip datapath: long-lived SPMD
     programs fed depth-N in-flight batches &mdash; dispatch fill,
-    coalescing across operations, spill absorption from the codec
-    service</div>
+    coalescing across operations</div>
   <div class="tiles" id="mesh-tiles"></div>
 
   <h2>Admission control</h2>
@@ -435,8 +434,8 @@ async function refresh() {
       '<tr><td colspan="5">no replication rules configured</td></tr>';
     const cx = await (await fetch("/api/codec")).json();
     document.getElementById("codec-tiles").innerHTML =
-      cx.enabled === false
-        ? tile("codec service", "disabled")
+      cx.started === false
+        ? tile("codec service", "idle")
         : [
       tile("batch fill", `${Math.round((cx.fill_ratio ?? 0) * 100)}%`),
       tile("queue depth", cx.queue_depth ?? 0),
@@ -451,13 +450,8 @@ async function refresh() {
     ].join("");
     const mx = await (await fetch("/api/mesh")).json();
     document.getElementById("mesh-tiles").innerHTML =
-      mx.enabled === false
-        ? tile("mesh executor", "disabled")
-        : mx.started === false
-        ? [
-      tile("mesh executor", "idle"),
-      tile("spill", mx.spill_enabled ? "on" : "off"),
-    ].join("")
+      mx.started === false
+        ? tile("mesh executor", "idle")
         : [
       tile("devices", mx.devices ?? 0),
       tile("mode", (mx.programs_host_twin ?? 0) > 0
@@ -470,9 +464,6 @@ async function refresh() {
       tile("in-flight", `${mx.inflight ?? 0}/${mx.mesh_depth ?? 0}`),
       tile("max in-flight", mx.max_inflight ?? 0),
       tile("programs", mx.programs ?? 0),
-      tile("spilled lanes", mx.spilled_lanes ?? 0),
-      tile("spilled stripes", mx.spilled_stripes ?? 0),
-      tile("spill", mx.spill_enabled ? "on" : "off"),
     ].join("");
     const ad = await (await fetch("/api/admission")).json();
     const ac = ad.counters || {};

@@ -14,10 +14,7 @@ open-per-chunk + `tobytes()` (which paid a 1 MiB copy AND an open/close
 per chunk); reads use `os.pread` on the same cached fd. Descriptors are
 refcounted so the store lock covers only cache bookkeeping — the actual
 pwrite/pread/fsync syscalls run outside it and concurrent readers are
-never serialized behind a committing writer's fsync. Measured on this
-rig: 1 MiB gRPC WriteChunk round-trip 4.22 -> 2.70 CPU ms (237 -> 370
-MiB/s/core); the store layer itself 0.49 -> 0.38 ms (docs/PERF.md
-per-layer table).
+never serialized behind a committing writer's fsync.
 """
 
 from __future__ import annotations

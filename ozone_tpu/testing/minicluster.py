@@ -71,16 +71,12 @@ class MiniOzoneCluster:
             block_size=block_size,
         )
         from ozone_tpu.parallel import mesh_executor
-        from ozone_tpu.parallel.sharded import default_codec_mesh
 
-        # repair decodes: the persistent mesh executor when it can
-        # exist (coalesces batches across containers on long-lived
-        # programs), else the raw DP mesh path
-        ex = mesh_executor.maybe_executor()
+        # repair decodes: the coordinator is handed the host's mesh
+        # executor where one can exist (it coalesces batches across
+        # containers on long-lived programs) and hands it to the door
         self.reconstruction = ECReconstructionCoordinator(
-            self.clients,
-            mesh=None if ex is not None else default_codec_mesh(),
-            executor=ex)
+            self.clients, executor=mesh_executor.maybe_executor())
         self._stopped_dns: set[str] = set()
         self._hb_thread: Optional[threading.Thread] = None
         self._stop = threading.Event()

@@ -14,11 +14,10 @@ bootstrap from whichever address they were given).
 Every process must be started with the SAME --shards book and --epoch,
 or the rings will disagree about slot ownership (the per-request
 `check_shard` gate turns that misconfiguration into SHARD_MOVED
-rejections rather than silent misplacement). `bench.py` boots its
-shard-scaling measurement through this entrypoint — one process per
-ring, the only configuration in which CPython can demonstrate
-horizontal metadata scaling (a single interpreter serializes all rings
-on the GIL).
+rejections rather than silent misplacement). One process per ring is
+the only configuration in which CPython can demonstrate horizontal
+metadata scaling (a single interpreter serializes all rings on the
+GIL).
 """
 
 from __future__ import annotations

@@ -4,7 +4,7 @@ this process built.
 The served path is many short-lived CLI processes running the same few
 jitted programs; without a persistent cache each one recompiles them.
 The rule, applied by every entry point that dispatches (tools/cli.py
-main, bench.py; chip_smoke.py's children inherit it by environment):
+main; chip_smoke.py's children inherit it by environment):
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: touch nothing. JAX reads the
   variable itself, and no code names another directory.

@@ -33,8 +33,8 @@ def cluster(tmp_path):
 def test_pattern_churn_never_recompiles(monkeypatch):
     """Every 2-erasure pattern of RS(6,3) decodes through the SAME
     compiled program: the per-pattern work is a small device matrix from
-    the plan cache, not a fresh jit (the compile-count probe that would
-    have caught the recompile cliff behind BENCH_r05's 21% spread)."""
+    the plan cache, not a fresh jit (the compile-count probe that
+    catches a per-pattern cache recompiling mid-read under churn)."""
     monkeypatch.setenv("OZONE_TPU_FUSED_BACKEND", "jax")
     from ozone_tpu.codec import fused
     from ozone_tpu.utils.checksum import Checksum, ChecksumType

@@ -435,24 +435,20 @@ def test_recon_lifecycle_endpoint(cluster):
         # queue depth for the device's continuous batching)
         cx = json.loads(urllib.request.urlopen(
             f"http://{recon.address}/api/codec", timeout=10).read())
-        if cx.get("enabled") is False:
-            assert set(cx) == {"enabled"}
-        elif cx.get("started") is False:
+        if cx.get("started") is False:
             # monitoring GET must not spawn the dispatcher itself
-            assert set(cx) == {"enabled", "started"}
+            assert set(cx) == {"started"}
         else:
             for want in ("fill_ratio", "ops_per_dispatch",
                          "queue_depth", "linger_ms", "weights"):
                 assert want in cx, want
         # the mesh-executor panel rides the same server (multi-chip
-        # dispatch/coalescing/spill accounting); the GET must not
-        # spawn the executor either
+        # dispatch/coalescing accounting); the GET must not spawn the
+        # executor either
         mx = json.loads(urllib.request.urlopen(
             f"http://{recon.address}/api/mesh", timeout=10).read())
-        if mx.get("enabled") is False:
-            assert set(mx) == {"enabled"}
-        elif mx.get("started") is False:
-            assert "spill_enabled" in mx and "spill_watermark" in mx
+        if mx.get("started") is False:
+            assert set(mx) == {"started"}
         else:
             for want in ("fill_ratio", "ops_per_dispatch", "devices",
                          "mesh_depth", "programs", "max_inflight"):

@@ -1,7 +1,7 @@
 """Persistent mesh executor tests: program persistence, cross-operation
 coalescing into full-width dispatches, depth-N in-flight buffering,
-staging reuse, codec-service spill, and the `pad_batch` /
-plan-cache-key edges the executor leans on."""
+staging reuse, and the `pad_batch` / plan-cache-key edges the executor
+leans on."""
 
 import sys
 import threading
@@ -14,8 +14,8 @@ import pytest
 from ozone_tpu.codec import create_encoder
 from ozone_tpu.codec import service as codec_service
 from ozone_tpu.codec.api import CoderOptions
-from ozone_tpu.codec.fused import FusedSpec
-from ozone_tpu.parallel import mesh_executor, sharded
+from ozone_tpu.codec.fused import FusedSpec, make_fused_encoder
+from ozone_tpu.parallel import dispatch, mesh_executor, sharded
 from ozone_tpu.parallel.mesh_executor import (
     MeshExecutor,
     _MeshProgram,
@@ -87,8 +87,6 @@ def test_decode_program_isolated_across_patterns(executor):
     k2 = codec_service.decode_key(SPEC, [1, 2, 3, 4, 5, 6], [0])
     assert executor.accepts(k1) and executor.accepts(k2)
     assert executor._programs[k1] is not executor._programs[k2]
-    assert executor.accepts_cached(k1) is True
-    assert executor.accepts_cached(("decode", "never-seen")) is None
 
 
 # --------------------------------------------------------- correctness
@@ -298,10 +296,18 @@ def test_program_error_fails_future(executor):
         fut.result(timeout=30)
 
 
-def test_mesh_pipeline_contract(executor):
-    """MeshPipeline mirrors ServicePipeline: submit returns the
-    PREVIOUS submission's (ctx, outs); drain flushes the last."""
-    pipe = executor.pipeline(codec_service.encode_key(SPEC), width=2)
+@pytest.mark.parametrize("qos,registry", [
+    ("bulk", mesh_executor.METRICS),
+    ("interactive", codec_service.METRICS),
+], ids=["mesh", "service"])
+def test_pipeline_contract(executor, qos, registry):
+    """The one depth-1 adaptor over either scheduler's futures: submit
+    returns the PREVIOUS submission's (ctx, outs); drain flushes the
+    last."""
+    before = registry.counter("submissions").value
+    pipe = dispatch.pipeline(
+        codec_service.encode_key(SPEC), make_fused_encoder(SPEC),
+        width=2, qos=qos, executor=executor)
     rng = np.random.default_rng(3)
     batches = [rng.integers(0, 256, (4, 6, 1024), dtype=np.uint8)
                for _ in range(3)]
@@ -319,6 +325,7 @@ def test_mesh_pipeline_contract(executor):
         assert np.array_equal(np.asarray(parity),
                               enc.encode(batches[ctx]))
     assert pipe.drain() is None
+    assert registry.counter("submissions").value == before + 3
 
 
 def test_close_fails_pending_and_rejects_submits():
@@ -328,78 +335,3 @@ def test_close_fails_pending_and_rejects_submits():
     ex.close()
     with pytest.raises(RuntimeError):
         ex.submit(key, np.zeros((1, 4), dtype=np.uint8), width=1)
-
-
-# ----------------------------------------------------------- spill path
-@pytest.mark.parametrize("width", [1, 4],
-                         ids=["borrowed_rows", "rows_reserved_in_staging"])
-def test_service_spill_redirects_whole_lane(executor, monkeypatch, width):
-    """Watermark-triggered overflow: with the service dispatcher pinned
-    on a slow lane and the queue past the watermark, untouched lanes
-    whose keys the mesh accepts move wholesale to the executor — and
-    their futures still resolve bit-exactly. At width 4 the lone rows
-    were already copied into staging batches at submit: the mesh packs
-    from the submitters' own rows and the buffers are handed back."""
-    monkeypatch.setenv("OZONE_TPU_MESH_SPILL", "1")
-    monkeypatch.setenv("OZONE_TPU_MESH_SPILL_WATERMARK", "4")
-    monkeypatch.setattr(mesh_executor, "_executor", executor)
-    enc_key = codec_service.encode_key(SPEC)
-    assert executor.accepts(enc_key)  # pre-warm: peek answers True
-
-    rng = np.random.default_rng(4)
-    datas = [rng.integers(0, 256, (1, 6, 1024), dtype=np.uint8)
-             for _ in range(12)]
-    release = threading.Event()
-
-    def slow_fn(batch):
-        release.wait(timeout=30)
-        return (batch.copy(),)
-
-    svc = codec_service.CodecService()
-    snap0 = codec_service.METRICS.snapshot()
-    msnap0 = mesh_executor.METRICS.snapshot()
-    try:
-        # pin the dispatcher: a full width-1 lane dispatches at once
-        # and blocks inside slow_fn until released
-        plug = svc.submit(("encode", "slow-plug"), slow_fn,
-                          np.zeros((1, 4), dtype=np.uint8), width=1)
-        time.sleep(0.05)
-        futs = [svc.submit(enc_key, None, d, width=width) for d in datas]
-        release.set()
-        plug.result(timeout=30)
-        results = [f.result(timeout=60) for f in futs]
-        if width > 1:
-            # the three reserved buffers came back: the next batch of
-            # that shape is packed into one of them
-            r0 = codec_service.METRICS.counter(
-                "staging_buffers_reused").value
-            (echo,) = svc.submit(("echo",), lambda b: (b.copy(),),
-                                 datas[0], width=width).result(timeout=30)
-            assert np.array_equal(echo, datas[0])
-            assert codec_service.METRICS.counter(
-                "staging_buffers_reused").value == r0 + 1
-    finally:
-        release.set()
-        svc.close()
-    enc = create_encoder(OPTS, "numpy")
-    for d, (parity, _crcs) in zip(datas, results):
-        assert np.array_equal(np.asarray(parity), enc.encode(d))
-    snap1 = codec_service.METRICS.snapshot()
-    assert snap1.get("mesh_spill_lanes", 0) > snap0.get(
-        "mesh_spill_lanes", 0)
-    assert snap1.get("mesh_spill_stripes", 0) >= snap0.get(
-        "mesh_spill_stripes", 0) + 8
-    msnap1 = mesh_executor.METRICS.snapshot()
-    assert msnap1.get("spilled_lanes", 0) > msnap0.get("spilled_lanes", 0)
-
-
-def test_spill_off_by_default(executor, monkeypatch):
-    """With OZONE_TPU_MESH_SPILL unset the service never redirects —
-    the knob is opt-in."""
-    monkeypatch.delenv("OZONE_TPU_MESH_SPILL", raising=False)
-    svc = codec_service.CodecService()
-    try:
-        with svc._lock:
-            assert svc._collect_spill_locked() == []
-    finally:
-        svc.close()

@@ -166,8 +166,7 @@ def test_prometheus_text_golden_every_registry_renders():
 
     for name in ("submissions", "dispatches", "stripes_dispatched",
                  "slots_dispatched", "coalesced_operations",
-                 "multi_op_dispatches", "spilled_lanes",
-                 "spilled_stripes", "staging_reuses"):
+                 "multi_op_dispatches", "staging_reuses"):
         MESH.counter(name).inc(0)
     for name in ("devices", "depth", "queue_depth", "batch_fill_pct",
                  "inflight_depth", "inflight_per_device",
@@ -274,7 +273,6 @@ def test_prometheus_text_golden_every_registry_renders():
                  "mesh_submissions", "mesh_dispatches",
                  "mesh_stripes_dispatched", "mesh_slots_dispatched",
                  "mesh_coalesced_operations", "mesh_multi_op_dispatches",
-                 "mesh_spilled_lanes", "mesh_spilled_stripes",
                  "mesh_staging_reuses", "mesh_devices", "mesh_depth",
                  "mesh_queue_depth", "mesh_batch_fill_pct",
                  "mesh_inflight_depth", "mesh_inflight_per_device",

@@ -195,17 +195,25 @@ def test_traces_whose_root_never_finishes_here_stay_bounded(t):
     assert len(t._open["feed"]) == tracing.MAX_TRACE_SPANS
 
 
+@pytest.mark.parametrize("queue", ["codec", "mesh"],
+                         ids=["handed_no_executor", "handed_the_mesh_executor"])
 def test_repair_spans_arrive_under_the_root_from_the_recon_pool(
-        t, tmp_path):
+        t, tmp_path, queue):
     """`repair:container` is the repair's root; its blocks run on the
-    `ec-recon` pool and their spans join it through Tracer.activate."""
+    `ec-recon` pool and their spans join it through Tracer.activate,
+    the decode's spans from whichever queue the door
+    (`parallel/dispatch.py`) sent the coordinator's decodes to."""
     from ozone_tpu.codec.api import CoderOptions
+    from ozone_tpu.parallel import mesh_executor
     from ozone_tpu.storage.reconstruction import (
         ECReconstructionCoordinator,
         ReconstructionCommand,
     )
     from ozone_tpu.testing.minicluster import MiniOzoneCluster
 
+    executor = mesh_executor.maybe_executor() if queue == "mesh" else None
+    dispatch_span = {"codec": "codec:dispatch",
+                     "mesh": "mesh:device_dispatch"}[queue]
     cell = 4096
     c = MiniOzoneCluster(tmp_path, num_datanodes=7, block_size=4 * cell,
                          container_size=1024 * 1024,
@@ -227,7 +235,7 @@ def test_repair_spans_arrive_under_the_root_from_the_recon_pool(
             g.container_id, CoderOptions.parse("rs-3-2-4096"),
             {u + 1: nodes[u] for u in range(5) if u != 1}, {2: spare})
         ECReconstructionCoordinator(
-            c.clients, bytes_per_checksum=1024,
+            c.clients, bytes_per_checksum=1024, executor=executor,
         ).reconstruct_container_group(cmd)
     finally:
         c.close()
@@ -242,7 +250,7 @@ def test_repair_spans_arrive_under_the_root_from_the_recon_pool(
     blocks = {s.span_id for s in spans if s.name == "repair:block"}
     under_blocks = {s.name for s in spans if s.parent_id in blocks}
     assert {"repair:write", "ec:fanout", "net:get_block",
-            "codec:queue_wait", "codec:dispatch"} <= under_blocks
+            f"{queue}:queue_wait", dispatch_span} <= under_blocks
     # the survivor reads run on the reader's own pool, under the fan-in
     fanouts = {s.span_id for s in spans if s.name == "ec:fanout"}
     assert {s.parent_id for s in spans
@@ -252,7 +260,7 @@ def test_repair_spans_arrive_under_the_root_from_the_recon_pool(
         <= len(rec["stages"])
     assert {"repair:prepare", "repair:block", "repair:write",
             "repair:close", "ec:fanout", "net:read_chunks",
-            "codec:dispatch"} <= set(rec["stages"])
+            dispatch_span} <= set(rec["stages"])
     # PUTs of the set-up are operations of their own, told apart by root
     assert [o["root"] for o in t.recorder.operations()] == [
         "client:put", "repair:container"]

@@ -210,11 +210,8 @@ def test_two_cluster_convergence_puts_overwrites_deletes(pair):
     assert str(dst.om.lookup_key("v", "b", "k0")
                ["replication"]).startswith("RATIS")
     # scheme conversion rode the shared codec service at bulk QoS
-    from ozone_tpu.codec import service as codec_service
-
-    if codec_service.enabled():
-        creg = get_registry("codec.service")
-        assert creg.histogram("queue_wait_bulk_seconds").count > bulk_before
+    creg = get_registry("codec.service")
+    assert creg.histogram("queue_wait_bulk_seconds").count > bulk_before
     # shipped, nothing pending: the lag gauge is back to 0
     lag = src.om.geo_status()["lag"]
     assert lag["entries"] == 0 and lag["seconds"] == 0.0

@@ -1,4 +1,4 @@
-"""Zero-copy datapath invariants (docs/PERF.md "Wire-speed datapath").
+"""Zero-copy datapath invariants.
 
 Three contracts, all enforced through the process-wide copy-accounting
 registry in codec/hostmem.py:
