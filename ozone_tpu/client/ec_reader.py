@@ -23,7 +23,8 @@ loser's bytes are discarded.
 from __future__ import annotations
 
 import logging
-from typing import Optional, Sequence
+import threading
+from typing import Callable, Optional, Sequence
 
 import numpy as np
 
@@ -43,9 +44,14 @@ from ozone_tpu.codec.pipeline import (
 from ozone_tpu.parallel import dispatch
 from ozone_tpu.storage.ids import BlockData, ChunkInfo, StorageError
 from ozone_tpu.utils.checksum import ChecksumType
+from ozone_tpu.utils.metrics import registry
 from ozone_tpu.utils.tracing import Tracer
 
 log = logging.getLogger(__name__)
+
+#: the client's per-operation registry (`client/ozone_client.py`): a
+#: read() books what it moved there, beside `get_seconds`
+OPS = registry("client.ops")
 
 
 class InsufficientLocationsError(Exception):
@@ -71,6 +77,24 @@ class _StragglerHedge(Exception):
     def __init__(self, units: list[int]):
         super().__init__(f"straggling units {units}: hedging to spares")
         self.units = units
+
+
+class _ReadTally:
+    """What one read() asked of the datanodes and what it took from its
+    own recovery's survivor batches instead: the `ec:read` span's tags
+    and the `get_*` counters of `client.ops`. Work done is counted, an
+    abandoned attempt's too. The unit reads run on pool threads."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.cells_fetched = 0
+        self.wire_bytes = 0
+        self.cells_reused = 0  # the reading thread's alone
+
+    def asked(self, infos: Sequence[ChunkInfo]) -> None:
+        with self._lock:
+            self.cells_fetched += len(infos)
+            self.wire_bytes += sum(i.length for i in infos)
 
 
 class ECBlockGroupReader:
@@ -130,6 +154,9 @@ class ECBlockGroupReader:
         #: operation deadline captured at the public entry points and
         #: re-activated on reader-pool worker threads
         self._deadline: Optional[resilience.Deadline] = None
+        #: the running read()'s tally; None outside one (repair's
+        #: recover_cells_iter books nothing under the GET's names)
+        self._tally: Optional[_ReadTally] = None
         #: the class its decode batches queue in: they coalesce with
         #: other operations sharing the erasure pattern (reconstruction
         #: storms, fleets of degraded readers)
@@ -203,12 +230,18 @@ class ECBlockGroupReader:
             # cell has no data (short final stripe)
             return np.zeros(self.cell, dtype=np.uint8)
         dn_id = self.group.pipeline.nodes[u]
+        self._asked([info])
         with Tracer.instance().span("net:read_chunk", dn=dn_id,
                                     unit=u, stripe=stripe):
             data = self._health.observe(
                 dn_id, self.clients.get(dn_id).read_chunk,
                 self.group.block_id, info, verify=self.verify)
         return self._cell_array(data)
+
+    def _asked(self, infos: Sequence[ChunkInfo]) -> None:
+        tally = self._tally
+        if tally is not None:
+            tally.asked(infos)
 
     def _cell_array(self, data: np.ndarray) -> np.ndarray:
         """Full cells pass through as zero-copy views over the wire
@@ -249,6 +282,7 @@ class ECBlockGroupReader:
             fn = getattr(client, "read_chunks", None)
             if fn is None:
                 return
+            self._asked([i for _, i in wanted])
             with Tracer.instance().span("net:read_chunks", dn=dn_id,
                                         unit=u, cells=len(wanted)):
                 datas = self._health.observe(
@@ -279,62 +313,119 @@ class ECBlockGroupReader:
         if pool is not None:
             pool.shutdown(wait=False)
 
+    def _cell_span(self, offset: int, length: int, u: int,
+                   stripe: int) -> tuple[int, int, int]:
+        """(a, b, cell_start) in user-byte space: the part [a, b) of data
+        unit u's cell of `stripe` (which starts at cell_start) that lies
+        in [offset, offset+length); a >= b where the range misses it."""
+        cell_start = (stripe * self.k + u) * self.cell
+        return (max(offset, cell_start),
+                min(offset + length, cell_start + self.cell), cell_start)
+
+    def _put_cell(self, out: np.ndarray, offset: int, length: int,
+                  u: int, stripe: int, cell: np.ndarray) -> int:
+        """Copy the part of data unit u's `cell` of `stripe` that the
+        range covers to its place in `out`; returns the bytes copied."""
+        a, b, cell_start = self._cell_span(offset, length, u, stripe)
+        if a >= b:
+            return 0
+        out[a - offset : b - offset] = cell[a - cell_start : b - cell_start]
+        return b - a
+
+    def _count_copy(self, nbytes: int, site: str) -> None:
+        if nbytes:
+            hostmem.count_copy(nbytes, site=f"ec_reader.{site}", warn=False)
+
     def _read_range_into(self, out: np.ndarray, offset: int, length: int,
                          missing_data: list[int]) -> None:
         """Fill `out` with user bytes [offset, offset+length): only the
-        cells intersecting the range move over the wire, and on degraded
-        groups only the covering stripes are reconstructed."""
+        cells intersecting the range move over the wire, each once, and
+        on degraded groups only the covering stripes are reconstructed."""
         row = self.k * self.cell
         s0 = offset // row
         s1 = (offset + length - 1) // row
         # reconstruct ONLY the stripes where a missing unit's cell
         # actually intersects the range — a ranged read that never
         # touches the missing unit costs no recovery at all
-        need_rec = [
-            s for s in range(s0, s1 + 1)
-            if any(max(offset, s * row + u * self.cell)
-                   < min(offset + length, s * row + (u + 1) * self.cell)
-                   for u in missing_data)
-        ]
-        # exclude_stragglers=False: a straggling survivor propagates to
-        # read()'s retry loop, which folds it into missing_data so the
-        # NEXT attempt reconstructs every missing unit in one batched
-        # decode instead of recovering twice
-        rec = (self.recover_cells(missing_data, need_rec,
-                                  exclude_stragglers=False)
-               if need_rec else None)
-        rec_pos = {s: i for i, s in enumerate(need_rec)}
+        def touched(u: int, s: int) -> bool:
+            a, b, _ = self._cell_span(offset, length, u, s)
+            return a < b
+
+        need_rec = [s for s in range(s0, s1 + 1)
+                    if any(touched(u, s) for u in missing_data)]
+        #: stripe -> the data units whose cell of it the recovery put
+        #: in `out`: the lost units' (decoded) and the survivors' it
+        #: read for the decode (the reference's reconstructed-stripe
+        #: stream serves data cells out of its stripe buffers too)
+        placed = (self._recover_into(out, offset, length, missing_data,
+                                     need_rec) if need_rec else {})
+        # what the recovery did not read is fetched here: stripes
+        # outside need_rec, data units its plan left out (health,
+        # topology, an LRC local repair)
         window = 8  # stripes prefetched per unit per RPC (bounds memory)
         for w0 in range(s0, s1 + 1, window):
             stripes = range(w0, min(w0 + window, s1 + 1))
+            wanted = [(s, i) for s in stripes for i in range(self.k)
+                      if i not in placed.get(s, ()) and touched(i, s)]
             if self._batch_reads:
                 # one batched RPC per needed unit, concurrently; a unit
                 # is needed only where the range touches its cells
                 needed: dict[int, list[int]] = {}
-                for s in stripes:
-                    for i in range(self.k):
-                        if i in missing_data or i in self._failed:
-                            continue
-                        cell_start = s * row + i * self.cell
-                        if (max(offset, cell_start)
-                                < min(offset + length,
-                                      cell_start + self.cell)):
-                            needed.setdefault(i, []).append(s)
+                for s, i in wanted:
+                    if i not in self._failed:
+                        needed.setdefault(i, []).append(s)
                 if needed:
                     self._prefetch_bounded(needed)
-            for s in stripes:
-                for i in range(self.k):
-                    cell_start = s * row + i * self.cell
-                    a = max(offset, cell_start)
-                    b = min(offset + length, cell_start + self.cell)
-                    if a >= b:
-                        continue
-                    if i in missing_data:
-                        cell = rec[rec_pos[s], missing_data.index(i)]
-                    else:
-                        cell = self._read_cell_hedged(i, s)
-                    out[a - offset : b - offset] = \
-                        cell[a - cell_start : b - cell_start]
+            copied = 0
+            for s, i in wanted:
+                copied += self._put_cell(out, offset, length, i, s,
+                                         self._read_cell_hedged(i, s))
+            self._count_copy(copied, "fetched_cell")
+
+    def _recover_into(self, out: np.ndarray, offset: int, length: int,
+                      targets: list[int],
+                      stripes: list[int]) -> dict[int, set[int]]:
+        """Reconstruct the lost data units `targets` of `stripes` into
+        `out`, and copy there as well the surviving DATA cells that the
+        recovery read for its decode, out of each survivor batch while
+        that batch's decode is queued and on the device: no cell crosses
+        the wire a second time. Returns stripe -> data units in `out`.
+
+        A unit that fails mid-recovery restarts the plan and every
+        batch comes again (recover_cells_iter), so each stripe's entry
+        is the LAST plan's: a cell that only an abandoned attempt
+        copied is not in it, and the caller fetches or reconstructs
+        that cell like any other."""
+        placed: dict[int, set[int]] = {}
+        tally = self._tally
+
+        def take_survivors(sb, valid, batch) -> None:
+            data = [(vi, u) for vi, u in enumerate(valid) if u < self.k]
+            copied = 0
+            for bi, s in enumerate(sb):
+                for vi, u in data:
+                    n = self._put_cell(out, offset, length, u, s,
+                                       batch[bi, vi])
+                    copied += n
+                    tally.cells_reused += bool(n)
+                placed[s] = {u for _, u in data}
+            self._count_copy(copied, "reuse_survivor")
+
+        # exclude_stragglers=False: a straggling survivor propagates to
+        # read()'s retry loop, which folds it into missing_data so the
+        # NEXT attempt reconstructs every missing unit in one batched
+        # decode instead of recovering twice
+        for sb, (rec, _crcs) in self.recover_cells_iter(
+                targets, stripes, exclude_stragglers=False,
+                on_survivors=take_survivors):
+            copied = 0
+            for bi, s in enumerate(sb):
+                for ti, u in enumerate(targets):
+                    copied += self._put_cell(out, offset, length, u, s,
+                                             rec[bi, ti])
+                placed[s].update(targets)
+            self._count_copy(copied, "recovered_cell")
+        return placed
 
     def _read_cell_checked(self, u: int, stripe: int) -> np.ndarray:
         try:
@@ -571,6 +662,14 @@ class ECBlockGroupReader:
             raise InsufficientLocationsError(
                 f"need {self.k} units, reachable: {avail}, erased: {list(erased)}"
             )
+        # `avail` is in unit order, data units first, and every choice
+        # below keeps that order among equals: of equally usable,
+        # equally near survivors the DATA units are read before parity
+        # (as the reference's reconstructed-stripe reader does). A
+        # degraded read serves the data cells it decoded from straight
+        # out of the survivor batch (_recover_into); a parity cell in
+        # their place would be read for the decode alone, and the data
+        # cell fetched on top.
         nodes = self.group.pipeline.nodes
         if len(avail) > self.k:
             # breaker consult (non-claiming — candidates that end up
@@ -586,7 +685,8 @@ class ECBlockGroupReader:
             # (the reference reads expectedDataLocations; with topology
             # it sorts replicas nearest-first — here the survivor choice
             # IS the replica choice)
-            nodes = self.group.pipeline.nodes
+            # (nearest_first and list.sort are stable: ties stay in
+            # unit order)
             order = {dn: i for i, dn in
                      enumerate(self.clients.nearest_first(
                          [nodes[u] for u in avail]))}
@@ -632,6 +732,8 @@ class ECBlockGroupReader:
     def recover_cells_iter(
         self, targets: Sequence[int], stripes: Optional[Sequence[int]] = None,
         exclude_stragglers: bool = True,
+        on_survivors: Optional[Callable[
+            [Sequence[int], list[int], np.ndarray], None]] = None,
     ):
         """Streaming recovery: yields (stripe_batch, (rec, crcs)) per
         decode batch — rec [b, len(targets), cell], crcs [b, len(targets),
@@ -639,7 +741,13 @@ class ECBlockGroupReader:
         batch's recovered chunks while the device decodes the next. On a
         unit failure mid-stream the whole recovery restarts with the unit
         excluded and ALL batches are re-yielded; consumers must treat
-        stripe indexes as overwrite keys (chunk writes are idempotent)."""
+        stripe indexes as overwrite keys (chunk writes are idempotent).
+
+        `on_survivors(stripe_batch, valid, batch)`, where given, sees
+        each survivor batch [b, len(valid), cell] once it is read and
+        its decode enqueued, before that decode's results are yielded
+        (a degraded read takes its surviving data cells from it). The
+        batch is the decoder's input: read it, keep no reference."""
         # refresh per call: a reader reused across operations must not
         # re-activate a PREVIOUS operation's (possibly expired) budget
         self._deadline = resilience.current()
@@ -649,7 +757,8 @@ class ECBlockGroupReader:
             # so they get their own allowance on top of the p+1 budget
             for _ in range(2 * self.p + 1):
                 try:
-                    yield from self._recover_batches_once(targets, stripes)
+                    yield from self._recover_batches_once(
+                        targets, stripes, on_survivors)
                     return
                 except _UnitReadError as e:
                     log.warning(
@@ -682,7 +791,8 @@ class ECBlockGroupReader:
             self._close_pool()
 
     def _recover_batches_once(
-        self, targets: Sequence[int], stripes: Optional[Sequence[int]] = None
+        self, targets: Sequence[int],
+        stripes: Optional[Sequence[int]] = None, on_survivors=None,
     ):
         """One recovery attempt as a depth-1 device pipeline: survivor
         reads of batch N+1 run while batch N decodes on device and its
@@ -719,6 +829,9 @@ class ECBlockGroupReader:
             # instead of stalling the whole batch behind it.
             self._fanout_survivors(pool, fill_unit, valid, len(sb))
             out = pipe.submit(batch, sb)
+            if on_survivors is not None:
+                # the batch's decode is queued or on the device by now
+                on_survivors(sb, valid, batch)
             if out is not None:
                 yield out
         out = pipe.drain()
@@ -764,25 +877,43 @@ class ECBlockGroupReader:
         return fn
 
     # ---------------------------------------------------------------- ranged
-    def read(self, offset: int, length: int) -> np.ndarray:
+    def read(self, offset: int, length: int,
+             out: Optional[np.ndarray] = None) -> np.ndarray:
         """Cell-granular range read in user-byte space: only the stripes
         covering [offset, offset+length) are fetched, and on degraded
         groups only those stripes are reconstructed (the reference's
         ECBlockInputStream positioned reads, not whole-block reads).
         Units that fail mid-read are excluded and retried, up to p
-        times."""
+        times. The bytes are written to `out` where given (a writable
+        one-dimensional uint8 array of `length` bytes: the caller's
+        slice of the key's one buffer) and `out` is returned; without
+        it the read allocates."""
         if offset < 0 or length < 0 or \
                 offset + length > self.group.length:
             raise ValueError("range out of bounds")
-        out = np.empty(length, dtype=np.uint8)
+        if out is None:
+            out = np.empty(length, dtype=np.uint8)
+        elif (out.dtype != np.uint8 or out.shape != (length,)
+              or not out.flags.writeable):
+            raise ValueError(
+                f"out must be a writable uint8 array of {length} bytes")
         if length == 0:
             return out
         # refresh per call (see recover_cells_iter): never re-activate a
         # previous operation's expired budget on a reused reader
         self._deadline = resilience.current()
+        tally = self._tally = _ReadTally()
         with Tracer.instance().span("ec:read", offset=offset,
-                                    bytes=length):
-            return self._read_traced(out, offset, length)
+                                    bytes=length) as sp:
+            try:
+                return self._read_traced(out, offset, length)
+            finally:
+                self._tally = None
+                sp.tags.update(cells_reused=tally.cells_reused,
+                               cells_fetched=tally.cells_fetched)
+                OPS.counter("get_cells_reused").inc(tally.cells_reused)
+                OPS.counter("get_cells_fetched").inc(tally.cells_fetched)
+                OPS.counter("get_wire_bytes").inc(tally.wire_bytes)
 
     def _read_traced(self, out: np.ndarray, offset: int,
                      length: int) -> np.ndarray:

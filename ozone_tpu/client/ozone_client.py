@@ -20,8 +20,10 @@ from ozone_tpu.client.dn_client import DatanodeClientFactory
 from ozone_tpu.client.ec_reader import ECBlockGroupReader
 from ozone_tpu.client.ec_writer import BlockGroup, ECKeyWriter
 from ozone_tpu.client.replicated import ReplicatedKeyReader, ReplicatedKeyWriter
+from ozone_tpu.codec import hostmem
 from ozone_tpu.om.om import OpenKeySession, OzoneManager
 from ozone_tpu.scm.pipeline import ReplicationType
+from ozone_tpu.storage.ids import StorageError
 from ozone_tpu.utils.checksum import ChecksumType
 from ozone_tpu.utils.metrics import registry
 from ozone_tpu.utils.tracing import Tracer
@@ -349,6 +351,7 @@ class OzoneBucket:
                                                   length)
         METRICS.histogram("get_seconds").observe(
             time.perf_counter() - t0, sp.trace_id)
+        METRICS.counter("get_user_bytes").inc(int(out.size))
         return out
 
     def _read_inline(self, info: dict, offset: int,
@@ -390,12 +393,16 @@ class OzoneBucket:
     def _read_groups_range(self, om, info: dict, offset: int,
                            length: int) -> np.ndarray:
         groups = om.key_block_groups(info)
-        parts: list[np.ndarray] = []
+        # the key's ONE buffer: every covered group's reader writes its
+        # bytes into its slice of it, no part is assembled twice
+        out = np.empty(length, dtype=np.uint8)
+        filled = 0
         pos = 0  # current group's start offset in key space
         for g in groups:
             a = max(offset, pos)
             b = min(offset + length, pos + g.length)
             if a < b:
+                dst = out[a - offset:b - offset]
                 if g.pipeline.replication.type is ReplicationType.EC:
                     reader = ECBlockGroupReader(
                         g,
@@ -410,12 +417,22 @@ class OzoneBucket:
                         qos_class=admission.ambient_qos(
                             self.client.qos_class),
                     )
+                    reader.read(a - pos, b - a, out=dst)
                 else:
-                    reader = ReplicatedKeyReader(g, self.client.clients)
-                parts.append(reader.read(a - pos, b - a))
+                    # the winner of a race between replicas: copied in
+                    dst[:] = ReplicatedKeyReader(
+                        g, self.client.clients).read(a - pos, b - a)
+                    hostmem.count_copy(
+                        b - a, site="ozone_client.replicated_group",
+                        warn=False)
+                filled += b - a
             pos += g.length
-        out = np.concatenate(parts) if parts else np.zeros(0, np.uint8)
-        assert out.size == length, (out.size, length)
+        if filled != length:
+            # the groups do not cover the range: never hand out the
+            # buffer's untouched bytes
+            raise StorageError(
+                "IO_EXCEPTION", f"block groups of {info.get('key', '')} "
+                f"cover {filled} of {length} bytes at offset {offset}")
         enc = info.get("encryption", {})
         if enc and length:
             from ozone_tpu.utils.kms import ctr_crypt

@@ -214,7 +214,8 @@ def test_replicated_ranged_read(cluster):
 
 def test_ranged_read_off_missing_unit_needs_no_recovery(cluster):
     """A ranged read that never touches the missing unit must not pay a
-    reconstruction: recover_cells is forbidden for the duration."""
+    reconstruction: the recovery entry points are forbidden for the
+    duration."""
     rng = np.random.default_rng(41)
     data = rng.integers(0, 256, 3 * CELL, dtype=np.uint8)  # one stripe
     groups = _write_key(cluster, data)
@@ -226,7 +227,7 @@ def test_ranged_read_off_missing_unit_needs_no_recovery(cluster):
     def boom(*a, **kw):
         raise AssertionError("range off the missing unit must not "
                              "trigger recovery")
-    r.recover_cells = boom
+    r.recover_cells = r.recover_cells_iter = boom
     # bytes [0, 2*CELL) live on units 0 and 1 only
     got = r.read(CELL // 2, CELL)
     assert np.array_equal(got, data[CELL // 2 : CELL // 2 + CELL])
