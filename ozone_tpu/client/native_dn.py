@@ -243,6 +243,18 @@ class NativeDatanodeClient(GrpcDatanodeClient):
                 return
         conn.close()
 
+    def _io_fault(self, e: Exception) -> StorageError:
+        """What a failed exchange on the native socket raises. Its IO
+        timeout is the operation's remaining budget where that is the
+        shorter (`_Conn.arm`): a timeout that the spent budget caused is
+        DEADLINE_EXCEEDED, the budget's, and not UNAVAILABLE, which
+        callers book against the peer (a failed key, an excluded node,
+        a breaker)."""
+        if isinstance(e, TimeoutError):
+            resilience.check_deadline("native_io")
+        return StorageError(
+            "UNAVAILABLE", f"native datapath to {self.address}: {e}")
+
     def _check_partition(self, verb: str) -> None:
         """Same chaos vocabulary as RpcChannel: rules key on the gRPC
         address (and verb), so a blocked or slowed datanode behaves
@@ -330,9 +342,7 @@ class NativeDatanodeClient(GrpcDatanodeClient):
             self._status(conn, body)
         except (OSError, ConnectionError) as e:
             conn.close()
-            raise StorageError(
-                "UNAVAILABLE",
-                f"native datapath to {self.address}: {e}") from e
+            raise self._io_fault(e) from e
         except StorageError:
             if completed:
                 # server-reported error after a full request/STATUS
@@ -443,9 +453,7 @@ class NativeDatanodeClient(GrpcDatanodeClient):
         except (OSError, ConnectionError) as e:
             conn.close()
             out.clear()  # the traceback pins this frame: drop the views
-            raise StorageError(
-                "UNAVAILABLE",
-                f"native datapath to {self.address}: {e}") from e
+            raise self._io_fault(e) from e
         except StorageError:
             # a mid-stream server error leaves this connection's framing
             # state unknown: don't pool it

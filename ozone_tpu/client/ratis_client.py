@@ -17,6 +17,7 @@ the leader so every replica applies the same ordered history.
 from __future__ import annotations
 
 import logging
+import time
 from typing import Optional
 
 from ozone_tpu.client import resilience
@@ -32,6 +33,14 @@ log = logging.getLogger(__name__)
 
 class XceiverClientRatis:
     """Leader-tracking submit/watch client for one pipeline."""
+
+    #: how long every member may answer NO_SUCH_RAFT_GROUP before the
+    #: pipeline is taken for retired. A NEW pipeline's members open its
+    #: group on the join command that rides their next heartbeat (1 s);
+    #: a RETIRED one (its 1:1 container filled and closed) never serves
+    #: again, and a writer that holds a block allocated before the close
+    #: must not climb the whole failover ladder (~8 s) to learn it
+    JOIN_GRACE_S = 3.0
 
     def __init__(self, pipeline: Pipeline, ratis_clients: RatisClientFactory,
                  max_attempts: int = 8, retry_interval_s: float = 0.25):
@@ -67,7 +76,9 @@ class XceiverClientRatis:
         Codes in `non_retriable` propagate immediately (a watch timeout
         is the leader's answer, not a routing failure)."""
         last: Optional[Exception] = None
+        no_group_since: Optional[float] = None
         for attempt in range(self.max_attempts):
+            answers: set[str] = set()
             for dn_id in self._candidates():
                 client = self.clients.maybe_get(dn_id)
                 if client is None:
@@ -78,6 +89,7 @@ class XceiverClientRatis:
                     return out
                 except StorageError as e:
                     last = e
+                    answers.add(e.code)
                     if e.code == "NOT_LEADER":
                         # e.msg carries the leader hint when known
                         self._leader = e.msg or None
@@ -91,6 +103,19 @@ class XceiverClientRatis:
                         raise  # deterministic application error
                 except (KeyError, OSError, ConnectionError) as e:
                     last = e
+                    answers.add("")
+            if answers == {"NO_SUCH_RAFT_GROUP"}:
+                # reached every member and none serves the group
+                now = time.monotonic()
+                if no_group_since is None:
+                    no_group_since = now
+                elif now - no_group_since >= self.JOIN_GRACE_S:
+                    raise StorageError(
+                        "NO_SUCH_RAFT_GROUP",
+                        f"pipeline {self.pipeline.id} is served by none "
+                        f"of its members: retired")
+            else:
+                no_group_since = None
             if attempt < self.max_attempts - 1 and \
                     not self.retry_policy.sleep(attempt):
                 # the operation deadline cannot cover another sweep:
@@ -148,7 +173,6 @@ class RatisKeyWriter(ReplicatedKeyWriter):
         #: per-policy wait before an ALL watch degrades to MAJORITY
         self.watch_timeout_s = watch_timeout_s
         self._xceivers: dict[int, XceiverClientRatis] = {}
-        self._watch_targets: list[tuple[XceiverClientRatis, int]] = []
         self._last_index = 0
 
     def _xceiver(self, group: BlockGroup) -> XceiverClientRatis:
@@ -192,10 +216,17 @@ class RatisKeyWriter(ReplicatedKeyWriter):
             x.watch_for_commit(int(out.get("index", 0)),
                                timeout=min(2.0, self.watch_timeout_s))
         except (StorageError, ConnectionError, KeyError, OSError) as e:
+            self._group = None
+            if isinstance(e, StorageError) \
+                    and e.code == "NO_SUCH_RAFT_GROUP":
+                # a block allocated before its container filled, closed
+                # and retired its pipeline: the members are healthy, the
+                # CONTAINER is gone; reallocate elsewhere, exclude nobody
+                self._excluded_containers.append(group.container_id)
+                raise StripeWriteError([], e)
             # the whole pipeline is unreachable through its ring (e.g. a
             # client-side partition): surface the base-class contract so
             # the retry path excludes these members and reallocates
-            self._group = None
             raise StripeWriteError(list(group.pipeline.nodes), e)
 
     def _commit_chunk(self, group: BlockGroup, info: ChunkInfo) -> None:
@@ -214,18 +245,17 @@ class RatisKeyWriter(ReplicatedKeyWriter):
         self._last_index = int(out.get("index", 0))
 
     def _finalize_group(self) -> None:
-        if self._group is not None and self._group.length > 0 and \
-                self._last_index:
-            self._watch_targets.append(
-                (self._xceiver(self._group), self._last_index))
-            self._last_index = 0
+        """hflush barrier at the block's end: its last commit index
+        applied on all replicas (BlockOutputStream watchForCommit
+        watermark). Asked for NOW, not at the key's close: a full
+        container closes through the ring after the writes it holds,
+        its (1:1) pipeline retires a heartbeat or two later, and a
+        watch that a many-block key sent only at its close found the
+        raft group gone from every member (NO_SUCH_RAFT_GROUP) though
+        every replica had applied the block."""
+        group, index = self._group, self._last_index
+        self._last_index = 0
         super()._finalize_group()
-
-    def close(self) -> list[BlockGroup]:
-        groups = super().close()
-        # hflush barrier: every finalized block's commit index applied on
-        # all replicas (BlockOutputStream watchForCommit watermark)
-        targets, self._watch_targets = self._watch_targets, []
-        for xceiver, index in targets:
-            xceiver.watch_for_commit(index, timeout=self.watch_timeout_s)
-        return groups
+        if group is not None and group.length > 0 and index:
+            self._xceiver(group).watch_for_commit(
+                index, timeout=self.watch_timeout_s)

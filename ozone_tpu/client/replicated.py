@@ -207,6 +207,11 @@ class ReplicatedKeyWriter:
                 self._commit_chunk(group, info)
                 return True, [], False, None
             except (StorageError, KeyError, OSError) as e:
+                if isinstance(e, StorageError) \
+                        and e.code == "INVALID_CONTAINER_STATE":
+                    # closed or gone unhealthy under the commit: the
+                    # retry must not be handed this container again
+                    self._excluded_containers.append(group.container_id)
                 return False, [], False, e  # commit failure: no node
         return False, failed, closed, err  # to exclude
 

@@ -13,6 +13,7 @@ from __future__ import annotations
 import json
 import logging
 import os
+import queue
 import threading
 import time
 from pathlib import Path
@@ -201,6 +202,17 @@ class DatanodeDaemon:
         self.reconstruction = ECReconstructionCoordinator(
             self.clients, mesh=self._codec_mesh)
         self._pending_acks: list[int] = []
+        self._acks_lock = threading.Lock()
+        #: the SCM's commands, executed in order on a thread of their
+        #: own (`_command_loop`) once the daemon's loops run: a
+        #: replication or a reconstruction takes seconds, and a heartbeat
+        #: that waited for it made a busy datanode look STALE to the SCM
+        #: (9 s), which then refused every allocation that needs all
+        #: nodes (upstream: the datanode's CommandDispatcher has its own
+        #: handler threads, the heartbeat its own). Bounded: with this
+        #: many waiting the heartbeat waits for room, as it always did
+        self._commands: "queue.Queue" = queue.Queue(maxsize=1024)
+        self._cmd: Optional[threading.Thread] = None
         # container-report gating (see heartbeat_once)
         self.full_report_every_s = 10.0
         self._last_report_fp = None
@@ -264,6 +276,10 @@ class DatanodeDaemon:
                           op_state=self._op_state,
                           capacity_bytes=self._capacity_bytes())
         self._sync_security()
+        self._cmd = threading.Thread(
+            target=self._command_loop, name=f"cmd-{self.dn.id}",
+            daemon=True)
+        self._cmd.start()
         self._hb = threading.Thread(
             target=self._heartbeat_loop, name=f"hb-{self.dn.id}", daemon=True
         )
@@ -411,7 +427,11 @@ class DatanodeDaemon:
                 pass
         return total
 
-    def heartbeat_once(self) -> None:
+    def heartbeat_once(self, defer_commands: bool = False) -> None:
+        """One heartbeat: report, acknowledge, take the SCM's commands.
+        They are executed here, before returning (tests and drills that
+        tick a cluster by hand), or with `defer_commands` handed to the
+        command thread, as the daemon's own loop does."""
         # full container reports only on change or every
         # full_report_every_s (the reference's ICR-on-change +
         # periodic-FCR cadence): building one walks every container's
@@ -428,7 +448,8 @@ class DatanodeDaemon:
         else:
             report = None
         used = self._last_used
-        acks, self._pending_acks = self._pending_acks, []
+        with self._acks_lock:
+            acks, self._pending_acks = self._pending_acks, []
         commands = self.scm.heartbeat(
             self.dn.id, container_report=report, used_bytes=used,
             layout_version=self.layout.metadata_version,
@@ -443,15 +464,31 @@ class DatanodeDaemon:
             self._last_report_t = now
         self._sync_security()
         for cmd in commands:
-            self._execute(cmd)
+            # opening a raft group is quick and writers wait for it: it
+            # never queues behind a close or a replication
+            if defer_commands and not (
+                    isinstance(cmd, dict)
+                    and cmd.get("type") == "join-pipeline"):
+                self._commands.put(cmd)
+            else:
+                self._execute(cmd)
 
     def _heartbeat_loop(self) -> None:
         while not self._stop.wait(self.heartbeat_interval):
             try:
-                self.heartbeat_once()
+                self.heartbeat_once(defer_commands=True)
                 self._drain_scan_requests()
             except Exception:
                 log.exception("%s heartbeat failed", self.dn.id)
+
+    def _command_loop(self) -> None:
+        """The SCM's commands in the order they came, one at a time
+        (`_execute` logs what fails and goes on)."""
+        while True:
+            cmd = self._commands.get()
+            if cmd is None:
+                return
+            self._execute(cmd)
 
     def _drain_scan_requests(self) -> None:
         """On-demand verification scans (OnDemandContainerDataScanner
@@ -504,7 +541,8 @@ class DatanodeDaemon:
                         log.warning("%s: delete of block %s failed "
                                     "(tx still acked): %s",
                                     self.dn.id, bid, e)
-                self._pending_acks.extend(cmd.tx_ids)
+                with self._acks_lock:
+                    self._pending_acks.extend(cmd.tx_ids)
             elif isinstance(cmd, ReconstructionCommand):
                 self._learn_topology()
                 self.reconstruction.reconstruct_container_group(cmd)
@@ -575,6 +613,12 @@ class DatanodeDaemon:
             # deadline to derive from, and an unbounded join would let
             # a wedged loop hang process exit
             self._hb.join(timeout=5)  # ozlint: allow[deadline-propagation] -- bounded shutdown join, no ambient op deadline at stop()
+        if self._cmd:
+            try:
+                self._commands.put_nowait(None)  # after what is queued
+            except queue.Full:  # ozlint: allow[error-swallowing] -- a backlog that deep is not drained at shutdown: the thread is a daemon and goes with the process
+                pass
+            self._cmd.join(timeout=5)  # ozlint: allow[deadline-propagation] -- bounded shutdown join, no ambient op deadline at stop()
         if self._scanner:
             self._scanner.join(timeout=5)  # ozlint: allow[deadline-propagation] -- bounded shutdown join, no ambient op deadline at stop()
         self.xceiver_ratis.stop()

@@ -11,9 +11,14 @@ and the route is decided here, once, from what the code can observe:
     caller was handed one (repair: a storm or a coordinator hands its
     executor to every reader, many groups with one erasure pattern),
     or when it is an encode sweep (lifecycle tiering, a re-encode that
-    lost its parity: full windows by construction) on a host where the
-    process-wide executor exists, which is where more than one device
-    is attached; and the executor has a program for the key.
+    lost its parity) on a host where the process-wide executor exists,
+    which is where more than one device is attached; and the executor
+    has a program for the key. The stream is told how many stripes one
+    dispatch of its lane carries (`_Pipeline.width`: the caller's width
+    on the codec service, that times the devices on the mesh), and a
+    sweep that packs its windows to it sends full dispatches; one that
+    submits at its own width (the re-encode) lingers and goes out
+    partly filled.
     Everything else joins the codec service: every single batch (a
     PUT's flush, a lone-stripe hedge decode), every interactive stream,
     and a bulk degraded read nobody handed an executor.
@@ -21,11 +26,12 @@ and the route is decided here, once, from what the code can observe:
 So on a one-chip host every stripe goes to the service; on a
 multi-device host background sweeps and repair storms meet in the mesh
 lanes while PUTs, GETs and hedge decodes stay on one chip. A lone bulk
-stream stays there too: on the mesh it is padded to every device's
-slots and runs half as fast (PERF.md section 6, PR 27), and telling a
-lone stream from a storm by what is queued is this module's open item
-(ROADMAP Queue 1 item 5b(iv)). The arrows point one way: consumers ->
-door -> {service, mesh executor} -> `fused` / `sharded`.
+DECODE stream stays there too: on the mesh its 4- or 8-stripe batches
+are padded to every device's slots and it runs half as fast (PERF.md
+section 6, PR 27), and telling a lone stream from a storm by what is
+queued is this module's open item (ROADMAP Queue 1 item 7, once item
+5b(iv)). The arrows point one way: consumers -> door -> {service, mesh
+executor} -> `fused` / `sharded`.
 """
 
 from __future__ import annotations
@@ -67,10 +73,11 @@ def submit(key: tuple, fn: Callable, stripes: np.ndarray, *, width: int,
 
 
 def _mesh_lane(key: tuple, width: int, qos: str,
-               executor) -> Optional[Callable[..., Future]]:
-    """`submit(stripes, *, tail, deadline)` of the mesh lane a stream
-    under `key` joins, or None where it stays on the codec service:
-    the route, decided here and nowhere else."""
+               executor) -> Optional[tuple[Callable[..., Future], int]]:
+    """(`submit(stripes, *, tail, deadline)` of the mesh lane a stream
+    under `key` joins, the stripes one dispatch of that lane carries),
+    or None where it stays on the codec service: the route, decided
+    here and nowhere else."""
     if qos != "bulk":
         return None
     if executor is None and key[0] == "encode":
@@ -78,7 +85,8 @@ def _mesh_lane(key: tuple, width: int, qos: str,
     if executor is None:
         return None
     try:
-        return executor.pipeline(key, width=width, qos=qos)
+        return (executor.pipeline(key, width=width, qos=qos),
+                executor.dispatch_width(width))
     except KeyError:
         # the executor's "no program for this key"; the service has one
         # for every key, and the mesh cell's comparison reports a
@@ -97,10 +105,14 @@ class _Pipeline:
     drain() returns the last. `ctx` rides along untouched, so every
     depth-1 consumer (degraded reads, repair, re-encode, lifecycle
     tiering) keeps its overlap: the writes of batch N run under the
-    device pass and the pull of batch N+1."""
+    device pass and the pull of batch N+1. `width` is the stripes ONE
+    dispatch of the lane the stream joined carries: a consumer that
+    packs across its operations (the tiering sweep) fills its batches
+    to it, whichever scheduler it is."""
 
-    def __init__(self, submit_fn: Callable[..., Future]):
+    def __init__(self, submit_fn: Callable[..., Future], width: int):
         self._submit = submit_fn
+        self.width = width
         self._pending: Optional[tuple] = None
 
     def submit(self, batch: np.ndarray, ctx: Any = None,
@@ -126,8 +138,8 @@ def pipeline(key: tuple, fn: Callable, *, width: int, qos: str,
     """A depth-1 stream of batches under `key`: the route is taken
     once, here, and every batch follows it. `executor` is the mesh
     executor the caller was handed (None: it was handed none)."""
-    lane = _mesh_lane(key, width, qos, executor)
-    if lane is None:
-        lane = functools.partial(codec_service.get_service().submit, key,
-                                 fn, width=width, qos=qos)
-    return _Pipeline(lane)
+    route = _mesh_lane(key, width, qos, executor)
+    if route is None:
+        route = (functools.partial(codec_service.get_service().submit,
+                                   key, fn, width=width, qos=qos), width)
+    return _Pipeline(*route)

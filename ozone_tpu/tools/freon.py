@@ -277,9 +277,13 @@ def lcg(client, n_keys: int = 20, size: int = 10 * 1024,
     soak/CI probe for the tiering subsystem. Writes `n_keys` replicated
     keys under an age-based TRANSITION_TO_EC rule, triggers a sweep
     (`lifecycle run-now`), then verifies every key reads back
-    byte-exact AND erasure-coded. The timer covers the writes; the
-    sweep/verify outcome rides the report extras (`transitioned`,
-    `verify_failures`)."""
+    byte-exact AND erasure-coded. The timer covers the WRITES only:
+    the report's rate and latencies are those of the replicated PUTs,
+    and the sweep (run inside the OM daemon, off any chip its host has)
+    is not timed here; its outcome rides the report extras
+    (`transitioned`, `verify_failures`). The sweep itself is timed by
+    the benchmark's cell `tier-mesh.rs-6-3`
+    (`benchmarks/generators/tier_sweep.py`)."""
     try:
         client.om.create_volume(volume)
     except Exception:

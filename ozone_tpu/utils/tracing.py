@@ -120,6 +120,41 @@ class Tracer:
             _local.span = prev
             self._finish(s)
 
+    def begin_operation(self, name: str, **tags) -> Span:
+        """Open an operation root that no `with` can bracket: one of
+        several a single thread works on in turn (the tiering sweep
+        opens a key, packs it, and commits it windows later, other keys
+        in between). It is never the thread's current span: its stages
+        are spans opened with `child_of=context(root)`. Finish it with
+        `end_operation`, exactly once."""
+        s = Span(self._new_id(), self._new_id(), "", name, time.time(),
+                 tags=dict(tags), mono=time.monotonic())
+        s.op = True
+        return s
+
+    def end_operation(self, s: Span) -> None:
+        s.duration = time.monotonic() - s.mono
+        self._finish(s)
+
+    @staticmethod
+    def context(s: Span) -> str:
+        """`s` as a `child_of` context string."""
+        return f"{s.trace_id}:{s.span_id}"
+
+    @contextmanager
+    def riders(self, contexts):
+        """While open, `inject()` on this thread gives ALL of
+        `contexts`, comma-joined: a submission that carries stripes of
+        several operations (a tiering window) hands the scheduler every
+        rider's context, and `record_span` then leaves the submission's
+        queue-wait and dispatch spans in each rider's trace."""
+        prev = getattr(_local, "riders", "")
+        _local.riders = ",".join(dict.fromkeys(c for c in contexts if c))
+        try:
+            yield
+        finally:
+            _local.riders = prev
+
     def _finish(self, s: Span) -> None:
         with self._lock:
             self.spans.append(s)
@@ -150,6 +185,14 @@ class Tracer:
         contextmanager span can't bracket the interval. `mono` is the
         interval's start on time.monotonic(); without it the interval
         is taken to have just ended."""
+        if mono is None:
+            mono = time.monotonic() - duration
+        if "," in child_of:
+            # a submission with several riders (`riders`): the same
+            # interval in each rider's trace; the first is returned
+            return [self.record_span(name, child_of=ctx, start=start,
+                                     duration=duration, mono=mono, **tags)
+                    for ctx in child_of.split(",")][0]
         if child_of:
             trace_id, parent_id = (child_of.split(":") + [""])[:2]
         else:
@@ -158,8 +201,6 @@ class Tracer:
                 trace_id, parent_id = cur.trace_id, cur.span_id
             else:
                 trace_id, parent_id = self._new_id(), ""
-        if mono is None:
-            mono = time.monotonic() - duration
         s = Span(trace_id, span_id or self._new_id(), parent_id, name,
                  start, duration, tags=dict(tags), mono=mono)
         self._finish(s)
@@ -194,8 +235,11 @@ class Tracer:
     def inject(self) -> str:
         """Export the current context for the wire ("traceID" field analog);
         empty string when not tracing."""
+        riders = getattr(_local, "riders", "")
+        if riders:
+            return riders
         s = self.current()
-        return f"{s.trace_id}:{s.span_id}" if s else ""
+        return self.context(s) if s else ""
 
     def current_trace_id(self) -> str:
         s = self.current()

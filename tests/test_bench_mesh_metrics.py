@@ -44,9 +44,8 @@ def test_a_new_metric_is_an_entry_of_the_mesh_cell_alone(name):
                      "source": "program_counter", "layer": layer,
                      "moves": "repair_mib_s", "workloads": [CELL]}
     assert mf.metric_params(name)["reader"] == reader
-    # appended: nothing the benchmark had moved
-    assert MANIFEST["per_layer"].index(entry) >= \
-        len(MANIFEST["per_layer"]) - len(NEW)
+    # appended after the 60 the benchmark had (later PRs append after)
+    assert 60 <= MANIFEST["per_layer"].index(entry) < 60 + len(NEW)
 
 
 def test_the_manifest_is_sound_and_the_cell_reports_what_it_did_and_three():

@@ -526,3 +526,64 @@ def test_volume_failure_triggers_reconstruction(cluster):
         for g in groups
     ]
     assert np.array_equal(np.concatenate(parts)[: data.size], data)
+
+
+def test_a_slow_command_does_not_hold_the_datanodes_heartbeat(tmp_path):
+    """The SCM's commands run on a thread of their own once the daemon's
+    loops run: while a replication or a reconstruction takes its seconds
+    the datanode keeps heartbeating (a late heartbeat made it STALE, and
+    the SCM then refused every allocation that needs all nodes), and the
+    commands still run one at a time, in the order they came."""
+    import threading
+    import time
+
+    from ozone_tpu.testing.minicluster import MiniOzoneHACluster
+
+    ha = MiniOzoneHACluster(tmp_path, num_meta=1, num_datanodes=1,
+                            heartbeat_interval_s=0.05)
+    try:
+        d = ha.datanodes[0]
+        started, release = threading.Event(), threading.Event()
+        done: list[str] = []
+        beats: list[float] = []
+        real_execute, real_beat = d._execute, d.scm.heartbeat
+
+        def execute(cmd):
+            if cmd == {"type": "test-slow"}:
+                started.set()
+                assert release.wait(10)
+                done.append("slow")
+            elif cmd == {"type": "test-after"}:
+                done.append("after")
+            else:
+                real_execute(cmd)
+
+        def heartbeat(*a, **kw):
+            out = list(real_beat(*a, **kw))
+            beats.append(time.monotonic())
+            if len(beats) == 1:
+                out += [{"type": "test-slow"}, {"type": "test-after"}]
+            return out
+
+        d._execute, d.scm.heartbeat = execute, heartbeat
+        assert started.wait(5)
+        n = len(beats)
+        deadline = time.monotonic() + 5
+        while len(beats) < n + 3 and time.monotonic() < deadline:
+            time.sleep(0.02)
+        assert len(beats) >= n + 3  # it kept beating under the command
+        assert done == []           # which has not ended, nor the next
+        release.set()
+        deadline = time.monotonic() + 5
+        while len(done) < 2 and time.monotonic() < deadline:
+            time.sleep(0.02)
+        assert done == ["slow", "after"]
+        # ticking by hand (tests, drills) still executes before returning
+        d._stop.set()
+        d._hb.join(5)
+        assert not d._hb.is_alive()
+        d.scm.heartbeat = lambda *a, **kw: [{"type": "test-after"}]
+        d.heartbeat_once()
+        assert done == ["slow", "after", "after"]
+    finally:
+        ha.shutdown()

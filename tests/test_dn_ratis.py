@@ -260,6 +260,143 @@ def test_write_succeeds_with_minority_member_down(ring):
     assert w._xceivers[pipeline.id]._degraded
 
 
+def test_a_blocks_watch_is_sent_at_its_end_not_at_the_keys_close(tmp_path):
+    """A full container closes through its ring after the writes it
+    holds, and its (1:1) pipeline retires a heartbeat or two later: a
+    key of many blocks must have watched its early blocks' commits by
+    then, or its close finds their raft group gone from every member."""
+    dns, xceivers, pipes, clients, ratis = _two_pipelines(tmp_path)
+    allocated = []
+
+    def allocate_group(excluded):
+        if allocated:
+            # the first block's container has closed and its pipeline
+            # retired: every member has left that raft group
+            for xc in xceivers:
+                xc.leave(pipes[0].id)
+        allocated.append(len(allocated) + 1)
+        return BlockGroup(container_id=len(allocated),
+                          local_id=next(_alloc_count),
+                          pipeline=pipes[len(allocated) - 1])
+
+    payload = np.random.default_rng(11).integers(
+        0, 256, 3 * 64 * 1024, dtype=np.uint8)
+    w = RatisKeyWriter(allocate_group, clients, ratis,
+                       block_size=2 * 64 * 1024, chunk_size=64 * 1024)
+    try:
+        w.write(payload)
+        groups = w.close()
+        assert [g.pipeline.id for g in groups] == [p.id for p in pipes]
+        out = np.concatenate(
+            [ReplicatedKeyReader(g, clients).read_all() for g in groups])
+        assert np.array_equal(out, payload)
+    finally:
+        for xc in xceivers:
+            xc.stop()
+        for dn in dns:
+            dn.close()
+
+
+def _two_pipelines(tmp_path):
+    ids = ["dn0", "dn1", "dn2"]
+    peers = {i: "" for i in ids}
+    dns = [make_dn(tmp_path, name) for name in ids]
+    xceivers = [RatisXceiverServer(dn, tmp_path / dn.id, "", config=FAST,
+                                   auto_timers=False) for dn in dns]
+    pipes = [Pipeline(ReplicationConfig.ratis(3), ids) for _ in range(2)]
+    for p in pipes:
+        transport = InProcessTransport()
+        for xc in xceivers:
+            xc.join(p.id, peers, transport=transport)
+        assert xceivers[0].get(p.id).start_election()
+    clients, ratis = DatanodeClientFactory(), RatisClientFactory()
+    for dn, xc in zip(dns, xceivers):
+        clients.register_local(dn)
+        ratis.register_local(xc, dn.id)
+    return dns, xceivers, pipes, clients, ratis
+
+
+def test_a_block_of_a_retired_pipeline_is_reallocated_and_nobody_excluded(
+        tmp_path, monkeypatch):
+    """A block allocated before its container filled: by the time the
+    writer opens it the container has closed and its pipeline retired.
+    Every member answers NO_SUCH_RAFT_GROUP for good; the writer learns
+    it within the join grace (not the whole failover ladder), asks for a
+    block elsewhere, excludes that CONTAINER and none of the healthy
+    members."""
+    import time
+
+    dns, xceivers, pipes, clients, ratis = _two_pipelines(tmp_path)
+    monkeypatch.setattr(XceiverClientRatis, "JOIN_GRACE_S", 0.3)
+    for xc in xceivers:
+        xc.leave(pipes[0].id)  # retired before the writer's first verb
+    asked = []
+
+    def allocate_group(excluded, excluded_containers):
+        asked.append((list(excluded), list(excluded_containers)))
+        return BlockGroup(container_id=len(asked),
+                          local_id=next(_alloc_count),
+                          pipeline=pipes[len(asked) - 1])
+
+    payload = np.random.default_rng(12).integers(
+        0, 256, 100_000, dtype=np.uint8)
+    w = RatisKeyWriter(allocate_group, clients, ratis,
+                       chunk_size=64 * 1024)
+    try:
+        t0 = time.monotonic()
+        w.write(payload)
+        groups = w.close()
+        assert time.monotonic() - t0 < 4.0
+        assert asked == [([], []), ([], [1])]
+        assert [g.pipeline.id for g in groups] == [pipes[1].id]
+        out = np.concatenate(
+            [ReplicatedKeyReader(g, clients).read_all() for g in groups])
+        assert np.array_equal(out, payload)
+    finally:
+        for xc in xceivers:
+            xc.stop()
+        for dn in dns:
+            dn.close()
+
+
+def test_a_container_refused_at_the_commit_is_not_taken_again(tmp_path):
+    """INVALID_CONTAINER_STATE from the commit (the replica closed or
+    went unhealthy between the data phase and the ring's apply): the
+    retry excludes the container, or the SCM, which still has it OPEN,
+    hands it out until the writer runs out of tries."""
+    dns, xceivers, pipes, clients, ratis = _two_pipelines(tmp_path)
+    asked = []
+
+    def allocate_group(excluded, excluded_containers):
+        asked.append(list(excluded_containers))
+        cid = 1 if 1 not in excluded_containers else 2
+        return BlockGroup(container_id=cid, local_id=next(_alloc_count),
+                          pipeline=pipes[cid - 1])
+
+    payload = np.arange(50_000, dtype=np.uint32).astype(np.uint8)
+    w = RatisKeyWriter(allocate_group, clients, ratis,
+                       chunk_size=64 * 1024)
+    real = w._commit_chunk
+
+    def commit(group, info):
+        if group.container_id == 1:
+            raise StorageError("INVALID_CONTAINER_STATE",
+                               "container 1 is UNHEALTHY, not writable")
+        real(group, info)
+
+    w._commit_chunk = commit
+    try:
+        w.write(payload)
+        groups = w.close()
+        assert asked == [[], [1]]
+        assert [g.container_id for g in groups] == [2]
+    finally:
+        for xc in xceivers:
+            xc.stop()
+        for dn in dns:
+            dn.close()
+
+
 def test_join_replaces_group_with_changed_membership(tmp_path):
     """Defense in depth: a served group whose announced membership
     differs is stale metadata — it must be replaced, never reused."""

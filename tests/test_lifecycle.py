@@ -185,7 +185,9 @@ def test_many_keys_share_device_dispatches(cluster, monkeypatch):
     """The tentpole's batching claim: a sweep over many small keys must
     pack MANY keys per DeviceBatchPipeline submission — dispatches ~
     total_stripes / window, never one-plus per key."""
-    monkeypatch.setenv("OZONE_TPU_TIER_BATCH", "8")
+    # one stripe a device: on the tests' 8 host devices the sweep joins
+    # the mesh lane, whose dispatch (the packer's window) is 8 stripes
+    monkeypatch.setenv("OZONE_TPU_TIER_BATCH", "1")
     oz = cluster.client()
     oz.create_volume("v").create_bucket("b", replication="RATIS/THREE")
     # 24576 bytes = exactly 2 rs-3-2-4096 stripes per key

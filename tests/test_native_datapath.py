@@ -258,3 +258,29 @@ def test_native_partition_rules_apply(cluster):
     bid = BlockID(1, 60)
     client.write_chunks_commit(bid, [(info, data)],
                                commit=BlockData(bid, [info]))
+
+
+def test_a_timeout_the_spent_budget_caused_is_the_budgets_not_the_peers():
+    """The socket's IO timeout is the operation's remaining budget where
+    that is shorter: when it fires with the budget spent the caller sees
+    DEADLINE_EXCEEDED (a sweep's window closing), not UNAVAILABLE, which
+    callers book against the datanode (a failed key, an excluded node)."""
+    import time
+
+    from ozone_tpu.client import resilience
+    from ozone_tpu.client.native_dn import NativeDatanodeClient
+    from ozone_tpu.storage.ids import StorageError
+
+    c = NativeDatanodeClient.__new__(NativeDatanodeClient)
+    c.address = "127.0.0.1:1"
+    # no budget, or a live one: the peer's fault, as before
+    assert c._io_fault(TimeoutError("timed out")).code == "UNAVAILABLE"
+    with resilience.start("op", seconds=60.0):
+        assert c._io_fault(TimeoutError("timed out")).code == "UNAVAILABLE"
+    with resilience.start("op", seconds=0.001):
+        time.sleep(0.005)
+        with pytest.raises(StorageError) as e:
+            c._io_fault(TimeoutError("timed out"))
+        assert e.value.code == resilience.DEADLINE_EXCEEDED
+        # a refused or reset connection stays the peer's, budget or not
+        assert c._io_fault(ConnectionError("reset")).code == "UNAVAILABLE"

@@ -264,3 +264,41 @@ def test_repair_spans_arrive_under_the_root_from_the_recon_pool(
     # PUTs of the set-up are operations of their own, told apart by root
     assert [o["root"] for o in t.recorder.operations()] == [
         "client:put", "repair:container"]
+
+
+def test_interleaved_roots_one_thread_and_a_submission_with_many_riders(t):
+    """The tiering sweep's shape: one thread works on several operation
+    roots in turn (`begin_operation` / `end_operation`, stages under
+    `activate(context(root))`), and one submission carries stripes of
+    them all: inside `riders(...)` the context `inject()` hands the
+    scheduler names every rider, and the interval the scheduler records
+    against it lands in each rider's trace."""
+    a = t.begin_operation("tier:key", key="a")
+    b = t.begin_operation("tier:key", key="b")
+    assert t.current() is None  # a begun root is nobody's current span
+    for root in (a, b, a):
+        with t.activate(t.context(root)), t.span("tier:read"):
+            time.sleep(0.001)
+    with t.riders([t.context(a), t.context(b), t.context(a), ""]):
+        ctx = t.inject()
+        assert ctx == f"{t.context(a)},{t.context(b)}"  # once each
+    assert t.inject() == ""  # outside the block: the thread's own
+    enq_wall, enq = time.time(), time.monotonic()
+    time.sleep(0.002)
+    first = t.record_span("mesh:device_dispatch", child_of=ctx,
+                          start=enq_wall, duration=time.monotonic() - enq,
+                          mono=enq, stripes=8)
+    assert first.trace_id == a.trace_id and first.tags == {"stripes": 8}
+    t.end_operation(b)
+    time.sleep(0.001)
+    t.end_operation(a)
+    recs = {r["traceId"]: r for r in t.recorder.operations("tier:key")}
+    assert set(recs) == {a.trace_id, b.trace_id}
+    for root in (a, b):
+        stages = recs[root.trace_id]["stages"]
+        assert set(stages) == {"tier:key", "tier:read",
+                               "mesh:device_dispatch"}
+        assert stages["mesh:device_dispatch"] >= 2000
+        assert sum(stages.values()) == pytest.approx(
+            recs[root.trace_id]["durationUs"], abs=3)
+    assert recs[a.trace_id]["durationUs"] > recs[b.trace_id]["durationUs"]
