@@ -23,6 +23,7 @@ loser's bytes are discarded.
 from __future__ import annotations
 
 import logging
+import math
 import threading
 from typing import Callable, Optional, Sequence
 
@@ -89,7 +90,12 @@ class _ReadTally:
         self._lock = threading.Lock()
         self.cells_fetched = 0
         self.wire_bytes = 0
-        self.cells_reused = 0  # the reading thread's alone
+        # the reading thread's alone:
+        self.cells_reused = 0
+        #: bytes of the read's own buffers (its part of the key's
+        #: buffer, its survivor batches) that were mapped new for it,
+        #: not recycled: first touched, page by page, inside the read
+        self.fresh_bytes = 0
 
     def asked(self, infos: Sequence[ChunkInfo]) -> None:
         with self._lock:
@@ -808,8 +814,17 @@ class ECBlockGroupReader:
         for sb in batched(stripes, self._decode_batch):
             # width = len(valid), not k: an LRC local repair reads only
             # the lost unit's group (group_size survivors)
-            batch = np.zeros((len(sb), len(valid), self.cell),
-                             dtype=np.uint8)
+            shape = (len(sb), len(valid), self.cell)
+            # pool memory, NOT zeroed: a recycled batch holds another
+            # key's bytes, and every row is written below before the
+            # decoder or `on_survivors` sees it. An attempt that a
+            # failed unit or a straggler hedge abandons leaves its batch
+            # to the reader threads still writing into it: `fill_unit`
+            # pins it, and its pages go back when the last one is done.
+            flat, fresh = hostmem.pool().lease_array(math.prod(shape))
+            batch = flat.reshape(shape)
+            if fresh and self._tally is not None:
+                self._tally.fresh_bytes += flat.size
 
             def fill_unit(vi_u):
                 vi, u = vi_u
@@ -818,6 +833,9 @@ class ECBlockGroupReader:
                 # per-chunk reads
                 self._prefetch_unit(u, sb)
                 for bi, s in enumerate(sb):
+                    # EVERY [bi, vi] is assigned a whole cell: an absent
+                    # or short one comes zero-padded (`_fetch_cell`,
+                    # `_cell_array`), so no recycled byte stays
                     batch[bi, vi] = self._read_cell_checked(u, s)
 
             # one reader thread per survivor unit: the k unit streams
@@ -878,7 +896,8 @@ class ECBlockGroupReader:
 
     # ---------------------------------------------------------------- ranged
     def read(self, offset: int, length: int,
-             out: Optional[np.ndarray] = None) -> np.ndarray:
+             out: Optional[np.ndarray] = None,
+             out_fresh: bool = False) -> np.ndarray:
         """Cell-granular range read in user-byte space: only the stripes
         covering [offset, offset+length) are fetched, and on degraded
         groups only those stripes are reconstructed (the reference's
@@ -886,13 +905,16 @@ class ECBlockGroupReader:
         Units that fail mid-read are excluded and retried, up to p
         times. The bytes are written to `out` where given (a writable
         one-dimensional uint8 array of `length` bytes: the caller's
-        slice of the key's one buffer) and `out` is returned; without
-        it the read allocates."""
+        slice of the key's one buffer; `out_fresh` where those pages
+        were mapped new for this operation, for the span's
+        `fresh_bytes`) and `out` is returned; without it the read
+        leases one from the host buffer pool, which the returned array
+        pins."""
         if offset < 0 or length < 0 or \
                 offset + length > self.group.length:
             raise ValueError("range out of bounds")
         if out is None:
-            out = np.empty(length, dtype=np.uint8)
+            out, out_fresh = hostmem.pool().lease_array(length)
         elif (out.dtype != np.uint8 or out.shape != (length,)
               or not out.flags.writeable):
             raise ValueError(
@@ -903,6 +925,7 @@ class ECBlockGroupReader:
         # previous operation's expired budget on a reused reader
         self._deadline = resilience.current()
         tally = self._tally = _ReadTally()
+        tally.fresh_bytes = length if out_fresh else 0
         with Tracer.instance().span("ec:read", offset=offset,
                                     bytes=length) as sp:
             try:
@@ -910,7 +933,8 @@ class ECBlockGroupReader:
             finally:
                 self._tally = None
                 sp.tags.update(cells_reused=tally.cells_reused,
-                               cells_fetched=tally.cells_fetched)
+                               cells_fetched=tally.cells_fetched,
+                               fresh_bytes=tally.fresh_bytes)
                 OPS.counter("get_cells_reused").inc(tally.cells_reused)
                 OPS.counter("get_cells_fetched").inc(tally.cells_fetched)
                 OPS.counter("get_wire_bytes").inc(tally.wire_bytes)

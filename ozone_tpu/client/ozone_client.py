@@ -394,8 +394,13 @@ class OzoneBucket:
                            length: int) -> np.ndarray:
         groups = om.key_block_groups(info)
         # the key's ONE buffer: every covered group's reader writes its
-        # bytes into its slice of it, no part is assembled twice
-        out = np.empty(length, dtype=np.uint8)
+        # bytes into its slice of it, no part is assembled twice. Pool
+        # memory, as the wire slabs are: the array the user gets pins
+        # its lease, and the pages go back to the pool when the answer
+        # and every view of it are dropped. Recycled pages hold another
+        # key's bytes: what the groups below do not write is never
+        # handed out (the `filled` check).
+        out, fresh = hostmem.pool().lease_array(length)
         filled = 0
         pos = 0  # current group's start offset in key space
         for g in groups:
@@ -417,7 +422,7 @@ class OzoneBucket:
                         qos_class=admission.ambient_qos(
                             self.client.qos_class),
                     )
-                    reader.read(a - pos, b - a, out=dst)
+                    reader.read(a - pos, b - a, out=dst, out_fresh=fresh)
                 else:
                     # the winner of a race between replicas: copied in
                     dst[:] = ReplicatedKeyReader(

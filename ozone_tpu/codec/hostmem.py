@@ -20,9 +20,39 @@ leases feeding the gRPC datapath in Apache Ozone — the same argument
 (allocation reuse + explicit lifetime beats GC'd byte[] churn) applied
 to the Python side of the sidecar protocol.
 
+What the pool holds: the wire slabs of every native read (one lease a
+ReadChunks stream), the codec service's staging batches, and the two
+per-operation buffers of an EC read: the key's one buffer that the
+user's answer is a view of (`OzoneBucket._read_groups_range`) and each
+survivor batch a recovery decodes from
+(`ECBlockGroupReader._recover_batches_once`: a degraded GET, a repair,
+a storm's stream). A lease that finds nothing on its class's free list
+maps new anonymous memory, and every page of that is faulted in at its
+first touch, inside whatever span writes it (on the benchmark's host,
+under eight readers, 1.3-2.3 ms of the writing thread's CPU a MiB on top
+of the copy itself, and ~0.06 ms a MiB of `munmap` at its end: PERF.md
+section 6, PR 34); a recycled lease holds ANOTHER operation's bytes, so
+whoever leases overwrites every byte it hands on. `pool_leases`,
+`pool_leases_recycled` and `pool_fresh_bytes` (registry `datapath`) say
+how often the pool engages.
+
+How retention was sized: the free lists must hold what a process's
+operations give back between two of them, or the next one maps fresh
+memory again. Eight concurrent degraded GETs of 40 MiB `rs-10-4` keys
+(the benchmark's cell) each cycle a key buffer (40 MiB), a survivor
+batch (40 MiB) and ten unit slabs (4 MiB + framing: the 5 MiB class),
+130 MiB a reader, 1,040 MiB in all and every byte of it free when all
+eight are between GETs: the default budget is 1 GiB (it was 256 MiB,
+which those GETs' slabs alone overran). Classes step four times an
+octave from 16 KiB up (1, 1.25, 1.5, 1.75 x 2^k; powers of two below):
+a 40 MiB key charges the budget 40 MiB, not the 64 of the next power
+of two, and a class wastes a quarter at most. The budget is a ceiling
+on what a process keeps, not a reservation: a process retains no more
+than its own operations once leased at the same time.
+
 Env knobs:
   OZONE_TPU_POOL_MAX_MIB        total bytes the pool *retains* on free
-                                lists (default 256). Leases above the
+                                lists (default 1024). Leases above the
                                 retention budget are released to the OS.
   OZONE_TPU_POOL_MAX_CLASS_MIB  largest size class retained (default
                                 256, sized so a whole-block GET slab —
@@ -60,6 +90,10 @@ _RATIO = METRICS.gauge("copy_ratio")
 _POOL_LEASED = METRICS.gauge("pool_leased_bytes")
 _POOL_FREE = METRICS.gauge("pool_free_bytes")
 _POOL_HIGH = METRICS.gauge("pool_high_water_bytes")
+_POOL_LEASES = METRICS.counter("pool_leases")
+_POOL_RECYCLED = METRICS.counter("pool_leases_recycled")
+#: bytes (of their classes) of the leases that mapped new memory
+_POOL_FRESH = METRICS.counter("pool_fresh_bytes")
 
 _logged_sites: set[str] = set()
 _logged_lock = threading.Lock()
@@ -124,16 +158,20 @@ class Lease:
 
     The creator holds one reference; ``array()`` views take another
     each (dropped via weakref.finalize when the ndarray dies), so the
-    backing buffer is recycled only after the last view is gone."""
+    backing buffer is recycled only after the last view is gone.
+    `fresh` says the lease mapped new memory (untouched, zero pages);
+    one that did not holds whatever its last holder wrote."""
 
-    __slots__ = ("_pool", "_mm", "cap", "size", "_refs", "__weakref__")
+    __slots__ = ("_pool", "_mm", "cap", "size", "fresh", "_refs",
+                 "__weakref__")
 
     def __init__(self, pool: "HostBufferPool", mm: mmap.mmap,
-                 cap: int, size: int):
+                 cap: int, size: int, fresh: bool):
         self._pool = pool
         self._mm = mm
         self.cap = cap
         self.size = size
+        self.fresh = fresh
         self._refs = 1
 
     @property
@@ -179,8 +217,9 @@ class Lease:
 class HostBufferPool:
     """Size-classed free lists of page-aligned mmap buffers.
 
-    Classes are powers of two from `min_class` up; a lease takes the
-    smallest class that fits. Released buffers are retained up to
+    Classes are powers of two from `min_class` up and, from four
+    pages a step, four to an octave; a lease takes the smallest class
+    that fits. Released buffers are retained up to
     `max_retained` total bytes (and only for classes up to
     `max_class`); beyond that they are unmapped, so a burst does not
     permanently inflate the process."""
@@ -196,7 +235,7 @@ class HostBufferPool:
             "OZONE_TPU_POOL_MAX_CLASS_MIB", 256) * (1 << 20)
         self.max_retained = (max_retained if max_retained is not None
                              else _env_int("OZONE_TPU_POOL_MAX_MIB",
-                                           256) * (1 << 20))
+                                           1024) * (1 << 20))
         self._free: dict[int, list[mmap.mmap]] = {}
         self.leased_bytes = 0
         self.leased_count = 0
@@ -207,6 +246,14 @@ class HostBufferPool:
         cap = self.min_class
         while cap < n:
             cap <<= 1
+        # between two powers of two, three classes more wherever a step
+        # is whole pages: a buffer just over 2^k charges the retention
+        # budget a quarter more, not twice
+        half, step = cap >> 1, cap >> 3
+        if half >= self.min_class and step % mmap.PAGESIZE == 0:
+            for c in (half + step, half + 2 * step, half + 3 * step):
+                if c >= n:
+                    return c
         return cap
 
     def lease(self, n: int) -> Lease:
@@ -219,7 +266,8 @@ class HostBufferPool:
             if lst:
                 mm = lst.pop()
                 self.free_bytes -= cap
-        if mm is None:
+        fresh = mm is None
+        if fresh:
             mm = mmap.mmap(-1, cap)  # anonymous => page-aligned
         with self._lock:
             self.leased_bytes += cap
@@ -227,7 +275,24 @@ class HostBufferPool:
             self.high_water_bytes = max(self.high_water_bytes,
                                         self.leased_bytes)
             self._publish_locked()
-        return Lease(self, mm, cap, n)
+        _POOL_LEASES.inc()
+        if fresh:
+            _POOL_FRESH.inc(cap)
+        else:
+            _POOL_RECYCLED.inc()
+        return Lease(self, mm, cap, n, fresh)
+
+    def lease_array(self, n: int) -> tuple[np.ndarray, bool]:
+        """`n` UNINITIALISED bytes as a flat uint8 array that alone pins
+        its lease (the pages go back to the free list when it and every
+        view of it are dead), and whether the lease is fresh. The
+        per-operation buffers take this door: unless fresh the array
+        holds another operation's bytes, so the caller writes every
+        byte before it hands any on."""
+        if n == 0:
+            return np.empty(0, dtype=np.uint8), False
+        with self.lease(n) as lease:
+            return lease.array(), lease.fresh
 
     def _recycle(self, mm: mmap.mmap, cap: int) -> None:
         retain = False
@@ -285,7 +350,8 @@ _pool_lock = threading.Lock()
 
 def pool() -> HostBufferPool:
     """The process-wide pool (client recv slabs, stream relays, the
-    codec service's staging batches)."""
+    codec service's staging batches, an EC read's key buffer and
+    survivor batches)."""
     global _pool
     with _pool_lock:
         if _pool is None:

@@ -408,9 +408,9 @@ class CodecService:
             return free.pop()
         # the array pins its lease: the pages go back to the pool when
         # the service drops the buffer
-        with hostmem.pool().lease(
-                lane.dtype.itemsize * math.prod(shape)) as lease:
-            return lease.array().view(lane.dtype).reshape(shape)
+        flat, _fresh = hostmem.pool().lease_array(
+            lane.dtype.itemsize * math.prod(shape))
+        return flat.view(lane.dtype).reshape(shape)
 
     def _give_staging_locked(self, batch: _Batch) -> None:
         if batch.borrowed:

@@ -17,6 +17,8 @@ registry in codec/hostmem.py:
 from __future__ import annotations
 
 import gc
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -90,7 +92,13 @@ class _CopyMeter:
 
 def _drain_leases():
     """Drop lingering array views so their weakref finalizers return
-    the backing leases to the pool."""
+    the backing leases to the pool, once no EC reader thread that an
+    earlier test of this process orphaned (a straggler still asleep in
+    its read) is left to give its survivor batch back meanwhile."""
+    t_end = time.monotonic() + 30
+    while any(t.name.startswith("ec-read") for t in threading.enumerate()) \
+            and time.monotonic() < t_end:
+        time.sleep(0.05)
     gc.collect()
 
 
@@ -139,6 +147,7 @@ def test_pooled_reuse_byte_exact_under_chaos(cluster):
     dn, client = cluster
     rng = np.random.default_rng(3)
     cs = Checksum(ChecksumType.CRC32C, 16 * 1024)
+    _drain_leases()
     base = hostmem.pool().stats()
     try:
         for i in range(40):
