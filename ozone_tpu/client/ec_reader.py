@@ -103,6 +103,43 @@ class _ReadTally:
             self.wire_bytes += sum(i.length for i in infos)
 
 
+class RecoveryTally:
+    """What one recover_cells_iter planned and read: the `kind`, `width`
+    and `widened` tags of the `ec:read` and `repair:block` spans and
+    the repair counters of `ec.reconstruction`. A plan is `rs` (any k
+    survivors), or LRC's `local` (the lost units' groups alone) or
+    `global`. Reads are counted as asked, an abandoned attempt's too;
+    the unit reads run on pool threads."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.kind = ""   # the FIRST plan's kind
+        self.width = 0   # units in the read set of the plan that ended it
+        #: why a plan that began `local` ended reading outside its
+        #: groups: "hedge" (a survivor straggled past its hedge delay
+        #: and was dropped) or "unit_failed"; "" where it did not
+        self.widened = ""
+        self.units: set[int] = set()  # units a payload read was asked of
+        self.bytes = 0
+
+    def plan(self, kind: str, width: int, cause: str) -> None:
+        self.kind = self.kind or kind
+        self.width = width
+        if self.kind == "local" and kind != "local":
+            self.widened = self.widened or cause
+
+    def asked(self, u: int, infos: Sequence[ChunkInfo]) -> None:
+        with self._lock:
+            self.units.add(u)
+            self.bytes += sum(i.length for i in infos)
+
+    def tags(self) -> dict:
+        out = {"kind": self.kind, "width": self.width}
+        if self.widened:
+            out["widened"] = self.widened
+        return out
+
+
 class ECBlockGroupReader:
     def __init__(
         self,
@@ -163,6 +200,10 @@ class ECBlockGroupReader:
         #: the running read()'s tally; None outside one (repair's
         #: recover_cells_iter books nothing under the GET's names)
         self._tally: Optional[_ReadTally] = None
+        #: the last recover_cells_iter's plan and reads (a repair's
+        #: caller and read() tag their spans from it); None before one
+        self.recovery: Optional[RecoveryTally] = None
+        self._replan_cause = ""  # why the next plan is not the first
         #: the class its decode batches queue in: they coalesce with
         #: other operations sharing the erasure pattern (reconstruction
         #: storms, fleets of degraded readers)
@@ -236,7 +277,7 @@ class ECBlockGroupReader:
             # cell has no data (short final stripe)
             return np.zeros(self.cell, dtype=np.uint8)
         dn_id = self.group.pipeline.nodes[u]
-        self._asked([info])
+        self._asked(u, [info])
         with Tracer.instance().span("net:read_chunk", dn=dn_id,
                                     unit=u, stripe=stripe):
             data = self._health.observe(
@@ -244,10 +285,12 @@ class ECBlockGroupReader:
                 self.group.block_id, info, verify=self.verify)
         return self._cell_array(data)
 
-    def _asked(self, infos: Sequence[ChunkInfo]) -> None:
-        tally = self._tally
+    def _asked(self, u: int, infos: Sequence[ChunkInfo]) -> None:
+        tally, recovery = self._tally, self.recovery
         if tally is not None:
             tally.asked(infos)
+        if recovery is not None:
+            recovery.asked(u, infos)
 
     def _cell_array(self, data: np.ndarray) -> np.ndarray:
         """Full cells pass through as zero-copy views over the wire
@@ -288,7 +331,7 @@ class ECBlockGroupReader:
             fn = getattr(client, "read_chunks", None)
             if fn is None:
                 return
-            self._asked([i for _, i in wanted])
+            self._asked(u, [i for _, i in wanted])
             with Tracer.instance().span("net:read_chunks", dn=dn_id,
                                         unit=u, cells=len(wanted)):
                 datas = self._health.observe(
@@ -640,6 +683,11 @@ class ECBlockGroupReader:
 
     # ------------------------------------------------------------- degraded
     def _choose_valid(self, erased: Sequence[int]) -> list[int]:
+        return self._plan_read(erased)[0]
+
+    def _plan_read(self, erased: Sequence[int]) -> tuple[list[int], str]:
+        """(read set, kind of plan): `rs`, or LRC's `local` / `global`
+        (`RecoveryTally`)."""
         avail = [u for u in self.available_units() if u not in erased]
         nodes = self.group.pipeline.nodes
         if self.spec.options.codec == "lrc":
@@ -659,11 +707,10 @@ class ECBlockGroupReader:
             if usable:
                 pref.sort(key=lambda u: u not in usable)  # stable
             try:
-                valid, _kind = lrc_math.plan_valid(
+                return lrc_math.plan_valid(
                     self.spec.options, list(erased), avail, prefer=pref)
             except ValueError as e:
                 raise InsufficientLocationsError(str(e)) from None
-            return valid
         if len(avail) < self.k:
             raise InsufficientLocationsError(
                 f"need {self.k} units, reachable: {avail}, erased: {list(erased)}"
@@ -698,7 +745,7 @@ class ECBlockGroupReader:
                          [nodes[u] for u in avail]))}
             avail.sort(key=lambda u: order.get(nodes[u], len(order)))
             avail = sorted(avail[: self.k])
-        return avail[: self.k]
+        return avail[: self.k], "rs"
 
     def recover_cells(
         self, targets: Sequence[int], stripes: Optional[Sequence[int]] = None,
@@ -757,6 +804,11 @@ class ECBlockGroupReader:
         # refresh per call: a reader reused across operations must not
         # re-activate a PREVIOUS operation's (possibly expired) budget
         self._deadline = resilience.current()
+        if self._tally is None or self.recovery is None:
+            # one tally a repair; a read() keeps its own over the
+            # retries that replan it
+            self.recovery = RecoveryTally()
+            self._replan_cause = ""
         try:
             # p hard failures plus straggler hedges can both consume
             # attempts; hedges are cheap (detected in one hedge window)
@@ -773,7 +825,9 @@ class ECBlockGroupReader:
                         e.cause,
                     )
                     self._failed.add(e.unit)
+                    self._replan_cause = "unit_failed"
                 except _StragglerHedge as e:
+                    self._replan_cause = "hedge"
                     # not a failure: the slow survivors are dropped and
                     # the decode replans around spares; their abandoned
                     # reads resolve (and are discarded) in the background.
@@ -808,7 +862,8 @@ class ECBlockGroupReader:
         persistent decode-plan cache."""
         stripes = list(
             stripes if stripes is not None else range(self.num_stripes))
-        valid = self._choose_valid(list(targets))
+        valid, kind = self._plan_read(list(targets))
+        self.recovery.plan(kind, len(valid), self._replan_cause)
         pipe = self._decode_pipe(valid, list(targets))
         pool = self._ensure_pool()
         for sb in batched(stripes, self._decode_batch):
@@ -926,6 +981,7 @@ class ECBlockGroupReader:
         self._deadline = resilience.current()
         tally = self._tally = _ReadTally()
         tally.fresh_bytes = length if out_fresh else 0
+        self.recovery = None
         with Tracer.instance().span("ec:read", offset=offset,
                                     bytes=length) as sp:
             try:
@@ -935,6 +991,8 @@ class ECBlockGroupReader:
                 sp.tags.update(cells_reused=tally.cells_reused,
                                cells_fetched=tally.cells_fetched,
                                fresh_bytes=tally.fresh_bytes)
+                if self.recovery is not None:  # a degraded read
+                    sp.tags.update(self.recovery.tags())
                 OPS.counter("get_cells_reused").inc(tally.cells_reused)
                 OPS.counter("get_cells_fetched").inc(tally.cells_fetched)
                 OPS.counter("get_wire_bytes").inc(tally.wire_bytes)
@@ -957,6 +1015,7 @@ class ECBlockGroupReader:
                         e.unit, e.cause
                     )
                     self._failed.add(e.unit)
+                    self._replan_cause = "unit_failed"
                 except _StragglerHedge:  # ozlint: allow[error-swallowing] -- handled by design: units already excluded and counted by the recovery layer
                     # units already excluded + counted by the recovery
                     # layer: the retry reconstructs them (and anything

@@ -653,6 +653,14 @@ class CodecService:
         METRICS.counter("stripes_borrowed" if batch.borrowed
                         else "stripes_packed_at_submit").inc(rows)
         METRICS.counter("slots_dispatched").inc(lane.width)
+        key = lane.lane_key[0]
+        if isinstance(key, tuple) and len(key) == 4 and key[0] == "decode":
+            # useful work of a decode dispatch (`decode_key`): cells
+            # read, at the width the plan really read (k for RS, a
+            # group's survivors for an LRC local repair), and rebuilt
+            METRICS.counter("decode_survivor_cells").inc(rows * len(key[2]))
+            METRICS.counter("decode_recovered_cells").inc(
+                rows * len(key[3]))
         METRICS.counter("coalesced_operations").inc(ops)
         if ops > 1:
             METRICS.counter("multi_op_dispatches").inc()

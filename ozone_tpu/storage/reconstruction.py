@@ -226,6 +226,24 @@ class ECReconstructionCoordinator:
                                     block=bd.block_id.local_id):
             return self._reconstruct_block_traced(cmd, bd, targets)
 
+    def _book_reads(self, plan) -> None:
+        """What the block's recovery planned and read: the `kind` /
+        `width` / `widened` of its `repair:block` span (the current
+        one) and this registry's counters. A
+        repair is `local` where it read its lost units' groups alone,
+        `widened` where it set out to and ended reading outside them,
+        `global` otherwise (Reed-Solomon's are all that)."""
+        if plan is None or not plan.kind:
+            return  # nothing to rebuild: the reader planned no read
+        Tracer.instance().current().tags.update(
+            plan.tags(), units_read=len(plan.units))
+        self.metrics.counter(
+            "repairs_widened" if plan.widened
+            else "repairs_local" if plan.kind == "local"
+            else "repairs_global").inc()
+        self.metrics.counter("survivor_units_read").inc(len(plan.units))
+        self.metrics.counter("survivor_bytes_read").inc(plan.bytes)
+
     def _reconstruct_block_traced(
         self, cmd: ReconstructionCommand, bd: BlockData, targets: list[int]
     ) -> int:
@@ -274,6 +292,7 @@ class ECReconstructionCoordinator:
                         write_unit_stream(
                             self.clients.get(cmd.targets[idx]),
                             group.block_id, pairs)
+        self._book_reads(reader.recovery)
 
         rebuilt = 0
         for ti, idx in enumerate(targets):

@@ -225,6 +225,14 @@ def test_local_repair_reads_exactly_group_size_units(tmp_path):
     parity — never the k=12 an RS repair would read."""
     opts = CoderOptions(12, 4, "lrc", cell_size=CELL, local_groups=2)
     cluster = MiniEC(tmp_path, n_dn=17, opts=opts)
+    # what is held here is the PLANNER's read set on a healthy group. A
+    # survivor read that a loaded host delays past the straggler limit
+    # (3 x 50 ms for these two stripes) is dropped for the nine units
+    # outside the group by design, and the test then read 13 nodes (seen
+    # under the tier-1 command, KNOWN_ISSUES.md): a limit no host load
+    # reaches keeps that rule out of this statement. The rule itself is
+    # held by test_a_local_plan_that_widens_says_so_and_why.
+    cluster.clients.health.hedge_floor_s = 60.0
     try:
         rng = np.random.default_rng(11)
         data = rng.integers(0, 256, 12 * 2 * CELL, dtype=np.uint8)
@@ -251,6 +259,142 @@ def test_local_repair_reads_exactly_group_size_units(tmp_path):
         cluster.close()
 
 
+def _repair(cluster, opts, g, unit, target):
+    """Wipe `unit`'s replica and rebuild it onto `target`; returns the
+    coordinator and the repair's `repair:block` span."""
+    from ozone_tpu.storage.reconstruction import (
+        ECReconstructionCoordinator,
+        ReconstructionCommand,
+    )
+    from ozone_tpu.utils.tracing import Tracer
+
+    dn = next(d for d in cluster.dns if d.id == g.pipeline.nodes[unit])
+    dn.delete_container(g.container_id, force=True)
+    coord = ECReconstructionCoordinator(cluster.clients,
+                                        bytes_per_checksum=1024)
+    seen = len(Tracer.instance().traces())
+    coord.reconstruct_container_group(ReconstructionCommand(
+        g.container_id, opts,
+        sources={u + 1: g.pipeline.nodes[u] for u in range(opts.all_units)
+                 if u != unit},
+        targets={unit + 1: target}))
+    span, = [s for s in Tracer.instance().traces()[seen:]
+             if s.name == "repair:block"]
+    return coord, span
+
+
+def test_repair_spans_and_counters_say_what_was_planned_and_read(tmp_path):
+    """ISSUE 35: a repair's `repair:block` span carries `kind` and
+    `width`, `ec.reconstruction` counts local and global repairs and the
+    survivor units and bytes they read, and `codec.service` counts the
+    cells each decode dispatch read and rebuilt, at the width it really
+    decoded (6 for a local repair, 12 for a global parity)."""
+    from ozone_tpu.codec import service as codec_service
+
+    opts = CoderOptions(12, 4, "lrc", cell_size=CELL, local_groups=2)
+    cluster = MiniEC(tmp_path, n_dn=17, opts=opts)
+    cluster.clients.health.hedge_floor_s = 60.0
+    try:
+        rng = np.random.default_rng(35)
+        data = rng.integers(0, 256, 12 * 3 * CELL, dtype=np.uint8)
+        g = _write_key(cluster, data)[0]
+        cells = codec_service.METRICS.counter("decode_survivor_cells")
+        rebuilt = codec_service.METRICS.counter("decode_recovered_cells")
+        c0, r0 = cells.value, rebuilt.value
+        coord, span = _repair(cluster, opts, g, 2, "dn16")
+        assert (span.tags["kind"], span.tags["width"],
+                span.tags["units_read"]) == ("local", 6, 6)
+        assert "widened" not in span.tags
+        m = coord.metrics.snapshot()
+        assert (m["repairs_local"], m["survivor_units_read"]) == (1, 6)
+        assert "repairs_global" not in m and "repairs_widened" not in m
+        assert m["survivor_bytes_read"] == 6 * 3 * CELL
+        assert m["bytes_reconstructed"] == 3 * CELL
+        assert (cells.value - c0, rebuilt.value - r0) == (3 * 6, 3)
+        # the rebuilt replica serves the group from its new node
+        g.pipeline.nodes[2] = "dn16"
+        assert np.array_equal(cluster.reader(g).read_all(), data)
+        # a global parity is a 12-wide read of the data units
+        coord, span = _repair(cluster, opts, g, 14, g.pipeline.nodes[14])
+        assert (span.tags["kind"], span.tags["width"],
+                span.tags["units_read"]) == ("global", 12, 12)
+        m = coord.metrics.snapshot()
+        assert (m["repairs_global"], m["survivor_units_read"]) == (1, 12)
+        assert "repairs_local" not in m
+        assert (cells.value - c0, rebuilt.value - r0) == (
+            3 * 6 + 3 * 12, 3 + 3)
+    finally:
+        cluster.close()
+
+
+def test_reed_solomon_repairs_book_the_same_counters(tmp_path):
+    from ozone_tpu.codec import service as codec_service
+
+    opts = CoderOptions(6, 3, "rs", cell_size=CELL)
+    cluster = MiniEC(tmp_path, n_dn=10, opts=opts)
+    try:
+        data = np.random.default_rng(36).integers(
+            0, 256, 6 * 2 * CELL, dtype=np.uint8)
+        g = _write_key(cluster, data)[0]
+        cells = codec_service.METRICS.counter("decode_survivor_cells")
+        c0 = cells.value
+        coord, span = _repair(cluster, opts, g, 7, "dn9")
+        assert (span.tags["kind"], span.tags["width"]) == ("rs", 6)
+        m = coord.metrics.snapshot()
+        assert (m["repairs_global"], m["survivor_units_read"]) == (1, 6)
+        assert cells.value - c0 == 2 * 6
+    finally:
+        cluster.close()
+
+
+@pytest.mark.parametrize("cause", ["unit_failed", "hedge"])
+def test_a_local_plan_that_widens_says_so_and_why(tmp_path, cause):
+    """A local read has no spare inside its group: when one of the six
+    survivors fails mid-read, or straggles past its hedge delay, the
+    plan falls back to the global read set. The repair still rebuilds
+    the unit byte-exact, and its span and counters say that it widened
+    and why."""
+    import time
+
+    opts = CoderOptions(12, 4, "lrc", cell_size=CELL, local_groups=2)
+    cluster = MiniEC(tmp_path, n_dn=17, opts=opts)
+    try:
+        data = np.random.default_rng(37).integers(
+            0, 256, 12 * 2 * CELL, dtype=np.uint8)
+        g = _write_key(cluster, data)[0]
+        victim = cluster.clients._local[g.pipeline.nodes[4]]
+        real = victim.read_chunks
+
+        if cause == "unit_failed":
+            cluster.clients.health.hedge_floor_s = 60.0
+            from ozone_tpu.storage.ids import StorageError
+
+            def broken(*a, **kw):
+                raise StorageError("CHECKSUM_MISMATCH", "planted")
+
+            victim.read_chunks = victim.read_chunk = broken
+        else:
+            cluster.clients.health.hedge_floor_s = 0.02
+
+            def slow(*a, **kw):
+                time.sleep(1.0)  # far past 3 x 20 ms
+                return real(*a, **kw)
+
+            victim.read_chunks = slow
+        coord, span = _repair(cluster, opts, g, 2, "dn16")
+        assert span.tags["kind"] == "local" and span.tags["widened"] == cause
+        assert span.tags["width"] == 12 and span.tags["units_read"] > 6
+        m = coord.metrics.snapshot()
+        assert m["repairs_widened"] == 1 and "repairs_local" not in m
+        g.pipeline.nodes[2] = "dn16"
+        victim.read_chunks = real
+        rec = cluster.reader(g).recover_cells([0])  # reads unit 2's rebuild
+        assert np.array_equal(
+            rec[:, 0, :], data.reshape(2, 12, CELL)[:, 0, :])
+    finally:
+        cluster.close()
+
+
 def test_lrc_degraded_read_byte_exact(tmp_path):
     """Kill a data unit's node: the degraded read path must decode
     through the planner and still return the key byte-exact."""
@@ -271,6 +415,36 @@ def test_lrc_degraded_read_byte_exact(tmp_path):
                 pass
         got = _read_key(cluster, groups)
         assert np.array_equal(got, data)
+    finally:
+        cluster.close()
+
+
+def test_a_degraded_reads_span_says_what_its_recovery_read(tmp_path):
+    """`ec:read` carries the recovery's `kind` and `width` (ISSUE 35): a
+    lone lost data unit is served from its group's six survivors; a
+    read that recovers nothing carries neither."""
+    from ozone_tpu.utils.tracing import Tracer
+
+    opts = CoderOptions(12, 4, "lrc", cell_size=CELL, local_groups=2)
+    cluster = MiniEC(tmp_path, n_dn=17, opts=opts)
+    cluster.clients.health.hedge_floor_s = 60.0
+    try:
+        data = np.random.default_rng(38).integers(
+            0, 256, 12 * 2 * CELL, dtype=np.uint8)
+        g = _write_key(cluster, data)[0]
+
+        def read_span():
+            got = cluster.reader(g).read(0, g.length)
+            assert np.array_equal(got, data)
+            return [s for s in Tracer.instance().traces()
+                    if s.name == "ec:read"][-1]
+
+        assert "kind" not in read_span().tags
+        dn = next(d for d in cluster.dns if d.id == g.pipeline.nodes[8])
+        dn.delete_block(g.block_id)
+        tags = read_span().tags
+        assert (tags["kind"], tags["width"]) == ("local", 6)
+        assert "widened" not in tags
     finally:
         cluster.close()
 
