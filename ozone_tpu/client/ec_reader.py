@@ -25,6 +25,9 @@ from __future__ import annotations
 import logging
 import math
 import threading
+from collections import deque
+from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import wait as fwait
 from typing import Callable, Optional, Sequence
 
 import numpy as np
@@ -53,6 +56,24 @@ log = logging.getLogger(__name__)
 #: the client's per-operation registry (`client/ozone_client.py`): a
 #: read() books what it moved there, beside `get_seconds`
 OPS = registry("client.ops")
+
+
+#: workers of every reader's block-record rounds, kept for the life of
+#: the process: a thread started for a record of a few KB costs more
+#: than the answer it waits for (PERF.md section 6, PR 37)
+_RECORD_WORKERS = 32
+_record_pool: Optional[ThreadPoolExecutor] = None
+_record_pool_lock = threading.Lock()
+
+
+def _record_executor() -> ThreadPoolExecutor:
+    global _record_pool
+    with _record_pool_lock:
+        if _record_pool is None:
+            _record_pool = ThreadPoolExecutor(
+                max_workers=_RECORD_WORKERS,
+                thread_name_prefix="ec-records")
+        return _record_pool
 
 
 class InsufficientLocationsError(Exception):
@@ -220,7 +241,10 @@ class ECBlockGroupReader:
         return -(-self.group.length // (self.k * self.cell))
 
     def _unit_block(self, u: int) -> Optional[BlockData]:
-        """BlockData of unit u (0-based) or None if unreachable/missing."""
+        """BlockData of unit u (0-based) or None if unreachable/missing.
+        The one place that asks a node and reads its answer as "there"
+        or "absent"; on the record pool's threads and the caller's during
+        `_ask_block_records`, a cache hit after it."""
         if u not in self._block_meta:
             dn_id = self.group.pipeline.nodes[u]
             try:
@@ -240,7 +264,78 @@ class ECBlockGroupReader:
                 self._block_meta[u] = None
         return self._block_meta[u]
 
+    def _ask_block_records(self) -> None:
+        """Fill `_block_meta` for every unit not asked yet in ONE round.
+        This thread takes units off one list and asks them in turn, and
+        workers of the process's record pool take from the same list:
+        whoever starts while more is left than it can ask next sends
+        for one more worker, and calls that one off if it has not
+        started by the time its own answer is back. Where a worker
+        starts at once (a lone repair) every record is in flight within
+        a millisecond or two and the round costs what the answers cost
+        side by side, not the walk's sum (15 records before an LRC
+        repair reads six). Where a hand-off takes longer than an answer
+        (many readers in one process, all waiting for the interpreter)
+        every thread woken is a turn at the interpreter the readers
+        lose: a dozen woken at once made the round SLOWER than the walk
+        (PERF.md section 6, PR 37); here this thread asks most itself
+        and few workers ever start. A unit in `_failed` is not asked; a
+        DEADLINE_EXCEEDED ends the operation, the lowest unit's first,
+        once every answer that was sent for is in."""
+        ask = [u for u in range(self.k + self.p)
+               if u not in self._block_meta and u not in self._failed]
+        if len(ask) < 2:
+            return  # none, or one that `_unit_block` asks on this thread
+        todo = deque(ask)
+        workers: list = []
+        spent: dict[int, StorageError] = {}
+        pool = _record_executor()
+
+        def take() -> None:
+            sent_for = None
+            if len(todo) > 1:
+                sent_for = self._submit_act(pool, take)
+                workers.append(sent_for)
+            while True:
+                try:
+                    u = todo.popleft()
+                except IndexError:
+                    return
+                try:
+                    self._unit_block(u)
+                except StorageError as e:  # DEADLINE_EXCEEDED alone
+                    spent[u] = e
+                if sent_for is not None:
+                    # not started in the time an answer took: called off
+                    sent_for.cancel()
+                    sent_for = None
+
+        with Tracer.instance().span("net:get_blocks",
+                                    records_asked=len(ask)) as sp:
+            try:
+                take()
+            finally:
+                # the list grows under the loop, which meets what is
+                # appended: a worker's own call for help is in it before
+                # that worker ends. One that has not started has nothing
+                # left to take and is called off: no wait for a hand-off
+                for f in workers:
+                    if not f.cancel():
+                        fwait([f])
+                sp.tags["records_present"] = sum(
+                    self._block_meta.get(u) is not None for u in ask)
+            for f in workers:
+                if not f.cancelled():
+                    f.result()
+            if spent:
+                raise spent[min(spent)]
+        OPS.counter("block_record_rounds").inc()
+        OPS.counter("block_records_asked").inc(len(ask))
+
     def available_units(self) -> list[int]:
+        """The units that are there, by their block records: asked for
+        in one round the first time, answered from `_block_meta` after."""
+        self._ask_block_records()
         return [
             u
             for u in range(self.k + self.p)
@@ -501,8 +596,6 @@ class ECBlockGroupReader:
         depth = max(len(ss) for ss in needed.values())
         delay = max(1, depth) * max(
             self._health.hedge_delay_s(nodes[u]) for u in needed)
-        from concurrent.futures import wait as fwait
-
         _done, pending = fwait(set(futs),
                                timeout=resilience.op_timeout(
                                    delay, "prefetch"))
@@ -511,8 +604,6 @@ class ECBlockGroupReader:
 
     def _ensure_pool(self):
         if self._read_pool is None:
-            from concurrent.futures import ThreadPoolExecutor
-
             self._read_pool = ThreadPoolExecutor(
                 max_workers=self.k, thread_name_prefix="ec-read")
         return self._read_pool
@@ -643,8 +734,6 @@ class ECBlockGroupReader:
 
     def _fanout_traced(self, pool, fill_unit, valid: list[int],
                        depth: int) -> None:
-        from concurrent.futures import wait as fwait
-
         nodes = self.group.pipeline.nodes
         futs = {self._submit_act(pool, fill_unit, (vi, u)): u
                 for vi, u in enumerate(valid)}

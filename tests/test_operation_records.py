@@ -92,6 +92,37 @@ def test_durations_come_from_the_monotonic_clock(t, monkeypatch):
     assert abs(rec["end"] - time.monotonic()) < 1.0
 
 
+def test_a_rounds_side_by_side_children_are_swept_once():
+    """A block-record round's `net:get_block` spans run side by side
+    (the caller's and the record pool's threads): the critical path
+    gives every instant of the round to one of them or to the round
+    itself, never to two."""
+    ms = 1e-3
+
+    def span(i, parent, name, start_ms, dur_ms):
+        return {"spanId": i, "parentId": parent, "name": name,
+                "start": 100.0 + start_ms * ms, "durationMs": dur_ms}
+
+    path = tracing.critical_path([
+        span("r", "", "client:get", 0, 50),
+        span("l", "r", "net:get_blocks", 5, 21),
+        # six answers side by side, begun 0.5 ms apart from 6.0, ended
+        # 1.5 ms apart from 18.0: the slowest is out at 25.5
+        *[span(f"g{u}", "l", "net:get_block", 6 + u / 2, 12 + u)
+          for u in range(6)],
+        span("x", "g5", "client:/ozone.tpu.DatanodeService/GetBlock",
+             10, 15),
+        span("f", "r", "ec:fanout", 30, 10)])
+    got = {p["stage"]: p["micros"] for p in path}
+    assert sum(got.values()) == 50_000
+    # first started, first swept: g0 has 6.0 -> 18.0, each later one what
+    # is left of it after the one before (g5: 24.0 -> 25.5, 1.0 of that
+    # under its RPC); the round keeps 1.0 before them and 0.5 after
+    assert got == {"client:get": 19_000, "net:get_blocks": 1_500,
+                   "net:get_block": 18_500, "ec:fanout": 10_000,
+                   "client:/ozone.tpu.DatanodeService/GetBlock": 1_000}
+
+
 def _planted(rec: FlightRecorder, name: str, start: float, seconds: float):
     root = Span("t" + name + str(start), "s", "", name, 0.0, seconds,
                 mono=start, op=True)
@@ -249,8 +280,15 @@ def test_repair_spans_arrive_under_the_root_from_the_recon_pool(
         "repair:close", "repair:prepare"]
     blocks = {s.span_id for s in spans if s.name == "repair:block"}
     under_blocks = {s.name for s in spans if s.parent_id in blocks}
-    assert {"repair:write", "ec:fanout", "net:get_block",
+    assert {"repair:write", "ec:fanout", "net:get_blocks",
             f"{queue}:queue_wait", dispatch_span} <= under_blocks
+    # the block records are asked for in one round a block: five units
+    # sought, the lost one's node is nobody to ask
+    rounds = {s.span_id: s for s in spans if s.name == "net:get_blocks"}
+    assert [(s.tags["records_asked"], s.tags["records_present"])
+            for s in rounds.values()] == [(5, 4)] * 3
+    asked = [s.parent_id for s in spans if s.name == "net:get_block"]
+    assert len(asked) == 15 and set(asked) == set(rounds)
     # the survivor reads run on the reader's own pool, under the fan-in
     fanouts = {s.span_id for s in spans if s.name == "ec:fanout"}
     assert {s.parent_id for s in spans
