@@ -546,13 +546,23 @@ class ECBlockGroupReader:
         def take_survivors(sb, valid, batch) -> None:
             data = [(vi, u) for vi, u in enumerate(valid) if u < self.k]
             copied = 0
-            for bi, s in enumerate(sb):
-                for vi, u in data:
-                    n = self._put_cell(out, offset, length, u, s,
-                                       batch[bi, vi])
-                    copied += n
-                    tally.cells_reused += bool(n)
-                placed[s] = {u for _, u in data}
+            # a leaf that only copies memory, one a pass over a batch
+            # (this one and the decoded cells' below), never one a
+            # cell, in a costed operation alone: its wall less its CPU
+            # is time this thread was runnable and not running
+            # (PERF.md section 3)
+            with Tracer.instance().cost_leaf(
+                    "ec:assemble", part="survivors",
+                    cells=len(sb) * len(data)) as sp:
+                for bi, s in enumerate(sb):
+                    for vi, u in data:
+                        n = self._put_cell(out, offset, length, u, s,
+                                           batch[bi, vi])
+                        copied += n
+                        tally.cells_reused += bool(n)
+                    placed[s] = {u for _, u in data}
+                if sp is not None:
+                    sp.tags["bytes"] = copied
             self._count_copy(copied, "reuse_survivor")
 
         # exclude_stragglers=False: a straggling survivor propagates to
@@ -563,11 +573,16 @@ class ECBlockGroupReader:
                 targets, stripes, exclude_stragglers=False,
                 on_survivors=take_survivors):
             copied = 0
-            for bi, s in enumerate(sb):
-                for ti, u in enumerate(targets):
-                    copied += self._put_cell(out, offset, length, u, s,
-                                             rec[bi, ti])
-                placed[s].update(targets)
+            with Tracer.instance().cost_leaf(
+                    "ec:assemble", part="decoded",
+                    cells=len(sb) * len(targets)) as sp:
+                for bi, s in enumerate(sb):
+                    for ti, u in enumerate(targets):
+                        copied += self._put_cell(out, offset, length, u, s,
+                                                 rec[bi, ti])
+                    placed[s].update(targets)
+                if sp is not None:
+                    sp.tags["bytes"] = copied
             self._count_copy(copied, "recovered_cell")
         return placed
 
@@ -621,7 +636,7 @@ class ECBlockGroupReader:
         re-activated on the worker (neither contextvars nor the
         thread-local span stack cross executor threads)."""
         d = self._deadline
-        ctx = Tracer.instance().inject()
+        ctx = Tracer.instance().handoff()
 
         def run():
             with resilience.activate(d), Tracer.instance().activate(ctx):
@@ -727,7 +742,8 @@ class ECBlockGroupReader:
         # the fan-in as a stage of its own: what the unit reads
         # (net:read_chunks, on the pool) do not cover — each cell's
         # copy into the decode batch, the pool's hand-offs — is the
-        # fan-in's own time, not the caller's
+        # fan-in's own time, not the caller's (a costed operation's
+        # `cost` has the copies apart, as `ec:fill`)
         with Tracer.instance().span("ec:fanout", units=len(valid),
                                     stripes=depth):
             self._fanout_traced(pool, fill_unit, valid, depth)
@@ -976,11 +992,17 @@ class ECBlockGroupReader:
                 # batch first; cells it couldn't serve fall back to
                 # per-chunk reads
                 self._prefetch_unit(u, sb)
-                for bi, s in enumerate(sb):
-                    # EVERY [bi, vi] is assigned a whole cell: an absent
-                    # or short one comes zero-padded (`_fetch_cell`,
-                    # `_cell_array`), so no recycled byte stays
-                    batch[bi, vi] = self._read_cell_checked(u, s)
+                # the unit's cells into the decode batch: one leaf a
+                # unit stream (a cell the batched read could not serve
+                # is a `net:read_chunk` child, not this span's self)
+                with Tracer.instance().cost_leaf("ec:fill", unit=u,
+                                                 cells=len(sb)):
+                    for bi, s in enumerate(sb):
+                        # EVERY [bi, vi] is assigned a whole cell: an
+                        # absent or short one comes zero-padded
+                        # (`_fetch_cell`, `_cell_array`), so no recycled
+                        # byte stays
+                        batch[bi, vi] = self._read_cell_checked(u, s)
 
             # one reader thread per survivor unit: the k unit streams
             # come off k DIFFERENT datanodes, so the read fan-in costs

@@ -724,7 +724,7 @@ class ECKeyWriter:
         context are ambient on the worker thread (RPC timeouts derive
         from the deadline; per-hop spans join the operation's trace)."""
         d = self._deadline
-        ctx = Tracer.instance().inject()
+        ctx = Tracer.instance().handoff()
         if d is None and not ctx:
             return fn
 

@@ -619,13 +619,14 @@ class HedgeGroup:
         fired = 0
         errors: list[BaseException] = []
 
-        ctx = Tracer.instance().inject()
-
         def fire(fn: Callable[[], object], idx: int) -> None:
             if idx > 0:
                 self.metrics.counter("hedges_fired").inc()
                 Tracer.instance().event("hedge_fired", idx=idx)
-            futs[ex.submit(self._wrap(fn, deadline, ctx))] = idx
+            # the context is made as the branch is handed over: its
+            # hand-off's wait then leaves the hedge delay out
+            futs[ex.submit(self._wrap(
+                fn, deadline, Tracer.instance().handoff()))] = idx
 
         fire(primary, 0)
         while True:

@@ -678,13 +678,21 @@ class CodecService:
 
     def _complete(self, rec: tuple) -> None:
         entries, outs, t0, t0_wall, dctx, batch = rec
-        d_tid, d_sid, fill_pct, reason, lane_desc, ops, rows, width = dctx
         try:
             with Stage("codec:d2h", METRICS.histogram("d2h_seconds")):
                 host = tuple(np.asarray(a) for a in outs)
         except BaseException as e:  # noqa: BLE001 - D2H fault
             _resolve_error(entries, e)
             host = None
+        # everything after the pull, until the last rider's future is
+        # resolved: the staging buffer back, the riders' dispatch spans,
+        # their slices, the joins of split submissions
+        with Stage("codec:complete", METRICS.histogram("complete_seconds")):
+            self._hand_back(rec, host)
+
+    def _hand_back(self, rec: tuple, host: Optional[tuple]) -> None:
+        entries, _outs, t0, t0_wall, dctx, batch = rec
+        d_tid, d_sid, fill_pct, reason, lane_desc, ops, rows, width = dctx
         # the outputs are host arrays (or lost): the launch's asynchronous
         # H2D is over and the staging buffer can be refilled. Not one
         # whose memory an output still shows (a `fn` that hands its input

@@ -103,8 +103,17 @@ class _GenericHandler(grpc.GenericRpcHandler):
                 with self._admit(method_name), Tracer.instance().span(
                     f"server:{method_name}",
                     child_of=remote_ctx or None,
-                ):
-                    return fn(request)
+                ) as sp:
+                    out = fn(request)
+                if sp.thread:
+                    # a costed trace: how long this daemon had the call
+                    # (the caller's span subtracts it from what it saw:
+                    # the wire, gRPC's threads, its own turns at the
+                    # interpreter) and what the handler's thread spent
+                    context.set_trailing_metadata((
+                        ("x-server-us", str(int(sp.duration * 1e6))),
+                        ("x-server-cpu-us", str(int(sp.cpu * 1e6)))))
+                return out
             except StorageError as e:
                 context.abort(
                     grpc.StatusCode.ABORTED,
@@ -230,6 +239,31 @@ class RpcServer:
         self._server.stop(grace).wait(timeout=(grace or 0) + 5)
 
 
+def _call_traced(fn, key: str, address: str, request,
+                 timeout: Optional[float]) -> bytes:
+    """`fn(request)` under its `client:<key>` span, the context sent
+    along. In a costed trace the span is tagged with the daemon's own
+    account of the call (`_GenericHandler._guard`'s trailing metadata):
+    `server_us`, how long the daemon had it, and `server_cpu_us`, what
+    its handler thread spent; a server that sends none leaves no tag."""
+    from ozone_tpu.utils.tracing import Tracer
+
+    tracer = Tracer.instance()
+    with tracer.span(f"client:{key}", address=address) as sp:
+        ctx = tracer.inject()
+        metadata = (("x-trace-id", ctx),) if ctx else None
+        if not sp.thread:
+            return fn(request, timeout=timeout, metadata=metadata)
+        out, call = fn.with_call(request, timeout=timeout,
+                                 metadata=metadata)
+        for k, v in call.trailing_metadata() or ():
+            if k == "x-server-us":
+                sp.tags["server_us"] = int(v)
+            elif k == "x-server-cpu-us":
+                sp.tags["server_cpu_us"] = int(v)
+        return out
+
+
 class RpcChannel:
     """Client side: method callables with raw-bytes serialization.
 
@@ -335,20 +369,15 @@ class RpcChannel:
                        timeout: Optional[float] = 120.0) -> bytes:
         """Client-streaming call: send an iterator of byte frames, get one
         response (the zero-round-trip-per-chunk write path)."""
-        from ozone_tpu.utils.tracing import Tracer
-
         key = f"/{service}/{method}"
         self._check_partition(key, timeout)
         fn = self._calls.get(key)
         if fn is None:
             fn = self._channel.stream_unary(key)
             self._calls[key] = fn
-        tracer = Tracer.instance()
         try:
-            with tracer.span(f"client:{key}", address=self.address):
-                ctx = tracer.inject()
-                metadata = (("x-trace-id", ctx),) if ctx else None
-                return fn(iter(frames), timeout=timeout, metadata=metadata)
+            return _call_traced(fn, key, self.address, iter(frames),
+                                timeout)
         except grpc.RpcError as e:
             raise self._map_rpc_error(key, e) from e
 
@@ -377,8 +406,6 @@ class RpcChannel:
 
     def call(self, service: str, method: str, request: bytes,
              timeout: Optional[float] = 30.0) -> bytes:
-        from ozone_tpu.utils.tracing import Tracer
-
         key = f"/{service}/{method}"
         self._check_partition(key, timeout)
         fn = self._calls.get(key)
@@ -389,11 +416,7 @@ class RpcChannel:
             if not self.traced:
                 out = fn(request, timeout=timeout)
             else:
-                tracer = Tracer.instance()
-                with tracer.span(f"client:{key}", address=self.address):
-                    ctx = tracer.inject()
-                    metadata = (("x-trace-id", ctx),) if ctx else None
-                    out = fn(request, timeout=timeout, metadata=metadata)
+                out = _call_traced(fn, key, self.address, request, timeout)
             self.ever_connected = True
             return out
         except grpc.RpcError as e:

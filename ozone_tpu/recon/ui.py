@@ -609,7 +609,26 @@ async function showTrace(id) {
       `<td>${(100 * s.micros / total).toFixed(1)}%</td></tr>`)
       .join("") ||
      '<tr><td colspan="3">trace no longer retained</td></tr>') +
-    "</tbody></table>";
+    "</tbody></table>" + spanCost(t.spans || []);
+}
+// what the spans cost their threads: a span whose CPU is far under
+// its duration waited (a lock, a future, a socket, the interpreter)
+function spanCost(spans) {
+  const by = {};
+  for (const s of spans) {
+    if (s.cpuMs === undefined) continue;
+    const c = by[s.name] || (by[s.name] = [0, 0, 0, 0]);
+    c[0] += 1; c[1] += s.durationMs; c[2] += s.cpuMs;
+    c[3] += s.blocks || 0;
+  }
+  const rows = Object.entries(by).sort((a, b) => b[1][1] - a[1][1]);
+  if (!rows.length) return "";
+  return '<table><thead><tr><th>span</th><th>n</th><th>ms</th>' +
+    "<th>cpu ms</th><th>blocks</th></tr></thead><tbody>" +
+    rows.map(([n, c]) =>
+      `<tr><td>${esc(n)}</td><td>${c[0]}</td><td>${c[1].toFixed(1)}` +
+      `</td><td>${c[2].toFixed(1)}</td><td>${c[3]}</td></tr>`)
+      .join("") + "</tbody></table>";
 }
 // du drill-down: click rows to descend, the header crumb to reset
 let duPath = "/";

@@ -142,7 +142,7 @@ class ECReconstructionCoordinator:
                 # pool threads start with no span of their own: carry
                 # the deadline and the trace context over, as
                 # ec_writer._act does
-                deadline, ctx = resilience.current(), tracer.inject()
+                deadline, ctx = resilience.current(), tracer.handoff()
 
                 def one(bd: BlockData) -> int:
                     with resilience.activate(deadline), \

@@ -1517,6 +1517,22 @@ def cmd_trace(args) -> int:
             share = 100.0 * st["micros"] / total
             print(f"  {st['stage']:<28} {st['micros']:>12} us  "
                   f"{share:5.1f}%")
+        # what the spans cost their threads (those bracketed on one):
+        # a span whose CPU is far under its duration waited
+        cost: dict[str, list] = {}
+        for sp in entry["spans"]:
+            if "cpuMs" in sp:
+                c = cost.setdefault(sp["name"], [0, 0.0, 0.0, 0])
+                c[0] += 1
+                c[1] += sp["durationMs"]
+                c[2] += sp["cpuMs"]
+                c[3] += sp.get("blocks", 0)
+        if cost:
+            print("cost by span (children included):")
+            for name, (n, ms, cpu, blocks) in sorted(
+                    cost.items(), key=lambda kv: -kv[1][1]):
+                print(f"  {name:<28} x{n:<4} {ms:>10.3f} ms  "
+                      f"cpu {cpu:>9.3f} ms  blocks {blocks}")
         return 0
     finally:
         ch.close()

@@ -15,12 +15,29 @@ attributed inside one process (the per-operation stage record), comes
 from `time.monotonic()`, the clock a load generator's window is on.
 `stage()` brackets a leaf interval of a loop that owns its thread and
 mirrors it to the JAX profiler, the device trace's clock.
+
+Cost: beside how long it took, a span bracketed on one thread says what
+that thread spent between its edges: CPU seconds, the times it went to
+sleep (`blocks`) and the times the kernel took its core away
+(`preempts`), in the operations that are costed (`COST_INTERVAL_S`: at
+most one of a name a second, because the two calls at each edge are
+system calls made with the interpreter lock held). In a costed
+operation a hand-off to a pool worker is counted and timed where the
+worker takes it up (`Tracer.handoff` / `Tracer.activate`), an RPC's
+client span carries how long the daemon had it (net/rpc.py), and the
+copies that had no span get a leaf of their own (`Tracer.cost_leaf`);
+its stage record sums them (`FlightRecorder`). An operation that is not
+costed pays none of this. One sampler thread a process keeps the
+process's CPU seconds, the host's busy share and how long a woken
+thread waits for the interpreter (`samples`).
 """
 
 from __future__ import annotations
 
+import os
 import random
 import re
+import resource
 import sys
 import threading
 import time
@@ -29,7 +46,16 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Optional
 
+from ozone_tpu.utils import metrics as _metrics
+
 _local = threading.local()
+
+#: on /prom: `handoffs`, `handoff_seconds` (of the costed operations: a
+#: sample, not a total), `spans_evicted`, and the sampler's
+#: `process_cpu_seconds`, `threads`, `interpreter_wait_seconds`
+METRICS = _metrics.registry("tracing")
+_HANDOFFS = METRICS.counter("handoffs")
+_HANDOFF_SECONDS = METRICS.histogram("handoff_seconds")
 
 
 @dataclass
@@ -48,6 +74,65 @@ class Span:
     mono: float = 0.0
     #: opened by Tracer.operation(): as a root it leaves a stage record
     op: bool = False
+    #: what ITS thread spent between its edges: CPU seconds (user +
+    #: system), voluntary context switches (it went to sleep: a lock, a
+    #: future, a socket, the interpreter) and involuntary ones (its core
+    #: was taken away). `thread` is that thread's id; 0, and no cost,
+    #: for a span of a trace that is not costed (`costed`), for an
+    #: interval another thread measured (`record_span`: waiting by
+    #: construction) and for a root of `begin_operation` (its thread
+    #: works on several roots in turn). `cost_only` marks a leaf of
+    #: `Tracer.cost_leaf`: in the record's `cost`, never in its `stages`
+    #: (`critical_path` gives its time to its parent). Defaults of the
+    #: class, not fields: only a span of a costed trace sets them, and
+    #: every other span is made and kept as cheaply as before
+    cpu = 0.0
+    blocks = 0
+    preempts = 0
+    thread = 0
+    cost_only = False
+
+
+#: whether this kernel counts a thread's context switches: a sandbox
+#: kernel (gVisor, the chip machines') reports none, and there
+#: `_thread_cost` leaves the call out. Asked once, of the importing
+#: thread after a sleep: a kernel that counts them has counted one
+time.sleep(1e-6)
+_SWITCHES_COUNTED = resource.getrusage(resource.RUSAGE_THREAD).ru_nvcsw > 0
+
+#: an operation is costed (every span of its trace takes its thread's
+#: cost at both edges, its hand-offs and RPCs are booked, its copies
+#: get leaves) if the last costed root of its name began at least this
+#: long ago: the first of a name, then one a second at most, whatever
+#: the operation rate. The two calls at an edge are system calls made
+#: with the interpreter lock held: ~1 us together on Linux, 12-40 us
+#: under a sandbox kernel, where eight readers that paid them at every
+#: span of every GET read 15 % fewer bytes, and a lone repair
+#: coordinator with every second repair costed 5 % (PERF.md section 6,
+#: PR 38)
+COST_INTERVAL_S = 1.0
+
+#: what a costed trace's id ends in: the root decides
+#: (`Tracer._new_trace_id`), and every thread and every daemon the
+#: trace reaches knows without being told. No id of sixteen hex digits,
+#: this tracer's or another's, ends so
+_COSTED = "-c"
+
+
+def costed(trace_id: str) -> bool:
+    """Whether the spans of this trace take their threads' cost."""
+    return trace_id.endswith(_COSTED)
+
+
+def _thread_cost() -> tuple[float, int, int]:
+    """(CPU seconds, voluntary, involuntary context switches) of the
+    calling thread so far. The CPU is `thread_time()`'s: rusage's is
+    scaled from the scheduler's ticks and reads 3 ms for a 0.2 ms spin
+    (tests/test_span_cost.py)."""
+    if not _SWITCHES_COUNTED:
+        return time.thread_time(), 0, 0
+    ru = resource.getrusage(resource.RUSAGE_THREAD)
+    return time.thread_time(), ru.ru_nvcsw, ru.ru_nivcsw
 
 
 #: traces with finished spans whose root has not finished here yet (in
@@ -57,12 +142,19 @@ MAX_OPEN_TRACES = 1024
 MAX_TRACE_SPANS = 4096
 
 
+#: the span ring: the fullest cell's run (a GET cell's, set-up included)
+#: ends at up to 13,600 spans (PERF.md section 7), well under two thirds
+#: of the ring, so a reader of a window's spans (`lrc_local_kept_pct`)
+#: sees all of them; `tracing/spans_evicted` counts what was pushed out
+MAX_SPANS = 32_768
+
+
 class Tracer:
     """Process-wide tracer with a bounded span buffer."""
 
     _instance: Optional["Tracer"] = None
 
-    def __init__(self, max_spans: int = 10_000):
+    def __init__(self, max_spans: int = MAX_SPANS):
         self.spans: deque[Span] = deque(maxlen=max_spans)
         self._lock = threading.Lock()
         #: filled by an attached SpanExporter; None = local-only mode
@@ -73,16 +165,32 @@ class Tracer:
         #: trace id -> its finished spans, handed to the recorder when
         #: the root finishes: a root never scans the span ring
         self._open: "OrderedDict[str, list[Span]]" = OrderedDict()
+        #: trace id -> the hand-offs booked against it so far, (pool,
+        #: seconds waited) each; the root takes them with it
+        self._handed: "OrderedDict[str, list[tuple]]" = OrderedDict()
+        #: root name -> when its last costed root began (monotonic)
+        self._costed_at: dict[str, float] = {}
 
     @classmethod
     def instance(cls) -> "Tracer":
         if cls._instance is None:
             cls._instance = cls()
+            ProcessSampler.ensure_started()
         return cls._instance
 
     @staticmethod
     def _new_id() -> str:
         return f"{random.getrandbits(64):016x}"
+
+    def _new_trace_id(self, root: str) -> str:
+        """An id for a trace that starts here, marked if the trace is
+        costed (`costed`): the first root of a name, and then one every
+        COST_INTERVAL_S at most."""
+        now = time.monotonic()
+        if now - self._costed_at.get(root, float("-inf")) < COST_INTERVAL_S:
+            return self._new_id()
+        self._costed_at[root] = now
+        return self._new_id() + _COSTED
 
     def current(self) -> Optional[Span]:
         return getattr(_local, "span", None)
@@ -108,17 +216,39 @@ class Tracer:
         elif parent is not None:
             trace_id, parent_id = parent.trace_id, parent.span_id
         else:
-            trace_id, parent_id = self._new_id(), ""
+            trace_id, parent_id = self._new_trace_id(name), ""
         s = Span(trace_id, self._new_id(), parent_id, name, time.time(),
                  tags=dict(tags), mono=time.monotonic())
-        prev = self.current()
         _local.span = s
+        if trace_id.endswith(_COSTED):
+            s.thread = threading.get_ident()
+            cpu0, blocks0, preempts0 = _thread_cost()
         try:
             yield s
         finally:
+            if s.thread:
+                cpu1, blocks1, preempts1 = _thread_cost()
+                s.cpu = cpu1 - cpu0
+                s.blocks = blocks1 - blocks0
+                s.preempts = preempts1 - preempts0
             s.duration = time.monotonic() - s.mono
-            _local.span = prev
+            _local.span = parent
             self._finish(s)
+
+    @contextmanager
+    def cost_leaf(self, name: str, **tags):
+        """A leaf span around work that only copies memory, in a costed
+        trace alone (elsewhere nothing is opened and None is yielded):
+        its wall less its CPU is time its thread was runnable and not
+        running. It is in the record's `cost` and never in its `stages`,
+        which stay what they were before the leaf existed."""
+        cur = self.current()
+        if cur is None or not costed(cur.trace_id):
+            yield None
+            return
+        with self.span(name, **tags) as s:
+            s.cost_only = True
+            yield s
 
     def begin_operation(self, name: str, **tags) -> Span:
         """Open an operation root that no `with` can bracket: one of
@@ -127,8 +257,8 @@ class Tracer:
         in between). It is never the thread's current span: its stages
         are spans opened with `child_of=context(root)`. Finish it with
         `end_operation`, exactly once."""
-        s = Span(self._new_id(), self._new_id(), "", name, time.time(),
-                 tags=dict(tags), mono=time.monotonic())
+        s = Span(self._new_trace_id(name), self._new_id(), "", name,
+                 time.time(), tags=dict(tags), mono=time.monotonic())
         s.op = True
         return s
 
@@ -156,23 +286,38 @@ class Tracer:
             _local.riders = prev
 
     def _finish(self, s: Span) -> None:
-        with self._lock:
-            self.spans.append(s)
-            if self._export_q is not None:
-                self._export_q.append(s)
-            if s.parent_id:
-                held = self._open.get(s.trace_id)
-                if held is None:
-                    held = self._open[s.trace_id] = []
+        # A child span's end takes no lock: eight threads of bare spans
+        # queued for one, and the convoy cost three times what the
+        # spans themselves did (a CPU loop; the chip's GET cell, whose
+        # threads are mostly outside the interpreter, did not feel it:
+        # PERF.md section 6, PR 38). Each step is one call the
+        # interpreter makes whole: a deque's and a list's append, a
+        # dict's get. The lock is for what changes `_open`'s and
+        # `_handed`'s keys.
+        spans = self.spans
+        if len(spans) == spans.maxlen:
+            # (two threads at the very append that fills the ring may
+            # count one eviction between them; from then on each counts)
+            METRICS.counter("spans_evicted").inc()
+        spans.append(s)
+        if self._export_q is not None:
+            self._export_q.append(s)
+        if s.parent_id:
+            held = self._open.get(s.trace_id)
+            if held is None:
+                with self._lock:
+                    held = self._open.setdefault(s.trace_id, [])
                     if len(self._open) > MAX_OPEN_TRACES:
                         self._open.popitem(last=False)
-                if len(held) < MAX_TRACE_SPANS:
-                    held.append(s)
-                return
+            if len(held) < MAX_TRACE_SPANS:
+                held.append(s)
+            return
+        with self._lock:
             held = self._open.pop(s.trace_id, [])
+            handed = self._handed.pop(s.trace_id, ())
         # the root finished last: `held` is the whole local trace
         held.append(s)
-        self.recorder.root_finished(s, held)
+        self.recorder.root_finished(s, held, handed)
 
     def record_span(self, name: str, *, child_of: str = "",
                     start: float, duration: float, span_id: str = "",
@@ -223,7 +368,10 @@ class Tracer:
         if not ctx:
             yield
             return
-        tid, sid = (ctx.split(":") + [""])[:2]
+        tid, sid, *made = (ctx.split(":") + [""])[:4]
+        if len(made) == 2 and made[1] != str(threading.get_ident()):
+            # a context of `handoff()`, taken up by another thread
+            self._book_handoff(tid, time.monotonic() - float(made[0]))
         prev = self.current()
         # context holder only — never finished, never recorded
         _local.span = Span(tid, sid, "", "<activated>", time.time())
@@ -241,13 +389,35 @@ class Tracer:
         s = self.current()
         return self.context(s) if s else ""
 
-    def current_trace_id(self) -> str:
-        s = self.current()
-        return s.trace_id if s else ""
+    def handoff(self) -> str:
+        """The current context for a pool worker of THIS process: as
+        `inject()`, and in a costed trace the moment it was made and by
+        which thread. `activate` on another thread then books one
+        hand-off against the trace: its count and how long the work
+        waited to be taken up (a thread started or woken, the pool's
+        queue, the worker's turn at the interpreter). Never sent over
+        the wire."""
+        ctx = self.inject()
+        if "," in ctx or not costed(ctx.partition(":")[0]):
+            return ctx
+        return f"{ctx}:{time.monotonic():.6f}:{threading.get_ident()}"
+
+    def _book_handoff(self, trace_id: str, waited: float) -> None:
+        # the worker's pool, from its thread's name: `ec-read_3`
+        pool = threading.current_thread().name.rstrip("0123456789") \
+            .rstrip("-_")
+        _HANDOFFS.inc()
+        _HANDOFF_SECONDS.observe(waited, trace_id)
+        with self._lock:
+            booked = self._handed.get(trace_id)
+            if booked is None:
+                booked = self._handed[trace_id] = []
+                if len(self._handed) > MAX_OPEN_TRACES:
+                    self._handed.popitem(last=False)
+            booked.append((pool, waited))
 
     def traces(self, trace_id: Optional[str] = None) -> list[Span]:
-        with self._lock:
-            out = list(self.spans)
+        out = list(self.spans)  # one call: no append comes between
         if trace_id:
             out = [s for s in out if s.trace_id == trace_id]
         return out
@@ -308,8 +478,123 @@ class Stage:
         self.histogram.observe(seconds)
 
 
-def dispatcher_seconds(metrics,
-                       stages=("idle", "pack", "launch", "d2h")) -> dict:
+class ProcessSampler:
+    """One thread a process (`proc-sampler`, started with the first
+    `Tracer.instance()`): every IDLE_TICK_S it books one sample of what
+    the PROCESS costs its host, kept for ten minutes:
+
+      (time.monotonic(), the process's CPU seconds so far, the host's
+       busy and total jiffies from the first line of /proc/stat, live
+       threads, and how LATE the sampler itself was: how long after the
+       tick it asked for it was running again, which is what a freshly
+       woken thread of this process waits for its turn at the
+       interpreter).
+
+    Registry `tracing`: gauges `process_cpu_seconds` and `threads`,
+    histogram `interpreter_wait_seconds`. While a profiler session is
+    on, a lateness above one switch interval is also an event
+    `interp:wait` on this thread in the profiler's trace; an annotation
+    cannot be back-dated, so the event marks the END of the wait and its
+    `late_us` says how far back it began."""
+
+    KEEP_S = 600.0
+    _started: Optional["ProcessSampler"] = None
+    _start_lock = threading.Lock()
+
+    def __init__(self):
+        self.ring: deque[tuple] = deque(
+            maxlen=int(self.KEEP_S / IDLE_TICK_S))
+        self._lock = threading.Lock()
+        #: (when, busy, total) of the last walk over /proc/<pid>/stat
+        self._scanned = (float("-inf"), 0, 0)
+        self.thread = threading.Thread(target=self._loop, daemon=True,
+                                       name="proc-sampler")
+
+    @classmethod
+    def ensure_started(cls) -> "ProcessSampler":
+        with cls._start_lock:
+            if cls._started is None or not cls._started.thread.is_alive():
+                cls._started = cls()
+                cls._started.thread.start()
+            return cls._started
+
+    def _host_jiffies(self, now: float) -> tuple[int, int]:
+        """(busy, total) clock ticks of all cores up to `now`: the first
+        line of /proc/stat. A sandbox kernel (gVisor, the chip machines')
+        shows that line as zeros and every process of the sandbox under
+        /proc: there busy is the sum of their CPU, and total the cores'
+        ticks; read once a second, a sample between two readings repeats
+        the last. (0, 0) where there is no /proc."""
+        try:
+            with open("/proc/stat", "rb") as f:
+                v = [int(x) for x in f.readline().split()[1:9]]
+        except (OSError, ValueError):
+            return 0, 0
+        total = sum(v)
+        if total:
+            return total - v[3] - v[4], total  # idle, iowait
+        if now - self._scanned[0] >= 1.0:
+            busy = 0
+            for pid in os.listdir("/proc"):
+                if pid.isdigit():
+                    try:
+                        with open(f"/proc/{pid}/stat", "rb") as f:
+                            # after the name: state is field 3, utime
+                            # and stime fields 14 and 15
+                            v = f.read().rpartition(b")")[2].split()
+                        busy += int(v[11]) + int(v[12])
+                    except (OSError, ValueError, IndexError):
+                        continue  # it exited meanwhile
+            ticks = os.sysconf("SC_CLK_TCK")
+            self._scanned = (now, busy,
+                             int(now * ticks) * (os.cpu_count() or 1))
+        return self._scanned[1:]
+
+    def _loop(self) -> None:
+        cpu = METRICS.gauge("process_cpu_seconds")
+        threads = METRICS.gauge("threads")
+        late_h = METRICS.histogram("interpreter_wait_seconds",
+                                   _metrics.log_buckets(1e-5, 10.0))
+        due = time.monotonic()
+        while True:
+            due = max(due + IDLE_TICK_S, time.monotonic())
+            time.sleep(max(0.0, due - time.monotonic()))
+            now = time.monotonic()
+            late = max(0.0, now - due)
+            busy, total = self._host_jiffies(now)
+            n = threading.active_count()
+            used = time.process_time()
+            with self._lock:
+                self.ring.append((now, used, busy, total, n, late))
+            cpu.set(used)
+            threads.set(n)
+            late_h.observe(late)
+            # longer than the timer's slack: it waited for the interpreter
+            if late > sys.getswitchinterval():
+                annotation = _profiler_annotation()
+                if annotation is not None and annotation.is_enabled():
+                    with annotation("interp:wait",
+                                    late_us=int(late * 1e6)):
+                        pass
+
+
+def samples(t0: float = float("-inf"),
+            t1: float = float("inf")) -> list[tuple]:
+    """The process sampler's samples taken in [t0, t1) on
+    time.monotonic(), oldest first: (monotonic, process CPU seconds,
+    host busy jiffies, host total jiffies, live threads, lateness
+    seconds) each. A reader takes deltas of the first four between the
+    window's first and last sample and means the last."""
+    sampler = ProcessSampler._started
+    if sampler is None:
+        return []
+    with sampler._lock:
+        kept = list(sampler.ring)
+    return [s for s in kept if t0 <= s[0] < t1]
+
+
+def dispatcher_seconds(metrics, stages=("idle", "pack", "launch", "d2h",
+                                        "complete")) -> dict:
     """Where a dispatcher's time went since start, from the stage
     histograms of its registry (`stages`: those of every thread it
     runs): a large idle share says it is starved, a large pack / launch
@@ -329,6 +614,10 @@ def span_json(s: Span, service: str = "") -> dict:
         "name": s.name,
         "start": s.start,
         "durationMs": round(s.duration * 1e3, 3),
+        # what its thread spent (a span bracketed on one thread)
+        **({"cpuMs": round(s.cpu * 1e3, 3), "blocks": s.blocks,
+            "preempts": s.preempts} if s.thread else {}),
+        **({"costOnly": True} if s.cost_only else {}),
         "tags": s.tags,
         **({"events": list(s.events)} if s.events else {}),
         **({"service": service} if service else {}),
@@ -343,7 +632,22 @@ def critical_path(spans: list[dict]) -> list[dict]:
     siblings are swept first-started-first so parallel hops (hedges,
     fan-out) never double-count. Output is aggregated by span name,
     ordered by first occurrence; the micros sum equals the root span's
-    duration by construction."""
+    duration by construction. A `costOnly` span (`Tracer.cost_leaf`)
+    is left out: its children are its parent's, its own time its
+    parent's self, as before the leaf existed."""
+    skipped = {s["spanId"]: s.get("parentId", "") for s in spans
+               if "costOnly" in s and s.get("spanId")}
+    if skipped:
+        kept = []
+        for s in spans:
+            if s.get("spanId") in skipped:
+                continue
+            pid = s.get("parentId", "")
+            while pid in skipped:
+                pid = skipped[pid]
+            kept.append(s if pid == s.get("parentId", "")
+                        else {**s, "parentId": pid})
+        spans = kept
     spans = [s for s in spans if s.get("spanId")]
     if not spans:
         return []
@@ -385,6 +689,55 @@ def critical_path(spans: list[dict]) -> list[dict]:
     ]
 
 
+def _cost(spans: list[Span]) -> dict:
+    """{name: [self wall us, self CPU us, blocks, preempts]}: see
+    `FlightRecorder.operations`."""
+    by_id = {s.span_id: s for s in spans}
+    own = {s.span_id: [s.duration, s.cpu, s.blocks, s.preempts]
+           for s in spans}
+    for s in spans:
+        parent = by_id.get(s.parent_id)
+        if parent is not None and s.thread and parent.thread == s.thread:
+            left = own[parent.span_id]
+            left[0] -= s.duration
+            left[1] -= s.cpu
+            left[2] -= s.blocks
+            left[3] -= s.preempts
+    out: dict[str, list] = {}
+    for s in spans:
+        wall, cpu, blocks, preempts = own[s.span_id]
+        c = out.setdefault(s.name, [0, 0, 0, 0])
+        c[0] += int(round(wall * 1e6))
+        c[1] += int(round(cpu * 1e6))
+        c[2] += blocks
+        c[3] += preempts
+    return out
+
+
+def _handoffs(handoffs) -> dict:
+    pools: dict[str, list] = {}
+    for pool, waited in handoffs:
+        p = pools.setdefault(pool, [0, 0])
+        p[0] += 1
+        p[1] += int(round(waited * 1e6))
+    return {"n": len(handoffs),
+            "waitUs": sum(p[1] for p in pools.values()),
+            "maxUs": int(round(max((w for _p, w in handoffs),
+                                   default=0.0) * 1e6)),
+            "pools": pools}
+
+
+def _rpc(spans: list[Span]) -> dict:
+    out: dict[str, list] = {}
+    for s in spans:
+        if s.name.startswith("client:/"):
+            r = out.setdefault(s.name[len("client:"):], [0, 0, 0])
+            r[0] += 1
+            r[1] += int(round(s.duration * 1e6))
+            r[2] += int(s.tags.get("server_us", 0))
+    return out
+
+
 class FlightRecorder:
     """Tail-based slow-trace retention: any trace whose ROOT span
     exceeds its per-op SLO is pinned — with its critical path — into a
@@ -422,31 +775,52 @@ class FlightRecorder:
         key = re.sub(r"[^A-Za-z0-9]+", "_", op).strip("_").upper()
         return env_float(f"OZONE_TPU_TRACE_SLO_{key}_MS", default) / 1e3
 
-    def root_finished(self, root: Span, spans: list[Span]) -> None:
+    def root_finished(self, root: Span, spans: list[Span],
+                      handoffs=()) -> None:
         """A root span finished in this process; `spans` is its whole
-        local trace, the root included."""
+        local trace, the root included, `handoffs` the (pool, seconds
+        waited) of every hand-off booked against it."""
         if root.op:
             # on the monotonic clock: the stages then partition the
             # root's own duration, whatever the wall clock did meanwhile
             path = critical_path([
                 {"spanId": s.span_id, "parentId": s.parent_id,
                  "name": s.name, "start": s.mono,
-                 "durationMs": s.duration * 1e3} for s in spans])
+                 "durationMs": s.duration * 1e3,
+                 **({"costOnly": True} if s.cost_only else {})}
+                for s in spans])
             rec = {"root": root.name, "traceId": root.trace_id,
                    "end": root.mono + root.duration,
                    "durationUs": int(round(root.duration * 1e6)),
                    "stages": {st["stage"]: st["micros"] for st in path}}
+            if costed(root.trace_id):
+                rec.update(cost=_cost(spans), handoffs=_handoffs(handoffs),
+                           rpc=_rpc(spans))
             with self._lock:
                 self._ops.append(rec)
         self.offer(root, spans)
 
     def operations(self, root: str = "", t0: float = float("-inf"),
                    t1: float = float("inf")) -> list[dict]:
-        """Stage records {root, traceId, end, durationUs, stages:
-        {stage: micros}} of the finished operations named `root` (all,
+        """Stage records of the finished operations named `root` (all,
         if empty) whose END lies in [t0, t1) on time.monotonic(),
-        oldest first. A record's stages sum to its durationUs (to the
-        rounding of each stage)."""
+        oldest first: {root, traceId, end, durationUs,
+        `stages`: {stage: critical-path micros}, which sum to durationUs
+        (to the rounding of each stage), and in the record of a costed
+        operation (`costed`) alone:
+        `cost`: {span name: [self wall us, self CPU us, blocks,
+        preempts]} over EVERY local span of the trace, on the critical
+        path or off it, a span's self being what is left of it without
+        its children on the SAME thread: the CPUs sum to the operation's
+        CPU in this process as far as spans cover its threads, and for a
+        leaf that only copies memory wall - CPU is time its thread was
+        runnable and not running (the interpreter lock, or a core taken
+        away: `preempts` says which);
+        `handoffs`: {n, waitUs, maxUs, pools: {pool: [n, waitUs]}} of
+        the work it handed to pool workers (`Tracer.handoff`);
+        `rpc`: {"/service/method": [calls, client us, server us]} of
+        its `client:/...` spans, server us as the daemon reported it
+        (0 from one that reports none)}."""
         with self._lock:
             ops = list(self._ops)
         return [r for r in ops
@@ -740,10 +1114,12 @@ class TraceCollector:
             {"traces": self.recorder.slow(m.get("limit", 50))})
 
 
+def _current_trace_id() -> str:
+    s = getattr(_local, "span", None)
+    return s.trace_id if s is not None else ""
+
+
 # Histogram exemplars stamp the active trace id (outlier observations
 # link a scraped tail bucket to a retained slow trace); registered here
 # so metrics stays import-independent of tracing.
-from ozone_tpu.utils import metrics as _metrics  # noqa: E402
-
-_metrics.set_trace_id_provider(
-    lambda: Tracer.instance().current_trace_id())
+_metrics.set_trace_id_provider(_current_trace_id)

@@ -21,6 +21,15 @@ os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 import pytest  # noqa: E402
 
+from ozone_tpu.utils import tracing as _tracing  # noqa: E402
+
+# The program costs one operation of a name a second
+# (tracing.COST_INTERVAL_S), and the benchmark's CPU passes here hold
+# windows of 0.4 to 1 s of operations that last milliseconds: ten a
+# second here, so that every such window holds costed operations from
+# their start to their end.
+_tracing.COST_INTERVAL_S = 0.1
+
 
 def pytest_configure(config):
     config.addinivalue_line(

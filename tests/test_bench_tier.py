@@ -51,6 +51,14 @@ TIER_METRICS = {
     "tier_om_ms": "mesh_op_stage_ms",
     "tier_client_ms": "mesh_op_stage_ms",
 }
+#: what ISSUE 38 appended for the cell: the cost of a key in this
+#: process, and the process's own series (shared with the `ockg` cells)
+COST_METRICS = {
+    "tier_cpu_ms": "op_cost_ms",
+    "client_cpu_cores.put": "process_cpu_cores",
+    "interp_wait_ms.put": "process_interp_wait_ms",
+    "host_busy_pct.put": "host_busy_pct",
+}
 GROUPS = ("tier_read_ms", "tier_mesh_ms", "tier_write_ms", "tier_om_ms",
           "tier_client_ms")
 #: the sweep at a size a test can hold: 4 KiB cells, keys of 2 and 4
@@ -87,7 +95,9 @@ def test_the_kept_cell_reports_every_metric_the_write_cell_does():
     for m in MANIFEST["per_layer"]:
         assert ("ockg.rs-6-3" in m["workloads"]) == (KEPT in m["workloads"])
         if KEPT in m["workloads"]:
-            assert m["workloads"] == ["ockg.rs-6-3", KEPT], m["name"]
+            # (the process's own series list the sweep cell after them)
+            assert m["workloads"] in (["ockg.rs-6-3", KEPT],
+                                      ["ockg.rs-6-3", KEPT, CELL]), m["name"]
     assert len(mf.metrics_for(MANIFEST, "per_layer", KEPT)) == len(
         mf.metrics_for(MANIFEST, "per_layer", "ockg.rs-6-3")) > 10
     assert mf.config_of(MANIFEST, mf.cell(MANIFEST, KEPT))["scheme"]["k"] == 10
@@ -100,7 +110,11 @@ def test_the_sweeps_metrics_list_the_cell_alone_and_move_put_mib_s():
         assert by_name[name]["moves"] == "put_mib_s"
         assert mf.metric_params(name)["reader"] == reader, name
     assert {m["name"] for m in mf.metrics_for(
-        MANIFEST, "per_layer", CELL)} == set(TIER_METRICS)
+        MANIFEST, "per_layer", CELL)} == set(TIER_METRICS) | set(COST_METRICS)
+    for name, reader in COST_METRICS.items():
+        assert CELL in by_name[name]["workloads"], name
+        assert by_name[name]["moves"] == "put_mib_s"
+        assert mf.metric_params(name)["reader"] == reader, name
     # appended in one run, after everything the benchmark had then
     names = [m["name"] for m in MANIFEST["per_layer"]]
     at = names.index(next(iter(TIER_METRICS)))
@@ -305,7 +319,12 @@ def _pass(tmp_path, trace: int = 0, control: str = "",
 
 
 def test_a_traced_pass_is_correct_and_reads_every_counter_and_span_metric(
-        tmp_path, capsys):
+        tmp_path, capsys, monkeypatch):
+    from ozone_tpu.utils import tracing
+
+    # the program costs one root of a name a second, and this window is
+    # 0.4 s: cost every key, so `tier_cpu_ms` has one to read
+    monkeypatch.setattr(tracing, "COST_INTERVAL_S", 0.0)
     out, run = _pass(tmp_path, trace=1)
     assert out["correct"] is True and out["failed"] == 0, out["compared"]
     assert out["attempted"] >= 4 and out["rehearsal"] is True

@@ -1,8 +1,9 @@
 """The codec dispatcher's timeline: the one dispatcher thread books every
-stretch of its time to one of four leaf stages that never nest —
+stretch of its time to one of five leaf stages that never nest —
 `codec:idle` (nothing ready, nothing in flight), `codec:pack`,
-`codec:launch`, `codec:d2h` — each a histogram of `codec.service`, and
-mirrors them to the profiler's own trace while a session is on."""
+`codec:launch`, `codec:d2h`, `codec:complete` (after the pull until the
+last rider's future is resolved) — each a histogram of `codec.service`,
+and mirrors them to the profiler's own trace while a session is on."""
 
 import subprocess
 import sys
@@ -17,7 +18,7 @@ from ozone_tpu.codec import service as cs
 from ozone_tpu.parallel import mesh_executor  # noqa: F401
 from ozone_tpu.utils.tracing import Stage, Tracer
 
-STAGES = ("idle", "pack", "launch", "d2h")
+STAGES = ("idle", "pack", "launch", "d2h", "complete")
 LAUNCH_S, D2H_S = 0.02, 0.01
 
 
@@ -81,6 +82,7 @@ def test_stage_histograms_follow_a_scripted_sequence(fresh, whole):
         assert np.array_equal(np.concatenate(outs), data)
     d = _delta(_book(), before)
     assert d["pack"][1] == d["launch"][1] == d["d2h"][1] == 3
+    assert d["complete"][1] == 3 and 0 < d["complete"][0] < 3 * D2H_S
     assert d["dispatch"][1] == 3
     assert d["launch"][0] >= 3 * LAUNCH_S
     assert 3 * D2H_S <= d["d2h"][0] < d["launch"][0]
@@ -91,7 +93,8 @@ def test_stage_histograms_follow_a_scripted_sequence(fresh, whole):
     assert -1e-3 <= hold < 0.05
     # the operator's view (/api/codec): the same split, since start
     took = svc.stats()["dispatcher_seconds"]
-    assert set(took) == {"idle", "pack", "launch", "d2h", "hold"}
+    assert set(took) == {"idle", "pack", "launch", "d2h", "complete",
+                         "hold"}
     assert took["launch"] >= d["launch"][0] and took["hold"] >= 0.0
     # 3. the stages never overlap: together they never exceed the wall
     # time the thread ran, and leave little of it out
@@ -172,7 +175,7 @@ def test_stages_reach_the_profiler_trace_and_spans_do_not(fresh, tmp_path):
     (names,) = by_line.values()
     ours = {n for n in names if n.startswith(("codec:", "client:", "ec:"))}
     assert ours == {"codec:idle", "codec:pack", "codec:launch",
-                    "codec:d2h"}
+                    "codec:d2h", "codec:complete"}
 
 
 def test_a_stage_outside_a_session_is_cheap_and_imports_no_jax():
