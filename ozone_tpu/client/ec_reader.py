@@ -399,18 +399,31 @@ class ECBlockGroupReader:
                            warn=False)
         return out
 
-    def _prefetch_unit(self, u: int, stripes: Sequence[int]) -> None:
+    def _prefetch_unit(
+        self, u: int, stripes: Sequence[int],
+        rows: Optional[dict[int, np.ndarray]] = None,
+    ) -> tuple[dict[int, ChunkInfo], int]:
         """Batch-read unit u's cells for `stripes` in ONE ReadChunks
         RPC (the read twin of the batched write path: transport round
-        trip per unit, not per cell) into the cell cache. Best-effort —
-        any error (including a server without the verb) simply leaves
-        the cells to the per-chunk path, which surfaces precise
-        per-cell failures."""
+        trip per unit, not per cell). Best-effort — any error
+        (including a server without the verb) simply leaves the cells
+        to the per-chunk path, which surfaces precise per-cell failures.
+
+        Without `rows` the cells land in the cell cache. With `rows`
+        (stripe -> that cell's row of a decode batch, writable, a
+        cell long) and a transport that offers `read_chunks_into`, the
+        answer is written to the rows and passes no other host buffer:
+        returns (stripe -> its chunk for every row written, each from
+        its start for the chunk's length; how many of them the
+        transport RECEIVED there, the rest it copied). A row of a read
+        that failed may be half written: its cell is not in the answer,
+        and the per-chunk path assigns it whole."""
+        none: tuple[dict[int, ChunkInfo], int] = ({}, 0)
         if not self._batch_reads:
-            return
+            return none
         bd = self._unit_block(u)
         if bd is None:
-            return
+            return none
         by_offset = {c.offset: c for c in bd.chunks}
         wanted = [
             (s, by_offset[s * self.cell])
@@ -419,28 +432,42 @@ class ECBlockGroupReader:
             and s * self.cell in by_offset
         ]
         if len(wanted) < 2:
-            return  # nothing saved over the per-chunk path
+            return none  # nothing saved over the per-chunk path
+        infos = [i for _, i in wanted]
         dn_id = self.group.pipeline.nodes[u]
         try:
             client = self.clients.get(dn_id)
+            # looked up on the CLASS: a wrapper that hands unknown names
+            # on to the client it wraps has not overridden the verb, and
+            # the `read_chunks` it does define must see this read
+            into = (getattr(type(client), "read_chunks_into", None)
+                    if rows is not None else None)
             fn = getattr(client, "read_chunks", None)
-            if fn is None:
-                return
-            self._asked(u, [i for _, i in wanted])
+            if into is None and fn is None:
+                return none
+            self._asked(u, infos)
             with Tracer.instance().span("net:read_chunks", dn=dn_id,
-                                        unit=u, cells=len(wanted)):
-                datas = self._health.observe(
-                    dn_id, fn, self.group.block_id,
-                    [i for _, i in wanted], verify=self.verify)
+                                        unit=u, cells=len(wanted)) as sp:
+                if into is None:
+                    datas = self._health.observe(
+                        dn_id, fn, self.group.block_id, infos,
+                        verify=self.verify)
+                else:
+                    sp.tags["in_place"] = in_place = self._health.observe(
+                        dn_id, client.read_chunks_into, self.group.block_id,
+                        infos, [rows[s] for s, _ in wanted],
+                        verify=self.verify)
+                    return dict(wanted), in_place
         except (StorageError, KeyError, OSError) as e:
             if isinstance(e, StorageError) \
                     and e.code == resilience.DEADLINE_EXCEEDED:
                 raise
             log.debug("batched read of unit %d failed (%s); per-chunk "
                       "path will retry", u, e)
-            return
+            return none
         for (s, _info), data in zip(wanted, datas):
             self._cell_cache[(u, s)] = self._cell_array(data)
+        return none
 
     # ---------------------------------------------------------------- normal
     def read_all(self) -> np.ndarray:
@@ -543,26 +570,29 @@ class ECBlockGroupReader:
         placed: dict[int, set[int]] = {}
         tally = self._tally
 
-        def take_survivors(sb, valid, batch) -> None:
-            data = [(vi, u) for vi, u in enumerate(valid) if u < self.k]
-            copied = 0
+        def assemble(part: str, sb, cols, src) -> tuple[int, int]:
             # a leaf that only copies memory, one a pass over a batch
-            # (this one and the decoded cells' below), never one a
-            # cell, in a costed operation alone: its wall less its CPU
-            # is time this thread was runnable and not running
+            # (its surviving data cells, then its decoded cells), never
+            # one a cell, in a costed operation alone: its wall less
+            # its CPU is time this thread was runnable and not running
             # (PERF.md section 3)
             with Tracer.instance().cost_leaf(
-                    "ec:assemble", part="survivors",
-                    cells=len(sb) * len(data)) as sp:
-                for bi, s in enumerate(sb):
-                    for vi, u in data:
-                        n = self._put_cell(out, offset, length, u, s,
-                                           batch[bi, vi])
-                        copied += n
-                        tally.cells_reused += bool(n)
-                    placed[s] = {u for _, u in data}
+                    "ec:assemble", part=part,
+                    cells=len(sb) * len(cols)) as sp:
+                copied, cells, strokes = self._put_cells(
+                    out, offset, length, sb, cols, src)
                 if sp is not None:
-                    sp.tags["bytes"] = copied
+                    sp.tags.update(bytes=copied, strokes=strokes)
+            OPS.counter("assemble_cells").inc(cells)
+            OPS.counter("assemble_strokes").inc(strokes)
+            return copied, cells
+
+        def take_survivors(sb, valid, batch) -> None:
+            data = [(vi, u) for vi, u in enumerate(valid) if u < self.k]
+            copied, cells = assemble("survivors", sb, data, batch)
+            tally.cells_reused += cells
+            for s in sb:
+                placed[s] = {u for _, u in data}
             self._count_copy(copied, "reuse_survivor")
 
         # exclude_stragglers=False: a straggling survivor propagates to
@@ -572,19 +602,64 @@ class ECBlockGroupReader:
         for sb, (rec, _crcs) in self.recover_cells_iter(
                 targets, stripes, exclude_stragglers=False,
                 on_survivors=take_survivors):
-            copied = 0
-            with Tracer.instance().cost_leaf(
-                    "ec:assemble", part="decoded",
-                    cells=len(sb) * len(targets)) as sp:
-                for bi, s in enumerate(sb):
-                    for ti, u in enumerate(targets):
-                        copied += self._put_cell(out, offset, length, u, s,
-                                                 rec[bi, ti])
-                    placed[s].update(targets)
-                if sp is not None:
-                    sp.tags["bytes"] = copied
+            copied, _ = assemble("decoded", sb, list(enumerate(targets)),
+                                 rec)
+            for s in sb:
+                placed[s].update(targets)
             self._count_copy(copied, "recovered_cell")
         return placed
+
+    def _put_cells(self, out: np.ndarray, offset: int, length: int,
+                   sb: Sequence[int], cols: Sequence[tuple[int, int]],
+                   src: np.ndarray) -> tuple[int, int, int]:
+        """Copy `src[bi, ci]`, data unit u's cell of stripe `sb[bi]`
+        for every (ci, u) of `cols`, to its place in `out`. Stripes
+        that lie whole inside the range go in strokes: `out` seen as
+        [stripe, k, cell], every run of columns whose units are
+        consecutive too is ONE assignment over all of a run of
+        consecutive stripes (lost units cut the columns into at most
+        e + 1 runs); a stripe the range cuts goes cell by cell.
+        Returns (bytes copied, cells with a byte in the range, the
+        assignments that moved them)."""
+        row = self.k * self.cell
+        first = -(-offset // row)  # the stripes whole inside the range
+        last = (offset + length) // row
+        out3 = None
+        if last > first and out.flags.c_contiguous:
+            out3 = out[first * row - offset:last * row - offset].reshape(
+                last - first, self.k, self.cell)
+        runs: list[tuple[int, int, int]] = []  # (column, unit, width)
+        for ci, u in cols:
+            if runs and (ci, u) == (runs[-1][0] + runs[-1][2],
+                                    runs[-1][1] + runs[-1][2]):
+                runs[-1] = (runs[-1][0], runs[-1][1], runs[-1][2] + 1)
+            else:
+                runs.append((ci, u, 1))
+        copied = cells = strokes = 0
+        b0 = 0
+        while b0 < len(sb):
+            s0 = sb[b0]
+            if out3 is None or not first <= s0 < last:
+                for ci, u in cols:
+                    n = self._put_cell(out, offset, length, u, s0,
+                                       src[b0, ci])
+                    copied += n
+                    cells += bool(n)
+                    strokes += bool(n)
+                b0 += 1
+                continue
+            b1 = b0 + 1
+            while b1 < len(sb) and sb[b1] == sb[b1 - 1] + 1 \
+                    and sb[b1] < last:
+                b1 += 1
+            for ci, u, w in runs:
+                out3[s0 - first:s0 - first + b1 - b0, u:u + w] = \
+                    src[b0:b1, ci:ci + w]
+            strokes += len(runs)
+            cells += (b1 - b0) * len(cols)
+            copied += (b1 - b0) * len(cols) * self.cell
+            b0 = b1
+        return copied, cells, strokes
 
     def _read_cell_checked(self, u: int, stripe: int) -> np.ndarray:
         try:
@@ -989,20 +1064,37 @@ class ECBlockGroupReader:
             def fill_unit(vi_u):
                 vi, u = vi_u
                 # one batched ReadChunks for the unit's cells of this
-                # batch first; cells it couldn't serve fall back to
-                # per-chunk reads
-                self._prefetch_unit(u, sb)
-                # the unit's cells into the decode batch: one leaf a
-                # unit stream (a cell the batched read could not serve
-                # is a `net:read_chunk` child, not this span's self)
-                with Tracer.instance().cost_leaf("ec:fill", unit=u,
-                                                 cells=len(sb)):
+                # batch first, received straight into the unit's column
+                # where the transport can: the batch is then the only
+                # host buffer those cells pass on their way in
+                served, in_place = self._prefetch_unit(
+                    u, sb, rows={s: batch[bi, vi]
+                                 for bi, s in enumerate(sb)})
+                # what the receive left: one leaf a unit stream (a cell
+                # the batched read could not serve is a `net:read_chunk`
+                # child, not this span's self)
+                with Tracer.instance().cost_leaf(
+                        "ec:fill", unit=u, cells=len(sb),
+                        in_place=in_place,
+                        copied=len(sb) - in_place):
                     for bi, s in enumerate(sb):
-                        # EVERY [bi, vi] is assigned a whole cell: an
-                        # absent or short one comes zero-padded
-                        # (`_fetch_cell`, `_cell_array`), so no recycled
-                        # byte stays
-                        batch[bi, vi] = self._read_cell_checked(u, s)
+                        # EVERY [bi, vi] is written a whole cell long:
+                        # a served chunk's row keeps what the transport
+                        # wrote and has its tail zeroed, any other cell
+                        # is assigned whole, an absent or short one
+                        # zero-padded (`_fetch_cell`, `_cell_array`),
+                        # so no recycled byte stays
+                        info = served.get(s)
+                        if info is None:
+                            batch[bi, vi] = self._read_cell_checked(u, s)
+                        elif info.length < self.cell:
+                            batch[bi, vi, info.length:] = 0
+                OPS.counter("fill_cells").inc(len(sb))
+                OPS.counter("survivor_cells_in_place").inc(in_place)
+                # an in-place receive of the stream's cells is one
+                # stroke, every cell copied one more
+                OPS.counter("fill_strokes").inc(
+                    bool(in_place) + len(sb) - in_place)
 
             # one reader thread per survivor unit: the k unit streams
             # come off k DIFFERENT datanodes, so the read fan-in costs

@@ -102,6 +102,25 @@ def _sendmsg_all(sock: socket.socket, parts: list) -> None:
             mv[i] = batch[j][sent:]
 
 
+def _recvmsg_some(sock: socket.socket, bufs: list, i: int) -> tuple[int, int]:
+    """One scatter receive into `bufs[i:]` (IOV_MAX at most a call),
+    the read twin of `_sendmsg_all`: returns (bytes received, index of
+    the first buffer not yet full); a buffer the receive ended inside
+    is cut in place to its unwritten rest, so a frame may straddle any
+    boundary and the next call carries on where this one stopped."""
+    batch = bufs[i:i + _IOV_MAX]
+    got = sock.recvmsg_into(batch)[0]
+    if got == 0:
+        raise ConnectionError("native datapath peer closed")
+    left, j = got, 0
+    while j < len(batch) and left >= len(batch[j]):
+        left -= len(batch[j])
+        j += 1
+    if left:
+        bufs[i + j] = batch[j][left:]
+    return got, i + j
+
+
 class _Conn:
     def __init__(self, host: str, port: int, uds: Optional[str] = None):
         # deadline-derived connect timeout: a spent budget raises
@@ -376,14 +395,24 @@ class NativeDatanodeClient(GrpcDatanodeClient):
             raise
 
     # ------------------------------------------------------------- read path
+    def _read_request(self, block_id, infos, verify) -> list:
+        """The frames of one ReadChunks request: RHDR, a RCHUNK a chunk
+        (its CRCs with it where the daemon is to verify), END."""
+        meta = {"op": "read", "block_id": block_id.to_json(),
+                **self._btok(block_id)}
+        frames: list[tuple[int, object]] = [
+            (_T_RHDR, json.dumps(meta, separators=(",", ":")).encode())]
+        for info in infos:
+            frames.append((_T_RCHUNK, _rchunk_body(info, verify)))
+        frames.append((_T_END, b""))
+        return frames
+
     def read_chunks(self, block_id, infos, verify=False):
         port = self._native_port()
         if port is None or (verify and not _natively_verifiable(infos)):
             return super().read_chunks(block_id, infos, verify=verify)
         self._check_partition("ReadChunks")
-        meta = {"op": "read", "block_id": block_id.to_json(),
-                **self._btok(block_id)}
-        hdr = json.dumps(meta, separators=(",", ":")).encode()
+        request = self._read_request(block_id, infos, verify)
         try:
             conn = self._checkout(port)
         except OSError:
@@ -423,11 +452,7 @@ class NativeDatanodeClient(GrpcDatanodeClient):
         out = []
         try:
             conn.arm("ReadChunks")
-            frames: list[tuple[int, object]] = [(_T_RHDR, hdr)]
-            for info in infos:
-                frames.append((_T_RCHUNK, _rchunk_body(info, verify)))
-            frames.append((_T_END, b""))
-            conn.send_frames(frames)
+            conn.send_frames(request)
             pos = 0
             for idx in range(len(infos) + 1):
                 _fill(pos + 5)
@@ -474,6 +499,105 @@ class NativeDatanodeClient(GrpcDatanodeClient):
             return super().read_chunk(block_id, info, verify=verify)
         return self.read_chunks(block_id, [info], verify=verify)[0]
 
+    def read_chunks_into(self, block_id, infos, rows, verify=False):
+        """`read_chunks` with the answer RECEIVED where it is wanted:
+        the response stream is scattered by the kernel over [frame
+        head scratch, rows[0], frame head scratch, rows[1], ..., status
+        scratch], so chunk i's payload goes from the socket to
+        `rows[i][:infos[i].length]` and passes no slab, no view and no
+        copy. A row (one writable C-contiguous uint8 array, at least
+        its chunk long) is written from its start; what lies behind
+        the chunk's length is left alone. Returns how many rows were
+        received in place: all, or the gRPC fallback's none. A row of
+        a read that raised may be half written."""
+        port = self._native_port()
+        if port is None or (verify and not _natively_verifiable(infos)):
+            return super().read_chunks_into(block_id, infos, rows,
+                                            verify=verify)
+        self._check_partition("ReadChunks")
+        request = self._read_request(block_id, infos, verify)
+        n = len(infos)
+        heads = memoryview(bytearray(5 * (n + 1)))
+        bufs: list[memoryview] = []  # the response stream's layout
+        head_at: list[int] = []  # index in `bufs` of each frame's head
+        head_end: list[int] = []  # stream offset where that head ends
+        at = 0
+        for i, info in enumerate(infos):
+            head_at.append(len(bufs))
+            bufs.append(heads[5 * i:5 * i + 5])
+            at += 5
+            head_end.append(at)
+            if info.length:
+                bufs.append(_row_view(rows[i], info.length))
+                at += info.length
+        head_at.append(len(bufs))
+        bufs.append(heads[5 * n:])
+        head_end.append(at + 5)
+        bufs.append(memoryview(bytearray(256)))  # the STATUS body, as a rule
+        try:
+            conn = self._checkout(port)
+        except OSError:
+            self._disable_native()
+            return super().read_chunks_into(block_id, infos, rows,
+                                            verify=verify)
+        try:
+            conn.arm("ReadChunks")
+            conn.send_frames(request)
+            todo = list(bufs)  # consumed from `nxt`, partial ones cut
+            nxt = got = idx = 0
+            while True:
+                # every head that is whole by now is read before more is
+                # asked of the socket: a STATUS where a DATA frame was
+                # due (an error mid-stream) is the last thing the daemon
+                # sends, and a receive that waited for the rest of the
+                # layout would wait for its timeout
+                if got < head_end[idx]:
+                    r, nxt = _recvmsg_some(conn.sock, todo, nxt)
+                    got += r
+                    continue
+                size, tag = _FRAME.unpack(heads[5 * idx:5 * idx + 5])
+                if size > _MAX_FRAME:
+                    raise ConnectionError(f"oversized frame {size}")
+                if tag == _T_STATUS:
+                    break
+                if idx == n or tag != _T_DATA:
+                    raise ConnectionError(f"unexpected frame tag {tag:#x}")
+                if size != infos[idx].length:
+                    raise ConnectionError(
+                        f"DATA frame {size}B != requested "
+                        f"{infos[idx].length}B")
+                idx += 1
+            # the STATUS body: what of it already came lies in the
+            # layout behind its head (a row's start, after an error),
+            # the rest is still to come
+            have = got - head_end[idx]
+            if have > size:
+                raise ConnectionError("bytes after the STATUS frame")
+            body = bytearray(size)
+            filled = 0
+            for b in bufs[head_at[idx] + 1:]:
+                if filled == have:
+                    break
+                take = min(len(b), have - filled)
+                body[filled:filled + take] = b[:take]
+                filled += take
+            conn.recv_exact_into(memoryview(body)[have:])
+            self._status(conn, body)  # raises on err
+            if idx != n:
+                raise ConnectionError("short native read stream")
+            hostmem.count_move(sum(int(i.length) for i in infos))
+        except (OSError, ConnectionError) as e:
+            conn.close()
+            raise self._io_fault(e) from e
+        except StorageError:
+            # a mid-stream server error leaves this connection's framing
+            # state unknown: don't pool it
+            conn.close()
+            raise
+        else:
+            self._checkin(conn)
+        return n
+
     def close(self):
         with self._np_lock:
             for c in self._pool:
@@ -496,6 +620,20 @@ def _payload_view(data) -> memoryview:
                   f"{caller.f_lineno}"))
         arr = np.ascontiguousarray(arr, dtype=np.uint8)
     return memoryview(arr.reshape(-1))
+
+
+def _row_view(row, length: int) -> memoryview:
+    """The first `length` bytes of a receive row, as the socket is to
+    write them; a row that is no writable C-contiguous uint8 array of
+    that many bytes is the caller's fault, found before a frame
+    leaves."""
+    if not (isinstance(row, np.ndarray) and row.dtype == np.uint8
+            and row.ndim == 1 and row.flags.c_contiguous
+            and row.flags.writeable and row.size >= length):
+        raise ValueError(
+            f"receive row must be a writable contiguous uint8 array of "
+            f"at least {length} bytes")
+    return memoryview(row)[:length]
 
 
 def _natively_verifiable(infos) -> bool:

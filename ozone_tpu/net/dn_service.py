@@ -532,6 +532,18 @@ class GrpcDatanodeClient:
                 f"ReadChunks returned {len(out)}/{len(infos)} frames")
         return out
 
+    def read_chunks_into(self, block_id, infos, rows, verify=False):
+        """`read_chunks` with chunk i's bytes written to
+        `rows[i][:infos[i].length]` (one writable uint8 row a chunk;
+        what lies behind the chunk's length is left alone). Returns how
+        many rows the transport received IN PLACE: none here, where
+        every frame is copied out of gRPC's buffer; the native datapath
+        receives into the rows themselves."""
+        for row, data in zip(rows, self.read_chunks(block_id, infos,
+                                                    verify=verify)):
+            row[:data.size] = data
+        return 0
+
     def put_block(self, block, sync=False, writer=None):
         m = {"block": block.to_json(), "sync": sync,
              **self._btok(block.block_id)}

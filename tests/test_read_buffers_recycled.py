@@ -18,8 +18,10 @@ import numpy as np
 import pytest
 
 from ozone_tpu.client import resilience
+from ozone_tpu.client.ec_reader import OPS
 from ozone_tpu.codec import hostmem
 from ozone_tpu.codec.api import CoderOptions
+from ozone_tpu.net import partition
 from ozone_tpu.storage.ids import StorageError
 from ozone_tpu.utils.checksum import Checksum, ChecksumType
 from ozone_tpu.utils.tracing import Tracer
@@ -354,22 +356,39 @@ def test_a_users_answer_is_not_leased_again_while_any_view_lives(tmp_path):
 
 
 # -------------------------------------- an abandoned batch and its writers
-def test_a_hedged_stragglers_batch_waits_for_its_late_writer(tmp_path,
-                                                             leases):
+@pytest.mark.parametrize("transport", ["copied", "in_place"])
+def test_a_hedged_stragglers_batch_waits_for_its_late_writer(
+        tmp_path, leases, transport):
     """A survivor straggles, the recovery hedges to a spare and the read
     returns; the straggler's reader thread is still to write its cells
-    into the abandoned batch. Until it has, those pages are nobody
-    else's."""
+    into the abandoned batch: by copies out of its late answer, or, over
+    the native datapath, by a late receive straight into the batch's
+    rows. Until it has, those pages are nobody else's."""
     straggle_s = 1.5
-    cluster, g, data = _one_group(tmp_path, RS32, 3, seed=71)
+    if transport == "in_place":
+        from ozone_tpu.storage.fast_datapath import load_lib
+        from tests.test_read_in_place import _group
+
+        if load_lib() is None:
+            pytest.skip("no native toolchain")
+        cluster, g, data = _group(tmp_path, RS32, "native", stripes=3,
+                                  seed=71)
+    else:
+        cluster, g, data = _one_group(tmp_path, RS32, 3, seed=71)
     pool = hostmem.pool()
+    in_place = OPS.counter("survivor_cells_in_place")
     try:
         _lose(cluster, g, (0,))
         cluster.reader(g).read_all()  # compile the decode shapes first
         base = _base()
         victim = g.pipeline.nodes[1]
-        cluster.clients._local[victim] = _SlowClient(
-            cluster.clients.get(victim), straggle_s)
+        if transport == "in_place":
+            partition.add_rule(
+                dst=cluster.clients.remote_address(victim),
+                verb="ReadChunks", delay_s=straggle_s)
+        else:
+            cluster.clients._local[victim] = _SlowClient(
+                cluster.clients.get(victim), straggle_s)
         cluster.clients.health = resilience.HealthRegistry()
         del leases[:]
         t0 = time.monotonic()
@@ -382,6 +401,7 @@ def test_a_hedged_stragglers_batch_waits_for_its_late_writer(tmp_path,
         (_, a_out), (n_batch, a_abandoned), (_, a_retry) = leases
         assert len({a_out, a_abandoned, a_retry}) == 3
         assert _leased() == base + 2, "the answer and the abandoned batch"
+        received = in_place.value
         mine = [pool.lease_array(n_batch)[0] for _ in range(4)]
         for m in mine:
             assert m.ctypes.data != a_abandoned
@@ -389,11 +409,15 @@ def test_a_hedged_stragglers_batch_waits_for_its_late_writer(tmp_path,
         # the straggler writes, late, and lets go
         assert _settled(base + 1 + len(mine)), "the batch never came back"
         assert time.monotonic() - t0 >= straggle_s
+        if transport == "in_place":
+            # its three cells landed in the abandoned batch's own rows
+            assert in_place.value - received == 3
         assert np.array_equal(got, data)
         assert all((m == POISON).all() for m in mine)
         del mine, m, got
         assert _leased() == base
     finally:
+        partition.clear()
         cluster.close()
 
 
