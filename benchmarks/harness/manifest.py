@@ -140,10 +140,23 @@ def problems(manifest: dict, root: Path = ROOT) -> list[str]:
             name_ok(f"config {c['name']} reduced", r)
         if not any(c["file"].startswith(p + "/") for p in manifest["paths"]):
             out.append(f"config {c['name']}: file outside paths")
+        chips = [w.get("chips") for w in manifest["workloads"]
+                 if w.get("config") == c["name"]]
+        if not chips:
+            out.append(f"config {c['name']}: no workload uses it")
         if not (root / c["file"]).is_file():
             out.append(f"config {c['name']}: {c['file']} does not exist")
-        if not any(w["config"] == c["name"] for w in manifest["workloads"]):
-            out.append(f"config {c['name']}: no workload uses it")
+        elif chips and all(n in (1, 4) for n in chips):
+            # the deployment states the chips it is laid out on: the
+            # most that any of its cells asks for
+            try:
+                got = _json(root / c["file"]).get("cluster", {}).get("chips")
+            except ManifestError as e:
+                out.append(f"config {c['name']}: {e}")
+            else:
+                if got != max(chips):
+                    out.append(f"config {c['name']}: cluster.chips {got!r} "
+                               f"is not its workloads' most, {max(chips)}")
     pairs = set()
     for w in manifest["workloads"]:
         if set(w) != {"name", "config", "traffic", "chips", "why"}:
@@ -163,6 +176,13 @@ def problems(manifest: dict, root: Path = ROOT) -> list[str]:
             generator_of(traffic_of(w, bench_dir), bench_dir)
         except (ManifestError, KeyError) as e:
             out.append(f"workload {w['name']}: {e}")
+    # the contract's rule: of n cells at most half, rounded down, take
+    # four chips, and one always may
+    four = sum(w.get("chips") == 4 for w in manifest["workloads"])
+    cap = max(1, len(manifest["workloads"]) // 2)
+    if four > cap:
+        out.append(f"{four} workloads take four chips; of "
+                   f"{len(manifest['workloads'])} at most {cap} may")
     if "setup_s" not in e2e:
         out.append("no end-to-end metric setup_s")
     for section, keys in (

@@ -8,17 +8,41 @@ cluster's launcher has been started.
 
 from __future__ import annotations
 
+import sys
+
+
+def _named_registries() -> dict:
+    """{name: registry} of every registry a module of the program holds:
+    made once through `utils/metrics.registry(name)` and shared by all
+    that ask for the name (`codec.service`, `mesh`, `client.ops`,
+    `datapath`, `lifecycle`, `tracing`, ...). A registry an object makes
+    for itself (each repair coordinator's `ec.reconstruction`, the OM's,
+    a datanode's) is replaced by the next one of its name, so deltas of
+    it mean nothing: no module holds it, and it is left out."""
+    from ozone_tpu.utils.metrics import MetricsRegistry
+
+    out = {}
+    for mod_name, mod in list(sys.modules.items()):
+        if mod is None or mod_name.partition(".")[0] != "ozone_tpu":
+            continue
+        for value in list(vars(mod).values()):
+            if isinstance(value, MetricsRegistry):
+                out[value.name] = value
+    return out
+
 
 def snapshot() -> dict[str, float]:
-    """Flat {"<registry>/<name>": value} of the program's counters that
-    readers may take deltas of. A histogram gives `.sum` and `.count`."""
-    from ozone_tpu.codec import service as codec_service
-    from ozone_tpu.parallel import mesh_executor
+    """Flat {"<registry>/<name>": value} of the counters of every named
+    registry, which readers may take deltas of. A histogram gives `.sum`
+    and `.count`."""
+    # the two registries read from the first: there before any module
+    # that submits work is imported
+    from ozone_tpu.codec import service  # noqa: F401
+    from ozone_tpu.parallel import mesh_executor  # noqa: F401
     from ozone_tpu.utils.compile_cache import compile_counts
 
     out: dict[str, float] = {}
-    for prefix, reg in (("codec.service", codec_service.METRICS),
-                        ("mesh", mesh_executor.METRICS)):
+    for prefix, reg in _named_registries().items():
         for name, c in list(reg._counters.items()):
             out[f"{prefix}/{name}"] = float(c.value)
         for name, h in list(reg._histograms.items()):

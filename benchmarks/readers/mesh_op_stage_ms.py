@@ -1,7 +1,7 @@
-"""`op_stage_ms` under a name of the mesh cell's own: the same function.
-tests/benchmark_tests/test_bench_spans.py pins the set of metrics whose
-files name `op_stage_ms` to those PR 25 brought, and a PR that adds a
-cell may not edit it; until a `benchmark` PR lifts the pin,
-`repair_mesh_ms` names the reader so (PERF.md section 7)."""
+"""`op_stage_ms` under a name of the mesh cells' own: the same function.
+No test pins the metrics that name `op_stage_ms` any longer; the mesh
+and `.tier` stage groups keep this name while tests/test_bench_tier.py
+and tests/test_bench_mesh_metrics.py hold their files to it (PERF.md
+section 7)."""
 
 from benchmarks.readers.op_stage_ms import read  # noqa: F401

@@ -1,7 +1,7 @@
-"""`window_share_pct` under a name of the mesh cell's own: the same
-function. tests/benchmark_tests/test_bench_spans.py pins the set of
-metrics whose files name `window_share_pct` to those PR 25 brought, and a
-PR that adds a cell may not edit it; until a `benchmark` PR lifts the pin,
-`mesh_idle_pct.repair` names the reader so (PERF.md section 7)."""
+"""`window_share_pct` under a name of the mesh cells' own: the same
+function. No test pins the metrics that name `window_share_pct` any
+longer; the mesh and `.tier` window shares keep this name while
+tests/test_bench_tier.py and tests/test_bench_mesh_metrics.py hold their
+files to it (PERF.md section 7)."""
 
 from benchmarks.readers.window_share_pct import read  # noqa: F401

@@ -166,35 +166,40 @@ def test_the_fused_encoders_units_and_crcs_are_the_references(monkeypatch):
 
 
 # ------------------------------------------------------------- the manifest
-def test_the_manifest_holds_both_cells_and_their_metrics():
-    assert mf.problems(MANIFEST) == []
-    cells = {w["name"]: w for w in MANIFEST["workloads"]}
-    assert [w["name"] for w in MANIFEST["workloads"]][-2:] == [KEPT, CELL]
+def manifest_rules(manifest: dict, root=mf.ROOT) -> None:
+    """Both cells of PR 35 with their configuration and traffic, and the
+    LRC cell's metrics, in any manifest that grows from this one."""
+    bench_dir = root / "benchmarks"
+    assert mf.problems(manifest, root) == []  # the chips rule among it
+    cells = {w["name"]: w for w in manifest["workloads"]}
     assert cells[CELL]["chips"] == cells[KEPT]["chips"] == 1
     assert (cells[CELL]["config"], cells[CELL]["traffic"]) == (
         CONFIG, "ecrd-lrc")
     assert (cells[KEPT]["config"], cells[KEPT]["traffic"]) == (
         "rs-6-3-1024k", "ockv-degraded")
-    assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 2
-    config = mf.config_of(MANIFEST, cells[CELL])
+    config = mf.config_of(manifest, cells[CELL], root)
     assert config["cluster"]["datanodes"] == 16
     assert config["scheme"]["p"] == config["scheme"]["l"] + \
         config["scheme"]["r"] == 4
-    by_name = {m["name"]: m for m in MANIFEST["per_layer"]}
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
     for name, reader in LRC_METRICS.items():
-        assert by_name[name]["workloads"] == [CELL]
+        assert CELL in by_name[name]["workloads"]
         assert by_name[name]["moves"] == "repair_mib_s"
-        assert mf.metric_params(name)["reader"] == reader
+        assert mf.metric_params(name, bench_dir)["reader"] == reader
     # counted at k = 12 a local repair would claim twice its bytes
     assert CELL not in by_name["fused_decode_roofline.repair"]["workloads"]
-    e2e = {m["name"] for m in mf.metrics_for(MANIFEST, "end_to_end", CELL)}
-    assert e2e == {"repair_mib_s", "setup_s"}
+    e2e = {m["name"] for m in mf.metrics_for(manifest, "end_to_end", CELL)}
+    assert e2e >= {"repair_mib_s", "setup_s"}
     assert {m["name"] for m in mf.metrics_for(
-        MANIFEST, "end_to_end", KEPT)} == {"get_mib_s", "setup_s"}
+        manifest, "end_to_end", KEPT)} >= {"get_mib_s", "setup_s"}
     # the kept cell reports what its wide sibling reports
-    for m in MANIFEST["per_layer"]:
+    for m in manifest["per_layer"]:
         assert (KEPT in m["workloads"]) == (
             "ockv-degraded.rs-10-4" in m["workloads"]), m["name"]
+
+
+def test_the_manifest_holds_both_cells_and_their_metrics():
+    manifest_rules(MANIFEST)
 
 
 # --------------------------------------------------------------- the readers

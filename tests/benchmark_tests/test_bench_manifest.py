@@ -17,9 +17,11 @@ from benchmarks.harness import manifest as mf
 from benchmarks.harness.context import Context, PayloadPool
 
 
-def test_the_manifest_and_every_file_it_names_are_sound():
-    manifest = mf.load()
-    assert mf.problems(manifest) == []
+def manifest_rules(manifest: dict, root=mf.ROOT) -> None:
+    """What every manifest the harness runs holds: problems() finds
+    nothing (the contract's rule for chips among it), and each per-layer
+    metric and configuration is as the harness reads it."""
+    assert mf.problems(manifest, root) == []
     assert len(json.dumps(manifest)) < 64 * 1024
     assert manifest["command"] == ["python3", "benchmarks/run.py"]
     for m in manifest["per_layer"]:
@@ -30,27 +32,43 @@ def test_the_manifest_and_every_file_it_names_are_sound():
                 x["name"] for x in mf.metrics_for(manifest, "end_to_end", w)]
     for c in manifest["configs"]:
         assert any(w["config"] == c["name"] for w in manifest["workloads"])
-        cfg = json.loads((mf.ROOT / c["file"]).read_text())
+        cfg = json.loads((root / c["file"]).read_text())
         assert set(c["reduced"]) == set(cfg["reduced"])
         assert cfg["guarantees"] and cfg["assumed"] and cfg["source"]
-    assert all(w["chips"] == 1 for w in manifest["workloads"])
+
+
+def test_the_manifest_and_every_file_it_names_are_sound():
+    manifest_rules(mf.load())
+
+
+def _cell(m: dict, name: str = "ockg.rs-6-3") -> dict:
+    return mf.cell(m, name)
+
+
+def _metric(m: dict, name: str = "codec_fill_pct.put") -> dict:
+    (entry,) = [x for x in m["per_layer"] + m["end_to_end"]
+                if x["name"] == name]
+    return entry
 
 
 @pytest.mark.parametrize("edit,complaint", [
-    (lambda m: m["workloads"][0].update(name="bad name"), "allowed name"),
-    (lambda m: m["workloads"][0].update(name="x" * 65), "allowed name"),
-    (lambda m: m["end_to_end"][0].update(unit="MiB per s"), "unit"),
-    (lambda m: m["end_to_end"][0].update(bound=0.5), "bound"),
-    (lambda m: m["per_layer"][0].update(moves="nothing"), "moves"),
-    (lambda m: m["per_layer"][0].pop("workloads"), "no workloads list"),
-    (lambda m: m["per_layer"][0].update(workloads=["ecrd.rs-6-3"]),
+    (lambda m: _cell(m).update(name="bad name"), "allowed name"),
+    (lambda m: _cell(m).update(name="x" * 65), "allowed name"),
+    (lambda m: _metric(m, "put_mib_s").update(unit="MiB per s"), "unit"),
+    (lambda m: _metric(m, "put_mib_s").update(bound=0.5), "bound"),
+    (lambda m: _metric(m).update(moves="nothing"), "moves"),
+    (lambda m: _metric(m).pop("workloads"), "no workloads list"),
+    (lambda m: _metric(m).update(workloads=["ecrd.rs-6-3"]),
      "does not report"),
-    (lambda m: m["per_layer"][0].update(why="x"), "keys"),
-    (lambda m: m["workloads"][0].update(traffic="nowhere"), "cannot read"),
+    (lambda m: _metric(m).update(why="x"), "keys"),
+    (lambda m: _cell(m).update(traffic="nowhere"), "cannot read"),
     (lambda m: m["configs"].append(dict(m["configs"][0], name="spare",
                                         file="benchmarks/configs/none.json")),
      "does not exist"),
-    (lambda m: m["end_to_end"].pop(-1), "setup_s"),
+    (lambda m: m["end_to_end"].remove(_metric(m, "setup_s")), "setup_s"),
+    (lambda m: _cell(m).update(chips=2), "chips 2"),
+    # a one-chip deployment's cell on four chips: the config says 1
+    (lambda m: _cell(m).update(chips=4), "cluster.chips 1"),
 ])
 def test_problems_names_what_is_wrong(edit, complaint):
     manifest = mf.load()

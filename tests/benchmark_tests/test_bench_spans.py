@@ -18,10 +18,25 @@ from benchmarks.harness.record import Run
 
 FIXTURES = mf.BENCH_DIR / "fixtures"
 MANIFEST = mf.load()
-NEW = [m for m in MANIFEST["per_layer"]
-       if mf.metric_params(m["name"])["reader"] in (
-           "op_stage_ms", "window_share_pct", "device_idle_unfed_pct")
-       or re.match(r"codec_(pack|launch|d2h)_ms\.", m["name"])]
+#: PR 25's metrics by family: (reader, source) of each
+FAMILIES = {
+    **{f"{b}.{c}": ("histogram_mean_ms", "program_counter")
+       for b in ("codec_pack_ms", "codec_launch_ms", "codec_d2h_ms")
+       for c in ("put", "get", "repair")},
+    **{f"codec_idle_pct.{c}": ("window_share_pct", "program_counter")
+       for c in ("put", "get", "repair")},
+    **{f"device_idle_unfed_pct.{c}": ("device_idle_unfed_pct",
+                                      "device_trace")
+       for c in ("put", "get", "repair")},
+    **{n: ("op_stage_ms", "program_span") for n in (
+        "put_om_ms", "put_codec_ms", "put_dn_write_ms", "put_client_ms",
+        "get_dn_read_ms", "get_codec_ms", "get_client_ms",
+        "repair_fixed_ms", "repair_read_ms", "repair_codec_ms",
+        "repair_write_ms")},
+}
+NEW = [m for m in MANIFEST["per_layer"] if m["name"] in FAMILIES]
+#: the readers that mean a root's operations by stage group
+STAGE_READERS = ("op_stage_ms", "mesh_op_stage_ms")
 
 
 def _run(**kw) -> Run:
@@ -35,35 +50,34 @@ def _read(name: str, run: Run):
     return mf.reader_of(params)(params, run)
 
 
-def _groups(cell: str) -> dict[str, list]:
-    """{metric: compiled stage patterns} of the cell's op_stage_ms
-    metrics, and their one root."""
-    out, roots = {}, set()
-    for m in mf.metrics_for(MANIFEST, "per_layer", cell):
-        p = mf.metric_params(m["name"])
-        if p["reader"] == "op_stage_ms":
-            out[m["name"]] = [re.compile(x) for x in p["stages"]]
-            roots.add(p["root"])
-    assert len(roots) == 1, roots
-    return out, roots.pop()
+def _groups(cell: str, manifest: dict = MANIFEST,
+            bench_dir=mf.BENCH_DIR) -> dict[str, dict[str, list]]:
+    """{root: {metric: compiled stage patterns}} of the cell's stage
+    group metrics: a cell whose traffic has several kinds of operation
+    has a root for each."""
+    out: dict[str, dict[str, list]] = {}
+    for m in mf.metrics_for(manifest, "per_layer", cell):
+        p = mf.metric_params(m["name"], bench_dir)
+        if p["reader"] in STAGE_READERS:
+            out.setdefault(p["root"], {})[m["name"]] = [
+                re.compile(x) for x in p["stages"]]
+    return out
+
+
+def metric_rules(manifest: dict, root=mf.ROOT) -> None:
+    """PR 25's metrics are there, each read by its reader and of its
+    source; others may follow."""
+    bench_dir = root / "benchmarks"
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    assert set(by_name) >= set(FAMILIES)
+    for name, (reader, source) in FAMILIES.items():
+        assert by_name[name]["source"] == source, name
+        assert mf.metric_params(name, bench_dir)["reader"] == reader, name
+    assert mf.problems(manifest, root) == []
 
 
 def test_this_pr_added_the_metrics_the_issue_names():
-    names = {m["name"] for m in NEW}
-    want = {f"{b}.{c}" for b in (
-        "codec_pack_ms", "codec_launch_ms", "codec_d2h_ms",
-        "codec_idle_pct", "device_idle_unfed_pct")
-        for c in ("put", "get", "repair")}
-    want |= {"put_om_ms", "put_codec_ms", "put_dn_write_ms",
-             "put_client_ms", "get_dn_read_ms", "get_codec_ms",
-             "get_client_ms", "repair_fixed_ms", "repair_read_ms",
-             "repair_codec_ms", "repair_write_ms"}
-    assert names == want
-    by_source = {m["name"]: m["source"] for m in NEW}
-    assert by_source["put_om_ms"] == "program_span"
-    assert by_source["codec_idle_pct.get"] == "program_counter"
-    assert by_source["device_idle_unfed_pct.repair"] == "device_trace"
-    assert mf.problems(MANIFEST) == []
+    metric_rules(MANIFEST)
 
 
 # ------------------------------------------------------ counter readers
@@ -130,7 +144,7 @@ def test_op_stage_ms_means_the_windows_operations_by_group(recorder):
     # the groups partition the root: they sum to its mean duration
     ops = spans.operations("client:put", run.t0, run.t1)
     assert [o["end"] for o in ops] == [100.0, 105.0]
-    assert sum(_read(m, run) for m in _groups("ockg.rs-6-3")[0]) \
+    assert sum(_read(m, run) for m in _groups("ockg.rs-6-3")["client:put"]) \
         == pytest.approx(sum(o["durationUs"] for o in ops) / 2 / 1e3)
     # no operation of the root ended in the window
     assert _read("repair_fixed_ms", run) is None
@@ -265,18 +279,20 @@ def test_a_cpu_pass_reads_every_new_counter_and_span_metric(
                for k in ("idle", "pack", "launch", "d2h"))
     assert 0.5 <= busy / (run.t1 - run.t0) <= 1.1
 
-    # the cell's stage groups partition the stage names this run
-    # produced, and sum to the mean duration of the same root spans
-    groups, root = _groups(cell)
-    ops = spans.operations(root, run.t0, run.t1)
-    assert ops
-    for stage in {name for o in ops for name in o["stages"]}:
-        owners = [m for m, pats in groups.items()
-                  if any(p.match(stage) for p in pats)]
-        assert len(owners) == 1, (stage, owners)
-    mean_ms = sum(o["durationUs"] for o in ops) / len(ops) / 1e3
-    assert sum(out["metrics"][m]["value"] for m in groups) \
-        == pytest.approx(mean_ms, rel=0.01)
+    # under each root, the cell's stage groups partition the stage names
+    # this run produced, and sum to the mean duration of the root's spans
+    by_root = _groups(cell)
+    assert by_root
+    for root, groups in by_root.items():
+        ops = spans.operations(root, run.t0, run.t1)
+        assert ops, root
+        for stage in {name for o in ops for name in o["stages"]}:
+            owners = [m for m, pats in groups.items()
+                      if any(p.match(stage) for p in pats)]
+            assert len(owners) == 1, (root, stage, owners)
+        mean_ms = sum(o["durationUs"] for o in ops) / len(ops) / 1e3
+        assert sum(out["metrics"][m]["value"] for m in groups) \
+            == pytest.approx(mean_ms, rel=0.01)
 
 
 def test_stage_groups_take_the_names_a_served_cluster_adds():
@@ -284,7 +300,7 @@ def test_stage_groups_take_the_names_a_served_cluster_adds():
     spans (the in-process mini-cluster has none): each known one falls in
     exactly one group of its cell too."""
     served = {
-        "ockg.rs-6-3": [
+        ("ockg.rs-6-3", "client:put"): [
             "client:/ozone.tpu.OmService/OpenKey",
             "client:/ozone.tpu.OmService/AllocateBlock",
             "client:/ozone.tpu.OmService/CommitKey",
@@ -294,14 +310,14 @@ def test_stage_groups_take_the_names_a_served_cluster_adds():
             "net:write_chunks_commit", "net:put_block", "om:open_key",
             "om:commit", "ec:flush", "codec:queue_wait",
             "codec:dispatch", "client:put", "client:write"],
-        "ockv-degraded.rs-10-4": [
+        ("ockv-degraded.rs-10-4", "client:get"): [
             "client:/ozone.tpu.DatanodeService/GetBlock",
             "client:/ozone.tpu.DatanodeService/ReadChunks",
             "client:/ozone.tpu.DatanodeService/ReadChunk",
             "net:get_block", "net:read_chunks", "net:read_chunk",
             "ec:read", "ec:fanout", "ec:decode_from_parity",
             "codec:queue_wait", "codec:dispatch", "client:get"],
-        "ecrd.rs-6-3": [
+        ("ecrd.rs-6-3", "repair:container"): [
             "client:/ozone.tpu.DatanodeService/CreateContainer",
             "client:/ozone.tpu.DatanodeService/ListBlock",
             "client:/ozone.tpu.DatanodeService/CloseContainer",
@@ -313,8 +329,8 @@ def test_stage_groups_take_the_names_a_served_cluster_adds():
             "repair:write", "repair:close", "ec:fanout", "net:get_block",
             "net:read_chunks", "codec:queue_wait", "codec:dispatch"],
     }
-    for cell, names in served.items():
-        groups, _root = _groups(cell)
+    for (cell, root), names in served.items():
+        groups = _groups(cell)[root]
         for stage in names:
             owners = [m for m, pats in groups.items()
                       if any(p.match(stage) for p in pats)]
