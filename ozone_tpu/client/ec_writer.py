@@ -55,9 +55,17 @@ from ozone_tpu.storage.ids import (
     StorageError,
 )
 from ozone_tpu.utils.checksum import Checksum, ChecksumData, ChecksumType
+from ozone_tpu.utils.metrics import registry
 from ozone_tpu.utils.tracing import Tracer
 
 log = logging.getLogger(__name__)
+
+#: the client's per-operation registry (`client/ozone_client.py`): a
+#: key that ends in a partial stripe books it (`partial_stripes`) and the
+#: zero data cells submitted to fill it (`pad_cells`)
+OPS = registry("client.ops")
+OPS.counter("partial_stripes")
+OPS.counter("pad_cells")
 
 
 @dataclass
@@ -378,7 +386,9 @@ class ECKeyWriter:
         than the reference's block-granular streaming mode. Falls back
         to the per-stripe path (commit order defines the ack watermark,
         as in flushStripeFromQueue:526) when a member lacks the verb."""
-        with Tracer.instance().span("ec:flush", stripes=len(stripes)):
+        pad = sum(n == 0 for s in stripes for n in s.lengths)
+        with Tracer.instance().span("ec:flush", stripes=len(stripes),
+                                    pad_cells=pad):
             self._write_batch_traced(stripes, parity_dev, crcs_dev)
 
     def _write_batch_traced(self, stripes, parity_dev, crcs_dev) -> None:
@@ -825,6 +835,8 @@ class ECKeyWriter:
                     else (self._cell_off if i == self._cell_idx else 0)
                     for i in range(self.k)
                 ]
+                OPS.counter("partial_stripes").inc()
+                OPS.counter("pad_cells").inc(lengths.count(0))
                 self._queue.append(_Stripe(self._buf, lengths))
                 self._buf = np.zeros((self.k, self.cell), dtype=np.uint8)
                 self._cell_idx = 0

@@ -27,6 +27,7 @@ import json
 import logging
 import math
 import threading
+import time
 import xml.etree.ElementTree as ET
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
@@ -47,6 +48,8 @@ from ozone_tpu.gateway.s3_auth import (
 )
 from ozone_tpu.om.requests import OMError
 from ozone_tpu.storage.ids import StorageError
+from ozone_tpu.utils.metrics import registry
+from ozone_tpu.utils.tracing import Tracer
 
 # a local OzoneManager raises OMError; a remote OM (GrpcOmClient) re-raises
 # the same codes as StorageError — the gateway maps both identically
@@ -56,6 +59,35 @@ log = logging.getLogger(__name__)
 
 S3_VOLUME = "s3v"
 _NS = "http://s3.amazonaws.com/doc/2006-03-01/"
+
+#: the gateway's registry: `requests_<kind>` and a histogram
+#: `request_seconds_<kind>` for each kind of request (`_request_kind`),
+#: `bytes_in` / `bytes_out` (request and reply bodies), `secret_fetches`
+#: (the OM's secret asked for, once a signed request) and
+#: `admission_rejects` (requests the gateway hop refused as SlowDown)
+METRICS = registry("gateway")
+_KINDS = ("get", "put", "head", "delete", "other")
+# made here, not at a first use that two handler threads could race to
+for _name in ("bytes_in", "bytes_out", "secret_fetches", "admission_rejects",
+              *(f"requests_{k}" for k in _KINDS)):
+    METRICS.counter(_name)
+for _kind in _KINDS:
+    METRICS.histogram(f"request_seconds_{_kind}")
+
+#: query verbs that make a request on an object something other than a
+#: plain GET / PUT / HEAD / DELETE of its bytes
+_SUBRESOURCES = frozenset({"uploads", "uploadId", "acl", "tagging"})
+
+
+def _request_kind(method: str, parts: list, q: dict) -> str:
+    """`get`, `put`, `head` or `delete` for a request on an object's
+    bytes; `other` for everything else (bucket and service requests,
+    multipart, ACLs, tags). Each request is one operation root
+    `s3:<kind>`."""
+    if (len(parts) >= 2 and method in ("GET", "PUT", "HEAD", "DELETE")
+            and not _SUBRESOURCES.intersection(q)):
+        return method.lower()
+    return "other"
 
 
 def _xml(root: ET.Element) -> bytes:
@@ -131,6 +163,12 @@ def _esc_fn(q: dict):
                      else (lambda s: s))
 
 
+def _etag(body: bytes) -> str:
+    """An object's ETag, the MD5 of its bytes (stage `s3:etag`)."""
+    with Tracer.instance().span("s3:etag", bytes=len(body)):
+        return hashlib.md5(body).hexdigest()
+
+
 def _err(code: str, message: str, status: int) -> tuple[int, bytes]:
     e = ET.Element("Error")
     ET.SubElement(e, "Code").text = code
@@ -184,22 +222,37 @@ class S3Gateway:
             def log_message(self, fmt, *args):
                 log.debug("s3: " + fmt, *args)
 
-            def _reply(self, status: int, body: bytes = b"",
+            def _reply(self, status: int, body=b"",
                        headers: Optional[dict] = None):
-                self.send_response(status)
-                for k, v in (headers or {}).items():
-                    self.send_header(k, v)
-                self.send_header("Content-Length", str(len(body)))
-                self.end_headers()
-                if body:
-                    self.wfile.write(body)
+                # stage `s3:send`: the reply written, an object's array
+                # made bytes included
+                with Tracer.instance().span("s3:send"):
+                    if isinstance(body, np.ndarray):
+                        body = body.tobytes()
+                    self.send_response(status)
+                    for k, v in (headers or {}).items():
+                        self.send_header(k, v)
+                    self.send_header("Content-Length", str(len(body)))
+                    self.end_headers()
+                    # a reply to HEAD has no body (RFC 9110 9.3.2), an
+                    # error's neither: on a keep-alive connection the
+                    # client would read it as the next reply's start
+                    if body and self.command != "HEAD":
+                        self.wfile.write(body)
+                        METRICS.counter("bytes_out").inc(len(body))
 
             def _body(self) -> bytes:
                 # memoized: read once so both signature verification and
                 # the operation handler can consume it
                 if not hasattr(self, "_cached_body"):
                     n = int(self.headers.get("Content-Length", 0))
-                    self._cached_body = self.rfile.read(n) if n else b""
+                    if n:
+                        # stage `s3:recv`: the body off the socket
+                        with Tracer.instance().span("s3:recv", bytes=n):
+                            self._cached_body = self.rfile.read(n)
+                        METRICS.counter("bytes_in").inc(n)
+                    else:
+                        self._cached_body = b""
                 return self._cached_body
 
             def _dispatch(self, method: str):
@@ -268,6 +321,7 @@ class S3Gateway:
                                 "aws-chunked streaming requires SigV4")
             return None
         auth = parse_authorization(header)
+        METRICS.counter("secret_fetches").inc()
         secret = self.client.om.get_s3_secret(auth.access_id, create=False)
         if secret is None:
             raise AuthError("InvalidAccessKeyId", auth.access_id)
@@ -333,6 +387,7 @@ class S3Gateway:
                             "aws-chunked streaming cannot be presigned")
         parsed = parse_query_auth(u.query)
         auth = parsed[0]
+        METRICS.counter("secret_fetches").inc()
         secret = self.client.om.get_s3_secret(auth.access_id, create=False)
         if secret is None:
             raise AuthError("InvalidAccessKeyId", auth.access_id)
@@ -394,18 +449,39 @@ class S3Gateway:
         return None
 
     def _route(self, h, method: str) -> None:
+        """One request as one operation root `s3:<kind>`, from its
+        request line to its reply written: the client's own operation
+        (`client:get`, `client:put`) and the gateway's stages
+        (`s3:auth`, `s3:recv`, `s3:etag`, `s3:send`) are its children."""
         u = urlparse(h.path)
         q = parse_qs(u.query, keep_blank_values=True)
         parts = [unquote(p) for p in u.path.strip("/").split("/") if p]
         vbucket = self._vhost_bucket(h)
         if vbucket is not None:
             parts = [vbucket] + parts
+        kind = _request_kind(method, parts, q)
+        tracer = Tracer.instance()
+        root = tracer.begin_operation(f"s3:{kind}", method=method)
+        t0 = time.perf_counter()
         try:
-            principal = self._authenticate(h, method)
-            self._request_ctx.volume = (
-                self._volume_for(principal) if principal is not None
-                else S3_VOLUME
-            )
+            with tracer.activate(tracer.context(root)):
+                self._serve(h, method, u, q, parts)
+        finally:
+            tracer.end_operation(root)
+            METRICS.counter(f"requests_{kind}").inc()
+            METRICS.histogram(f"request_seconds_{kind}").observe(
+                time.perf_counter() - t0, root.trace_id)
+
+    def _serve(self, h, method: str, u, q: dict, parts: list) -> None:
+        try:
+            # stage `s3:auth`: the signature checked against the secret
+            # the OM holds (its RPC is a child) and the principal's volume
+            with Tracer.instance().span("s3:auth"):
+                principal = self._authenticate(h, method)
+                self._request_ctx.volume = (
+                    self._volume_for(principal) if principal is not None
+                    else S3_VOLUME
+                )
             if principal is None and self.require_auth:
                 # anonymous: gated by the bucket's public ACL grants
                 # (READ for reads, WRITE for mutations)
@@ -464,6 +540,7 @@ class S3Gateway:
             }.get(e.code, ("InternalError", 500))
             headers = None
             if e.code == "SERVER_BUSY":
+                METRICS.counter("admission_rejects").inc()
                 # Retry-After is integer seconds (RFC 9110); round UP so
                 # the client never comes back before the hinted instant
                 hint = admission.retry_after_hint(str(e)) or 1.0
@@ -1031,7 +1108,13 @@ class S3Gateway:
         elif method == "HEAD":
             self._head_object(h, bucket, key)
         elif method == "DELETE":
-            self._bucket_handle(bucket).delete_key(key)
+            try:
+                self._bucket_handle(bucket).delete_key(key)
+            except _OM_ERRORS as e:
+                # S3 answers 204 for a missing key too (as _multi_delete
+                # does), so a retried DELETE is no error
+                if e.code != "KEY_NOT_FOUND":
+                    raise
             h._reply(204)
         else:
             h._reply(*_err("MethodNotAllowed", method, 405))
@@ -1184,7 +1267,7 @@ class S3Gateway:
             if tags:
                 self.client.om.set_key_attrs(self._vol, bucket, key,
                                              {"tags": tags})
-            etag = hashlib.md5(data).hexdigest()
+            etag = _etag(data)
             root = ET.Element("CopyObjectResult", xmlns=_NS)
             ET.SubElement(root, "ETag").text = f'"{etag}"'
             ET.SubElement(root, "LastModified").text = _iso_now()
@@ -1209,7 +1292,7 @@ class S3Gateway:
         if tags:
             self.client.om.set_key_attrs(self._vol, bucket, key,
                                          {"tags": tags})
-        etag = hashlib.md5(body).hexdigest()
+        etag = _etag(body)
         h._reply(200, headers={"ETag": f'"{etag}"'})
 
     @staticmethod
@@ -1271,8 +1354,7 @@ class S3Gateway:
             # ranged GET reads ONLY the covering cells/chunks (round-4
             # positioned reads), not the whole key
             hi = min(hi, size - 1)
-            part = bh.read_key_info_range(info, lo,
-                                          hi - lo + 1).tobytes()
+            part = bh.read_key_info_range(info, lo, hi - lo + 1)
             h._reply(
                 206,
                 part,
@@ -1283,8 +1365,7 @@ class S3Gateway:
                 },
             )
         else:
-            data = bh.read_key_info(info).tobytes()
-            h._reply(200, data,
+            h._reply(200, bh.read_key_info(info),
                      {"Content-Type": "application/octet-stream", **meta})
 
     def _head_object(self, h, bucket: str, key: str) -> None:
@@ -1292,12 +1373,13 @@ class S3Gateway:
         body (S3 semantics; SDKs size objects this way before ranged
         GETs), so the reply is hand-rolled instead of using _reply."""
         info = self.client.om.lookup_key(self._vol, bucket, key)
-        h.send_response(200)
-        h.send_header("Content-Type", "application/octet-stream")
-        h.send_header("Content-Length", str(info["size"]))
-        for k, v in (info.get("metadata") or {}).items():
-            h.send_header(f"x-amz-meta-{k}", str(v))
-        h.end_headers()
+        with Tracer.instance().span("s3:send"):
+            h.send_response(200)
+            h.send_header("Content-Type", "application/octet-stream")
+            h.send_header("Content-Length", str(info["size"]))
+            for k, v in (info.get("metadata") or {}).items():
+                h.send_header(f"x-amz-meta-{k}", str(v))
+            h.end_headers()
 
     # ------------------------------------------------------------- multipart
     # Backed by the OM multipart table (om/multipart.py), the reference's

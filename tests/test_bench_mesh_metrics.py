@@ -44,8 +44,12 @@ def test_a_new_metric_is_an_entry_of_the_mesh_cell_alone(name):
                      "source": "program_counter", "layer": layer,
                      "moves": "repair_mib_s", "workloads": [CELL]}
     assert mf.metric_params(name)["reader"] == reader
-    # appended after the 60 the benchmark had (later PRs append after)
-    assert 60 <= MANIFEST["per_layer"].index(entry) < 60 + len(NEW)
+    # appended in one run after the 60 the benchmark had; later PRs
+    # append after them
+    at = [m["name"] for m in MANIFEST["per_layer"]].index(next(iter(NEW)))
+    assert at >= 60
+    assert [m["name"] for m in MANIFEST["per_layer"][at:at + len(NEW)]] \
+        == list(NEW)
 
 
 def test_the_manifest_is_sound_and_the_cell_reports_what_it_did_and_three():
@@ -58,7 +62,8 @@ def test_the_manifest_is_sound_and_the_cell_reports_what_it_did_and_three():
             "repair_rpc_daemon_ms", "repair_rpc_client_side_ms",
             "client_cpu_cores.repair", "interp_wait_ms.repair",
             "host_busy_pct.repair"}
-    assert set(NEW) | cost <= got and len(got) == 15 + len(NEW) + len(cost)
+    # the 15 it had at least (a later PR may list it under more)
+    assert set(NEW) | cost <= got and len(got) >= 15 + len(NEW) + len(cost)
     # what test_bench_mesh.py asserts behind its pinned statement
     assert {m["name"] for m in mf.metrics_for(
         MANIFEST, "end_to_end", CELL)} == {"repair_mib_s", "setup_s"}
