@@ -36,7 +36,7 @@ from urllib.parse import parse_qs, quote as _url_quote, unquote, urlparse
 import numpy as np
 
 from ozone_tpu import admission
-from ozone_tpu.client.ozone_client import OzoneClient
+from ozone_tpu.client.ozone_client import OzoneBucket, OzoneClient
 from ozone_tpu.gateway.s3_auth import (
     STREAMING,
     AuthError,
@@ -522,6 +522,8 @@ class S3Gateway:
             code = {
                 "KEY_NOT_FOUND": ("NoSuchKey", 404),
                 "BUCKET_NOT_FOUND": ("NoSuchBucket", 404),
+                # a tenant whose volume is gone has no buckets at all
+                "VOLUME_NOT_FOUND": ("NoSuchBucket", 404),
                 "BUCKET_ALREADY_EXISTS": ("BucketAlreadyExists", 409),
                 "BUCKET_NOT_EMPTY": ("BucketNotEmpty", 409),
                 "NO_SUCH_MULTIPART_UPLOAD": ("NoSuchUpload", 404),
@@ -1059,13 +1061,16 @@ class S3Gateway:
             for el in list(tree.iter("{%s}Object" % _NS)) +
             list(tree.iter("Object"))
         ]
-        bh = self._bucket_handle(bucket)
+        om = self.client.om
+        # the per-name deletes below would list a missing bucket as one
+        # error per key in a 200: ask once, so it answers NoSuchBucket
+        om.bucket_info(self._vol, bucket)
         root = ET.Element("DeleteResult", xmlns=_NS)
         for name in names:
             if not name:
                 continue
             try:
-                bh.delete_key(name)
+                om.delete_key(self._vol, bucket, name)
                 if not quiet:
                     d = ET.SubElement(root, "Deleted")
                     ET.SubElement(d, "Key").text = name
@@ -1083,8 +1088,13 @@ class S3Gateway:
         h._reply(200, _xml(root), {"Content-Type": "application/xml"})
 
     # ------------------------------------------------------------- objects
-    def _bucket_handle(self, bucket: str):
-        return self.client.get_volume(self._vol).get_bucket(bucket)
+    def _bucket_handle(self, bucket: str) -> OzoneBucket:
+        """A handle that asks the OM nothing: the operation's own OM
+        call (LookupKey, DeleteKey, the PUT's small-object BucketInfo,
+        the multipart calls) answers BUCKET_NOT_FOUND for a missing
+        bucket, so a VolumeInfo + BucketInfo first would only ask it
+        again. Nothing is kept across requests."""
+        return OzoneBucket(self.client, self._vol, bucket)
 
     def _object_op(self, h, method: str, bucket: str, key: str, q) -> None:
         if method == "POST" and "uploads" in q:
@@ -1109,7 +1119,7 @@ class S3Gateway:
             self._head_object(h, bucket, key)
         elif method == "DELETE":
             try:
-                self._bucket_handle(bucket).delete_key(key)
+                self.client.om.delete_key(self._vol, bucket, key)
             except _OM_ERRORS as e:
                 # S3 answers 204 for a missing key too (as _multi_delete
                 # does), so a retried DELETE is no error
@@ -1314,7 +1324,8 @@ class S3Gateway:
         }
 
     def _get_object(self, h, bucket: str, key: str) -> None:
-        # one lookup serves metadata headers AND the block list
+        # one lookup serves the bucket's existence, the metadata
+        # headers AND the block list
         info = self.client.om.lookup_key(self._vol, bucket, key)
         bh = self._bucket_handle(bucket)
         meta = self._meta_headers_from(info)
@@ -1396,8 +1407,8 @@ class S3Gateway:
 
     def _mpu_handle(self, h, bucket: str, key: str, q):
         # no existence pre-check: the underlying OM call raises
-        # NO_SUCH_MULTIPART_UPLOAD itself (mapped to 404 in _route),
-        # avoiding an extra MultipartInfo round-trip per part
+        # BUCKET_NOT_FOUND or NO_SUCH_MULTIPART_UPLOAD itself (mapped to
+        # 404 in _serve), avoiding extra round trips per part
         from ozone_tpu.client.ozone_client import MultipartUpload
 
         upload_id = q["uploadId"][0]
